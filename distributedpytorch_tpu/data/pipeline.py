@@ -257,7 +257,7 @@ def build_semantic_train_transform(
     flip: bool = True,
     geom: bool = True,
 ) -> T.Compose:
-    """Multi-class semantic pipeline (the DeepLabV3 configs of BASELINE.md):
+    """Multi-class semantic pipeline (the DeepLabV3 configs of BASELINE.json):
     flip -> scale/rotate with nearest-warped class ids (``semseg=True``) ->
     fixed resize (gt nearest, 255 void preserved in-band) -> rename onto the
     step contract (``concat``/``crop_gt``).

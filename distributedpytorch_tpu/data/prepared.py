@@ -10,8 +10,8 @@ source (``data.source=packed`` + ``prepared_cache``) when caching the
 deterministic crop stage on top is still wanted.
 
 The end-to-end bound on a weak host is the deterministic front of the train
-pipeline — JPEG/PNG decode, mask-bbox crop, fixed resize (BASELINE.md: ~19
-fresh imgs/s e2e vs a ~65 imgs/s chip).  That front is *identical every
+pipeline — JPEG/PNG decode, mask-bbox crop, fixed resize (~19
+fresh imgs/s e2e vs a ~65 imgs/s chip, 2026-07 session).  That front is *identical every
 epoch*: given the sample and the crop config it has no randomness.  So run
 it once, store the result compactly on disk, and serve every later epoch
 from an ``np.memmap`` read — the FFCV recipe (PAPERS.md) applied to the

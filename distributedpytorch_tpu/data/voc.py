@@ -358,7 +358,7 @@ class VOCSemanticSegmentation:
     """Per-image semantic VOC2012: class-id masks from ``SegmentationClass``.
 
     The multi-class counterpart of :class:`VOCInstanceSegmentation` for the
-    DeepLabV3 semantic configs of BASELINE.md (configs 1 and 4).  The
+    DeepLabV3 semantic configs of BASELINE.json (configs 1 and 4).  The
     reference never trained this mode — its dataset is instance-level — but
     its class PNGs are read for the category cache (reference
     pascal.py:171-176), and this class exposes them directly:

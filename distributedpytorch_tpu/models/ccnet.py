@@ -5,7 +5,7 @@ attention lineage (the reference imports DANet from the PyTorch-Encoding
 family, train_pascal.py:32; CCNet — Huang et al. ICCV'19 — is that
 lineage's memory-light successor).  Where DANet's position attention
 scores every token against every token (N² = (HW)² energies — the
-measured 64 MB HBM tenant of the flagship step, BASELINE.md roofline),
+64 MB HBM tenant of the flagship step in f32),
 criss-cross attention scores each position only against its own row and
 column: O(N·(H+W)) energies, with a recurrence of R=2 giving every pixel
 a full-image receptive field through (at most) one intermediate

@@ -40,7 +40,7 @@ from .resnet import ResNet, make_norm
 
 
 #: 'auto' switch point for the position branch outside the bf16-TPU hot
-#: path (scripts/pam_crossover.py on the v5e, table in BASELINE.md): the
+#: path (scripts/pam_crossover.py on the v5e, 2026-07-30): the
 #: f32 sweep measured XLA's fused einsum FASTER at every compilable token
 #: count (32k: 147 ms vs flash's 185 ms fwd+bwd), so for f32 compute —
 #: and on CPU meshes, which run pallas through the slow interpreter —

@@ -1,8 +1,8 @@
 """DeepLabV3 (ASPP) segmentation model (flax.linen, NHWC).
 
 The second model family: the reference driver carries a commented DeepLab
-alternative to DANet (reference train_pascal.py:85), and BASELINE.md's
-measured configs name DeepLabV3-ResNet50/101 at output_stride 16 as the
+alternative to DANet (reference train_pascal.py:85), and BASELINE.json's
+configs name DeepLabV3-ResNet50/101 at output_stride 16 as the
 metric-bearing model.  Built natively: atrous spatial pyramid pooling over the
 dilated-ResNet stage-4 features, image-level pooling branch, optional FCN
 auxiliary head on stage-3 (standard DeepLabV3 training recipe).

@@ -55,7 +55,7 @@ def make_norm(
     instead of flax's float32 promotion (``force_float32_reductions``).
     The op profiles attribute 46% of the b8 flagship's device time — and
     the b16 regression's largest term — to bf16→f32 convert+reduce chains
-    riding the conv fusions (BASELINE.md batch autopsy); this is the
+    riding the conv fusions (2026-08 op profiles); this is the
     measured-mechanism A/B.  Accuracy: bf16 mean/var over >=8·64² elements
     loses ~2-3 decimal digits; gate on a convergence check before
     defaulting."""
@@ -171,12 +171,12 @@ class ResNet(nn.Module):
     remat: bool = False  # rematerialize blocks: trade FLOPs for HBM
     #: with remat: a jax.checkpoint_policies name ('dots_saveable',
     #: 'dots_with_no_batch_dims_saveable', ...) instead of full recompute.
-    #: Rationale (BASELINE.md b16 autopsy): XLA AUTO-rematerializes under
+    #: Rationale (2026-08 b16 op profiles): XLA AUTO-rematerializes under
     #: HBM pressure at b16 with its own op choice; full per-block remat
     #: measured -13.5% there because the recompute re-reads more HBM than
     #: the stash it saves.  'dots_saveable' keeps conv/matmul outputs and
     #: recomputes only the cheap elementwise/BN chains — the explicit
-    #: pre-emption VERDICT r3 item 5 asks to A/B.
+    #: pre-emption to A/B against it.
     remat_policy: str | None = None
 
     @nn.compact

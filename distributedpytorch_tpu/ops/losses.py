@@ -115,7 +115,7 @@ def softmax_xent_ignore(
     void pixels (the reference's 255-labeled boundary pixels,
     pascal.py:240-242).  Ignored pixels contribute zero and are excluded
     from the mean — the multi-class loss for the DeepLabV3 semantic-
-    segmentation configs of BASELINE.md.
+    segmentation configs of BASELINE.json.
 
     The label log-prob is selected with a compare-select-reduce over the
     class axis rather than ``take_along_axis``: XLA lowers the gather to a

@@ -126,7 +126,7 @@ def np_jaccard_thresholds(
 
 
 # ---------------------------------------------------------------------------
-# multi-class semantic metrics (the DeepLabV3 "val mIoU" of BASELINE.md)
+# multi-class semantic metrics (the DeepLabV3 "val mIoU" of BASELINE.json)
 # ---------------------------------------------------------------------------
 
 def confusion_matrix(

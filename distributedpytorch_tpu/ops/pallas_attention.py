@@ -28,9 +28,15 @@ Backward for both: a ``jax.custom_vjp`` whose reverse pass recomputes
 with the O(N·block) / jnp reference form and differentiates that —
 recompute-not-store, the standard flash trade.
 
-Tests run these kernels with ``interpret=True`` on CPU (pallas's
-interpreter executes the same program the Mosaic compiler lowers on
-TPU), including forward AND backward parity against the XLA forms.
+Mosaic compiles the kernels unless a caller passes ``interpret=True`` —
+only the CPU test suite does (pallas's interpreter executes the same
+program), including forward AND backward parity against the XLA forms.
+Off-TPU the ``auto`` selector in :mod:`models.danet` picks the einsum
+forms; the kernels themselves never fall back.
+
+Mosaic refuses automatic partitioning, so under a multi-device ``jit``
+each forward runs inside a ``shard_map`` on its device's batch shard —
+see :func:`_on_local_batch`.
 """
 
 from __future__ import annotations
@@ -45,6 +51,25 @@ from jax.experimental.pallas import tpu as pltpu
 from .attention import blocked_position_attention, channel_attention
 
 _NEG_INF = -1e30
+
+
+def _on_local_batch(kernel, *operands):
+    """Run ``kernel`` (batch-leading operands -> batch-leading result) on
+    each device's batch shard.
+
+    GSPMD cannot partition a Mosaic call, so when the program is traced
+    under a context mesh (``parallel.step`` enters the step's mesh) the
+    call is wrapped in a ``shard_map`` over every mesh axis: batch rows
+    split over ``data``, token and channel dims whole on each device.
+    With no context mesh (one device) or inside per-device code already
+    (the bucketed step's shard_map region) the kernel is called as is."""
+    from ..parallel.mesh import batch_spec
+
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.manual_axes:
+        return kernel(*operands)
+    return jax.shard_map(kernel, in_specs=batch_spec(),
+                         out_specs=batch_spec(), check_vma=False)(*operands)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, s_ref, acc_ref,
@@ -89,12 +114,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, s_ref, acc_ref,
                     ).astype(o_ref.dtype)
 
 
-def _flash_forward(q, k, v, block_q: int, block_k: int,
-                   scale: float | None, interpret: bool | None):
-    if interpret is None:
-        # Mosaic compiles on TPU; everywhere else run the same program in
-        # the pallas interpreter (slow but correct — CI / CPU meshes).
-        interpret = jax.default_backend() != "tpu"
+def _flash_local(q, k, v, *, block_q: int, block_k: int,
+                 scale: float | None, interpret: bool):
     b, n, ck = q.shape
     cv = v.shape[-1]
     nq = pl.cdiv(n, block_q)
@@ -129,10 +150,17 @@ def _flash_forward(q, k, v, block_q: int, block_k: int,
     return out[:, :n, :]
 
 
+def _flash_forward(q, k, v, block_q: int, block_k: int,
+                   scale: float | None, interpret: bool):
+    return _on_local_batch(
+        functools.partial(_flash_local, block_q=block_q, block_k=block_k,
+                          scale=scale, interpret=interpret), q, k, v)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_position_attention(q, k, v, block_q: int = 256, block_k: int = 256,
                              scale: float | None = None,
-                             interpret: bool | None = None):
+                             interpret: bool = False):
     """Flash position attention: same math as
     :func:`ops.attention.position_attention` (unscaled DANet energies unless
     ``scale``), O(N·block) memory, MXU-scheduled.
@@ -202,9 +230,7 @@ def _cam_apply_kernel(attn_ref, x_ref, o_ref):
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
-def _cam_forward(x, block_n: int, interpret: bool | None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def _cam_local(x, *, block_n: int, interpret: bool):
     b, n, c = x.shape
     nb = pl.cdiv(n, block_n)
     pad = nb * block_n - n
@@ -233,9 +259,15 @@ def _cam_forward(x, block_n: int, interpret: bool | None):
     return out[:, :n, :]
 
 
+def _cam_forward(x, block_n: int, interpret: bool):
+    return _on_local_batch(
+        functools.partial(_cam_local, block_n=block_n, interpret=interpret),
+        x)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
 def flash_channel_attention(x, block_n: int = 256,
-                            interpret: bool | None = None):
+                            interpret: bool = False):
     """Fused channel (gram-matrix) attention: same math as
     :func:`ops.attention.channel_attention` — C×C gram of the (B, N, C)
     tokens, max-subtraction softmax, applied back over channels — with

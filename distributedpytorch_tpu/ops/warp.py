@@ -3,9 +3,9 @@
 The full-res semantic val protocol (reference train_pascal.py:280-306
 generalized to multi-class — metric at ORIGINAL resolution) needs every
 sample's crop-space class probabilities resized to that sample's own
-native size.  Ragged per-image work was host-bound in rounds 2-3
-(BASELINE.md: 1.5 imgs/s — one 21-channel cv2 resize per image on a
-1-core host, after shipping a 22 MB probability volume over the wire).
+native size.  Done on the host that is ragged per-image work: one
+21-channel cv2 resize per image, after reading a 22 MB probability volume
+back from the device (1.5 imgs/s on a 1-core host, 2026-08 session).
 
 TPU-native formulation: bilinear resize to a *per-sample* target size is
 a pair of matmuls with weight matrices built from compares over a static

@@ -45,7 +45,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .mesh import axis_size, make_mesh_1d, shard_map
+from .mesh import make_mesh_1d
 
 #: canonical pipeline-stage axis name
 PIPE_AXIS = "pipe"
@@ -77,7 +77,7 @@ def pipeline_apply_local(stage_fn: Callable[[Any, jax.Array], jax.Array],
     microbatches but only stage 0 ingests them.
     Returns (M, mb, ...) — the last stage's outputs, replicated via psum.
     """
-    n_stages = axis_size(axis_name)
+    n_stages = jax.lax.axis_size(axis_name)
     stage_idx = jax.lax.axis_index(axis_name)
     params = jax.tree.map(lambda x: x[0], stacked_params)
     n_micro = microbatches.shape[0]
@@ -118,7 +118,7 @@ def _meshed_apply(mesh: Mesh, stage_fn: Callable[[Any, jax.Array], jax.Array],
     """The (unjitted) meshed pipeline forward shared by
     :func:`make_pipeline_apply` and :func:`make_pipeline_train_step`."""
     specs = stage_param_specs(stacked_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(pipeline_apply_local, stage_fn,
                           axis_name=axis_name),
         mesh=mesh, in_specs=(specs, P()), out_specs=P(),

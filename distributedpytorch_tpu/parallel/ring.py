@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .mesh import DATA_AXIS, axis_size, shard_map
+from .mesh import DATA_AXIS
 
 
 def _online_block(q, k_blk, v_blk, m, s, acc, scale: float | None):
@@ -71,7 +71,7 @@ def ring_attention_local(q, k, v, axis_name: str = DATA_AXIS,
     Returns (B, N_local, Cv), bit-matching full softmax attention over the
     global token axis (up to f32 accumulation order).
     """
-    n_hops = axis_size(axis_name)
+    n_hops = jax.lax.axis_size(axis_name)
     b, nq, _ = q.shape
     cv = v.shape[-1]
     m0 = jnp.full((b, nq, 1), -jnp.inf, jnp.float32)
@@ -105,7 +105,7 @@ def make_ring_attention_inline(mesh: Mesh, axis_name: str = DATA_AXIS,
     ``axis_name``.
     """
     spec = P(batch_axis, axis_name, None)
-    return shard_map(
+    return jax.shard_map(
         functools.partial(ring_attention_local, axis_name=axis_name,
                           scale=scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

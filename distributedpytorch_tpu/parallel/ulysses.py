@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .mesh import DATA_AXIS, axis_size, shard_map
+from .mesh import DATA_AXIS
 
 
 def _heads_attention(q, k, v, scale: float | None):
@@ -58,7 +58,7 @@ def ulysses_attention_local(q, k, v, axis_name: str = DATA_AXIS,
     (B, N_local, H, Dv), bit-matching full attention over the global token
     axis (up to f32 accumulation order).
     """
-    ax = axis_size(axis_name)
+    ax = jax.lax.axis_size(axis_name)
     h = q.shape[2]
     if h % ax:
         raise ValueError(
@@ -84,7 +84,7 @@ def make_ulysses_attention(mesh: Mesh, axis_name: str = DATA_AXIS,
     token axis sharded on ``axis_name`` of ``mesh`` — the all-to-all
     long-context configuration (two ICI collectives per call)."""
     spec = P(None, axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ulysses_attention_local, axis_name=axis_name,
                           scale=scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

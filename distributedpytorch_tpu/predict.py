@@ -394,9 +394,13 @@ class Predictor:
                     "Predictor(mesh=...) is single-process (all local "
                     "devices); multi-host serving should shard requests "
                     "across processes instead")
-            from .parallel.mesh import batch_sharding, replicated_sharding
+            from .parallel.mesh import (
+                batch_sharding,
+                replicated_sharding,
+                traced_on,
+            )
             self._forward = jax.jit(
-                forward, in_shardings=batch_sharding(mesh),
+                traced_on(mesh, forward), in_shardings=batch_sharding(mesh),
                 out_shardings=replicated_sharding(mesh))
 
     @property

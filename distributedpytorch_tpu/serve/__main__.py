@@ -252,12 +252,7 @@ def build_fresh_predictor(spec: str):
     (default ``64:resnet18:head``) — a replica with no checkpoint at
     all, for the fleet's chaos scenarios and dev loops where the test
     is the SERVING MACHINERY (routing, membership, failover), not the
-    weights.  Rides the persistent compile cache so a scenario spawning
-    the same fresh replicas run after run pays the compile ladder
-    once."""
-    from ..backend_health import enable_compile_cache
-
-    enable_compile_cache()
+    weights."""
     import jax
     import optax
 
@@ -278,9 +273,11 @@ def build_fresh_predictor(spec: str):
 
 
 def main(argv: list[str] | None = None) -> int:
-    from ..backend_health import pin_requested_platform
+    from ..backend_health import enable_compile_cache
 
-    pin_requested_platform()
+    # a replica restarted run after run pays its bucket ladder's compiles
+    # once
+    enable_compile_cache()
     parser = argparse.ArgumentParser(
         prog="distributedpytorch_tpu.serve",
         description="TPU-native batched inference service for click-guided "

@@ -461,9 +461,6 @@ def main(argv: list[str] | None = None, predictor=None) -> int:
         if not (args.run_dir or args.torch):
             parser.error("build needs --run-dir or --torch "
                          "(or pass --verify)")
-        from ..backend_health import pin_requested_platform
-
-        pin_requested_platform()
         from .__main__ import build_predictor
 
         predictor = build_predictor(args)

@@ -23,10 +23,10 @@ genuinely concurrent work the per-bucket sums can legitimately exceed
 wall-clock (two threads, one clock); single-threaded runs sum exactly.
 
 MFU (model FLOPs utilization) composes the other half: model FLOPs/step
-(XLA's own cost analysis where available) / step time / device peak
-FLOPs, with the peak table keyed by device kind and a conservative
-fallback (the smallest known TPU peak) for unknown hardware — an
-estimate is always produced, labeled with its source.
+(XLA's own cost analysis) / step time / device peak FLOPs, with the peak
+table keyed by device kind.  A device that is not in the table is an
+error, not a default: a CPU has no MFU, and a TPU generation nobody
+entered gets its row before it gets a number.
 """
 
 from __future__ import annotations
@@ -71,16 +71,12 @@ PEAK_HBM_BY_KIND = {
     "v2": 700e9,
 }
 
-#: unknown hardware (CPU dev boxes, future chips): assume the smallest
-#: known TPU peak — conservative in the sense that it never inflates a
-#: denominator it cannot justify, and the estimate is labeled 'fallback'
-#: so nobody mistakes it for a measured-peak ratio
-FALLBACK_PEAK_FLOPS = min(PEAK_FLOPS_BY_KIND.values())
-
 
 def peak_flops_for(device_kind: str | None = None) -> tuple[float, str]:
-    """(peak FLOP/s, source) for a device kind; source is the matched
-    table key or 'fallback'."""
+    """(peak FLOP/s, matched table key) for a device kind (default: this
+    process's first device).  Raises ``ValueError`` for a kind the table
+    does not hold — callers that may run off-TPU check the platform
+    first and report no MFU there."""
     if device_kind is None:
         import jax
 
@@ -89,7 +85,10 @@ def peak_flops_for(device_kind: str | None = None) -> tuple[float, str]:
     for sub, val in PEAK_FLOPS_BY_KIND.items():
         if sub in kind:
             return val, sub
-    return FALLBACK_PEAK_FLOPS, "fallback"
+    raise ValueError(
+        f"no published peak for device kind {device_kind!r} — add its row "
+        "to telemetry.goodput.PEAK_FLOPS_BY_KIND (and PEAK_HBM_BY_KIND) "
+        "with its source")
 
 
 def mfu_estimate(flops_per_step: float, step_time_s: float,

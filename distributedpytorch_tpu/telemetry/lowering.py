@@ -51,8 +51,6 @@ class LoweredProgram:
         if self._cost is None:
             try:
                 cost = self.compiled.cost_analysis()
-                if isinstance(cost, (list, tuple)):  # older jax: [dict]
-                    cost = cost[0]
                 self._cost = {
                     "flops": float(cost["flops"]),
                     "bytes": float(cost.get("bytes accessed", 0.0)) or None,

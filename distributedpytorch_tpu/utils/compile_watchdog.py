@@ -6,7 +6,7 @@ catches the recompiles that actually happen.  A steady-state train step
 that recompiles (shape drift from a ragged batch, a donation mismatch, a
 Python branch on a tracer) costs seconds-to-minutes of XLA work per
 occurrence and is invisible in wall-clock-only logging — three rounds of
-this repo's perf work (VERDICT r3) chased overheads that a compile counter
+this repo's perf work chased overheads that a compile counter
 would have attributed instantly.
 
 Built on ``jax.log_compiles()``: with it enabled, every in-memory jit-cache

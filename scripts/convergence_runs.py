@@ -1,4 +1,4 @@
-"""Convergence evidence runs (VERDICT r1 item 3): prove the model learns
+"""Convergence evidence runs: prove the model learns
 from pixels, not just from the guidance channel.
 
 Real-chip runs a-d share a 200-image fake-VOC at real image sizes
@@ -15,7 +15,7 @@ Real-chip runs a-d share a 200-image fake-VOC at real image sizes
      perf_sweep variants 11-12); compare curve (d) against curve (a);
   e. large-fixture semantic plateau: DeepLabV3-R101 on a 1,000-image
      fake-VOC to a non-trivial mIoU plateau — the learning-from-pixels
-     evidence VERDICT r2 item 2 prescribes if ablation (b) tracks (a)
+     evidence needed if ablation (b) tracks (a)
      (guidance-copying); report epochs-to-plateau.  NOT in the default
      selection (run only when the a/b outcome calls for it):
      ``python scripts/convergence_runs.py e --epochs 60``.
@@ -45,28 +45,18 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("XLA_PYTHON_CLIENT_MEM_FRACTION", "0.92")
-
-from distributedpytorch_tpu.backend_health import (  # noqa: E402
-    ensure_backend_or_cpu_fallback,
-    pin_requested_platform,
-)
-
-ensure_backend_or_cpu_fallback()
-
+#: JAX_PLATFORMS=cpu asks for the downsized flow check by name
 import jax  # noqa: E402
 
-pin_requested_platform()
+from distributedpytorch_tpu.backend_health import (  # noqa: E402
+    enable_compile_cache,
+    require_accelerator,
+)
 
-from distributedpytorch_tpu.backend_health import enable_compile_cache  # noqa: E402
-
+CPU_SMOKE = require_accelerator("scripts/convergence_runs.py") == "cpu"
 enable_compile_cache()
 
 import numpy as np  # noqa: E402
-
-CPU_SMOKE = "--cpu-smoke" in sys.argv
-if CPU_SMOKE:
-    sys.argv.remove("--cpu-smoke")
 
 EPOCHS = 30
 if "--epochs" in sys.argv:
@@ -79,9 +69,9 @@ if CPU_SMOKE:
 from distributedpytorch_tpu.data.fake import make_fake_voc  # noqa: E402
 from distributedpytorch_tpu.train import Config, Trainer, apply_overrides  # noqa: E402
 
-# val >= 200 (VERDICT r3 item 7): a 20-50-image val split oscillates
+# val >= 200: a 20-50-image val split oscillates
 # +-0.05-0.10 mIoU from single-class flips late-epoch; 200 images makes the
-# curves quotable at the precision BASELINE.md quotes them.  Train counts
+# curves quotable to two decimals.  Train counts
 # stay what rounds 1-3 used (180 small / 1000 big) so curve comparisons
 # against the committed artifacts remain train-scale-identical.
 N_IMAGES = 16 if CPU_SMOKE else 380
@@ -162,8 +152,8 @@ if __name__ == "__main__":
         },
         "d_bf16_scores": {"data.device_guidance": True,
                           "model.pam_score_dtype": "bfloat16"},
-        # g: the accuracy gate for model.bn_fp32_stats=false (VERDICT r3
-        # item 5): run a's config with BN batch stats in bf16, stacked
+        # g: the accuracy gate for model.bn_fp32_stats=false: run a's
+        # config with BN batch stats in bf16, stacked
         # with bf16 PAM scores — compare best/plateau vs runs a and d.
         # bf16 fast-variance cancels hardest on the raw-[0,255] stem BN
         # (test_models pins ~5-10% relative variance error); this run

@@ -2,7 +2,7 @@
 as the token count grows.
 
 The flagship shape (512² crop, output-stride 8) gives 64² = 4096 tokens,
-where the fully-fused XLA einsum wins (BASELINE.md).  Flash attention's
+where the fully-fused XLA einsum won in f32 (2026-07-30).  Flash attention's
 regime is larger token counts — 1024² crops at os=8, or os=4, give 16k-64k
 tokens where the materialized N² score matrix first saturates HBM bandwidth
 and then simply does not fit.  This sweep measures forward+backward time per
@@ -21,32 +21,19 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("XLA_PYTHON_CLIENT_MEM_FRACTION", "0.92")
-
-from distributedpytorch_tpu.backend_health import (  # noqa: E402
-    ensure_backend_or_cpu_fallback,
-    pin_requested_platform,
-)
-
-ensure_backend_or_cpu_fallback()
-
+#: JAX_PLATFORMS=cpu asks for the downsized flow check by name
 import jax  # noqa: E402
 
-pin_requested_platform()
+from distributedpytorch_tpu.backend_health import (  # noqa: E402
+    enable_compile_cache,
+    require_accelerator,
+)
 
-from distributedpytorch_tpu.backend_health import enable_compile_cache  # noqa: E402
-
+CPU_SMOKE = require_accelerator("scripts/pam_crossover.py") == "cpu"
 enable_compile_cache()
 
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-
-CPU_SMOKE = "--cpu-smoke" in sys.argv
-if CPU_SMOKE:
-    sys.argv.remove("--cpu-smoke")
-elif not any(d.platform == "tpu" for d in jax.devices()):
-    print(json.dumps({"error": "no TPU (pass --cpu-smoke for a flow check)"}))
-    sys.exit(1)
 
 from distributedpytorch_tpu.ops.attention import (  # noqa: E402
     blocked_position_attention,
@@ -67,8 +54,10 @@ def impls(n):
     out = {"einsum": lambda q, k, v: position_attention(q, k, v),
            "blocked1024": lambda q, k, v:
                blocked_position_attention(q, k, v, min(1024, n)),
+           # the CPU flow check has no Mosaic: it asks for the interpreter
            "flash512": lambda q, k, v:
-               flash_position_attention(q, k, v, min(512, n), min(512, n))}
+               flash_position_attention(q, k, v, min(512, n), min(512, n),
+                                        interpret=CPU_SMOKE)}
     if not CPU_SMOKE:
         out["flash1024"] = lambda q, k, v: \
             flash_position_attention(q, k, v, min(1024, n), min(1024, n))

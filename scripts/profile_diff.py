@@ -1,6 +1,5 @@
 """Compare two `scripts/profile_step.py` outputs (e.g. b8 vs b16) and name
-what regressed — the analysis half of VERDICT round-2 item 4 ("explain b16
-and b4 with the op profiles").
+what regressed ("explain b16 and b4 with the op profiles").
 
 Raw HLO op names don't line up across batch sizes (XLA re-fuses and
 renumbers: ``fusion.123`` at b8 is not ``fusion.123`` at b16), so the

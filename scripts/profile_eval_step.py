@@ -17,22 +17,16 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("XLA_PYTHON_CLIENT_MEM_FRACTION", "0.92")
 os.environ.setdefault("PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION", "python")
-
-from distributedpytorch_tpu.backend_health import (  # noqa: E402
-    ensure_backend_or_cpu_fallback,
-    pin_requested_platform,
-)
-
-ensure_backend_or_cpu_fallback()
 
 import jax  # noqa: E402
 
-pin_requested_platform()
+from distributedpytorch_tpu.backend_health import (  # noqa: E402
+    enable_compile_cache,
+    require_accelerator,
+)
 
-from distributedpytorch_tpu.backend_health import enable_compile_cache  # noqa: E402
-
+ON_TPU = require_accelerator("scripts/profile_eval_step.py") == "tpu"
 enable_compile_cache()
 
 import dataclasses  # noqa: E402
@@ -47,7 +41,6 @@ OUT = "profile_eval_out"
 if "--out" in sys.argv:
     OUT = sys.argv[sys.argv.index("--out") + 1]
 STEPS = 10
-ON_TPU = any(d.platform == "tpu" for d in jax.devices())
 
 
 def main() -> None:

@@ -1,5 +1,4 @@
-"""Op-level device profile of the flagship train step (VERDICT item 2's
-missing per-op evidence): run N steps under ``jax.profiler.trace``, convert
+"""Op-level device profile of the flagship train step: run N steps under ``jax.profiler.trace``, convert
 the XPlane capture to the XProf "hlo_stats" table, and print the top ops by
 self time as JSON — plus write the raw trace for TensorBoard/xprof.
 
@@ -17,24 +16,18 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("XLA_PYTHON_CLIENT_MEM_FRACTION", "0.92")
 # tensorboard_plugin_profile's generated protos predate protobuf 4's C++
 # fast path; pure-python parsing works and only runs at conversion time.
 os.environ.setdefault("PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION", "python")
 
-from distributedpytorch_tpu.backend_health import (  # noqa: E402
-    ensure_backend_or_cpu_fallback,
-    pin_requested_platform,
-)
-
-ensure_backend_or_cpu_fallback()
-
 import jax  # noqa: E402
 
-pin_requested_platform()
+from distributedpytorch_tpu.backend_health import (  # noqa: E402
+    enable_compile_cache,
+    require_accelerator,
+)
 
-from distributedpytorch_tpu.backend_health import enable_compile_cache  # noqa: E402
-
+ON_TPU = require_accelerator("scripts/profile_step.py") == "tpu"
 enable_compile_cache()
 
 import numpy as np  # noqa: E402
@@ -52,11 +45,10 @@ if "--score-dtype" in sys.argv:
     SCORE_DTYPE = sys.argv[sys.argv.index("--score-dtype") + 1]
 #: --model deeplabv3 profiles BASELINE config 4 (DeepLabV3-R101 os=16 513²,
 #: 21-class multi-output CE, 3-channel input) — the same shape bench.py's
-#: DPTPU_BENCH_MODEL hook measures; VERDICT r3 item 2 wants its op table.
+#: DPTPU_BENCH_MODEL hook measures.
 MODEL = "danet"
 if "--model" in sys.argv:
     MODEL = sys.argv[sys.argv.index("--model") + 1]
-ON_TPU = any(d.platform == "tpu" for d in jax.devices())
 SEMANTIC = MODEL != "danet"
 SIZE = (513 if SEMANTIC else 512) if ON_TPU else 64
 BACKBONE = "resnet101" if ON_TPU else "resnet18"

@@ -67,7 +67,8 @@ class TestDANet:
             np.testing.assert_allclose(np.asarray(oa), np.asarray(ob),
                                        rtol=1e-4, atol=1e-4)
 
-    def test_pam_impl_auto_picks_by_token_count(self, monkeypatch):
+    def test_pam_impl_auto_picks_by_token_count(self, monkeypatch,
+                                                interpreted_kernels):
         """auto = einsum below the measured crossover, flash at/above it;
         both resolve at trace time and agree numerically (flash is exact
         online softmax, interpreted on CPU)."""

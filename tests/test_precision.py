@@ -362,7 +362,7 @@ class TestAsyncOverlapGate:
         mesh = mesh_lib.make_mesh()
 
         def f(x):
-            return mesh_lib.shard_map(
+            return jax.shard_map(
                 lambda v: jax.lax.psum(v, "data"), mesh=mesh,
                 in_specs=P("data"), out_specs=P())(x)
 

@@ -36,9 +36,9 @@ class TestStepTimer:
         s = t.summary()
         assert s["p50_s"] <= s["p99_s"] <= s["max_s"]
 
-    def test_default_sync_is_block_until_ready(self, monkeypatch):
-        # the default path must be UNCHANGED: block_until_ready, never a
-        # value materialization
+    def test_tick_blocks_without_materializing(self, monkeypatch):
+        # a tick waits for the device (block_until_ready) and never copies
+        # the outputs to the host
         import distributedpytorch_tpu.utils.profiling as prof
         calls = []
         monkeypatch.setattr(prof.jax, "block_until_ready",
@@ -48,26 +48,6 @@ class TestStepTimer:
         t = StepTimer(warmup=0)
         t.tick(jnp.zeros(()))
         assert [kind for kind, _ in calls] == ["block"]
-
-    def test_device_get_sync_mode(self, monkeypatch):
-        # opt-in mode for remote-tunneled backends where block_until_ready
-        # can be a no-op (throughput()'s documented hazard): tick must
-        # materialize the outputs instead
-        import distributedpytorch_tpu.utils.profiling as prof
-        calls = []
-        monkeypatch.setattr(prof.jax, "block_until_ready",
-                            lambda o: calls.append(("block", o)))
-        monkeypatch.setattr(prof.jax, "device_get",
-                            lambda o: calls.append(("get", o)))
-        t = StepTimer(warmup=0, sync="device_get")
-        t.tick(jnp.zeros(()))
-        t.tick(jnp.zeros(()))
-        assert [kind for kind, _ in calls] == ["get", "get"]
-        assert t.summary()["steps"] == 1
-
-    def test_unknown_sync_mode_rejected(self):
-        with pytest.raises(ValueError, match="block.*device_get"):
-            StepTimer(sync="nope")
 
 
 class TestPercentile:

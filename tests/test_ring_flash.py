@@ -2,8 +2,11 @@
 
 Both must reproduce ``ops.attention.position_attention`` exactly: ring runs
 sharded over the 8-device CPU mesh; flash runs in pallas interpreter mode
-(the same program Mosaic compiles on TPU).
+(the same program Mosaic compiles on TPU), asked for by name on every call
+— the kernels never pick it themselves.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -14,11 +17,15 @@ from distributedpytorch_tpu.models import DANet, build_model
 from distributedpytorch_tpu.ops import (
     blocked_position_attention,
     channel_attention,
-    flash_channel_attention,
-    flash_position_attention,
+    pallas_attention,
     position_attention,
 )
 from distributedpytorch_tpu.parallel import make_mesh, make_ring_attention
+
+flash_position_attention = functools.partial(
+    pallas_attention.flash_position_attention, interpret=True)
+flash_channel_attention = functools.partial(
+    pallas_attention.flash_channel_attention, interpret=True)
 
 
 from conftest import assert_grads_close as _assert_grads_close
@@ -101,7 +108,7 @@ class TestFlashAttention:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=1e-3)
 
-    def test_danet_flash_impl_forward(self):
+    def test_danet_flash_impl_forward(self, interpreted_kernels):
         m = DANet(nclass=1, backbone_depth=18, output_stride=8,
                   pam_impl="flash", pam_block_size=64)
         x = jnp.zeros((1, 32, 32, 4))
@@ -198,7 +205,7 @@ class TestFlashChannelAttention:
             np.asarray(out, np.float32), np.asarray(ref, np.float32),
             atol=3e-2)
 
-    def test_danet_cam_flash_matches_einsum(self):
+    def test_danet_cam_flash_matches_einsum(self, interpreted_kernels):
         x = jnp.asarray(np.random.RandomState(1).normal(
             size=(1, 32, 32, 4)), jnp.float32)
         m_ein = DANet(nclass=1, backbone_depth=18, output_stride=8)
@@ -223,6 +230,49 @@ class TestFlashChannelAttention:
                     "dropout": jax.random.key(1)}, x, train=False)
 
 
+class TestKernelsUnderMesh:
+    """GSPMD cannot partition a Mosaic call: under a multi-device jit the
+    kernels shard_map themselves onto the local batch shard (the mesh
+    comes from parallel.mesh.traced_on — what make_train_step /
+    make_eval_step wrap their programs in)."""
+
+    def test_runs_on_local_batch_shard(self):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from distributedpytorch_tpu.parallel.mesh import traced_on
+
+        mesh = make_mesh()
+        seen = []
+
+        def local_rows(x):
+            seen.append(x.shape[0])
+            return x
+
+        def f(q, k, v):
+            pallas_attention._on_local_batch(local_rows, q)
+            return (flash_position_attention(q, k, v, 64, 64),
+                    flash_channel_attention(v, 64))
+
+        q, k, v = qkv(b=8, n=128)
+        sh = NamedSharding(mesh, P("data"))
+        pam, cam = jax.jit(traced_on(mesh, f), in_shardings=(sh, sh, sh),
+                           out_shardings=sh)(q, k, v)
+        assert seen == [1]  # 8 rows over 8 devices
+        np.testing.assert_allclose(
+            np.asarray(pam), np.asarray(position_attention(q, k, v)),
+            atol=1e-5)
+        np.testing.assert_allclose(
+            np.asarray(cam), np.asarray(channel_attention(v)), atol=1e-4)
+
+    def test_no_mesh_calls_kernel_directly(self):
+        # single-device programs (predict/serve) trace with no context
+        # mesh and must not grow a shard_map
+        q, k, v = qkv(n=64)
+        jaxpr = str(jax.make_jaxpr(
+            lambda *a: flash_position_attention(*a, 64, 64))(q, k, v))
+        assert "shard_map" not in jaxpr and "pallas_call" in jaxpr
+
+
 class TestAttentionImplKnob:
     """model.attention_impl — one knob, both branches (build_model)."""
 
@@ -239,10 +289,10 @@ class TestAttentionImplKnob:
         real_cam = pa.flash_channel_attention
         monkeypatch.setattr(
             pa, "flash_position_attention",
-            lambda *a, **k: called.add("pam") or real_pam(*a, **k))
+            lambda *a: called.add("pam") or real_pam(*a, interpret=True))
         monkeypatch.setattr(
             pa, "flash_channel_attention",
-            lambda *a, **k: called.add("cam") or real_cam(*a, **k))
+            lambda *a: called.add("cam") or real_cam(*a, interpret=True))
         x = jnp.asarray(np.random.RandomState(2).normal(
             size=(1, 32, 32, 4)), jnp.float32)
         m_auto = build_model("danet", nclass=1, backbone="resnet18",

@@ -1,6 +1,6 @@
 """Semantic (multi-class) segmentation mode: dataset, metrics, end-to-end.
 
-The DeepLabV3 configs of BASELINE.md (configs 1 and 4): per-image class-id
+The DeepLabV3 configs of BASELINE.json (configs 1 and 4): per-image class-id
 masks with in-band 255 void, softmax CE with ignore_index, confusion-matrix
 mIoU gating checkpoints.
 """

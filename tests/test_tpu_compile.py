@@ -1,0 +1,161 @@
+"""Compile for the chip without a chip.
+
+The CPU suite never reaches the Mosaic kernels (``attention_impl=auto``
+picks einsum off-TPU) and runs them only through the pallas interpreter,
+so nothing in it can see a kernel Mosaic refuses or a multi-device program
+that cannot lower.  libtpu compiles for a topology it does not have:
+``get_topology_desc("v5e:2x2")`` gives four compile-only ``TPU v5 lite``
+devices, and everything below is lowered and compiled for them — the
+kernels at the flagship's head shape, and the train and eval steps over
+the 4-device data mesh with the kernels live.  A compile check, not a run:
+``chip_smoke.py`` is the run.
+
+One process only: libtpu holds ``/tmp/libtpu_lockfile`` even compile-only.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from distributedpytorch_tpu.models import build_model
+from distributedpytorch_tpu.models import danet as danet_mod
+from distributedpytorch_tpu.ops import pallas_attention as pa
+from distributedpytorch_tpu.parallel import (
+    create_train_state,
+    make_eval_step,
+    make_train_step,
+)
+from distributedpytorch_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+from distributedpytorch_tpu.train.precision import precision_policy
+
+
+@pytest.fixture(scope="module")
+def v5e_mesh():
+    try:
+        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    except Exception as e:  # no libtpu in this environment
+        pytest.skip(f"no compile-only TPU topology: {e}")
+    devices = np.asarray(topo.devices)
+    assert devices.size == 4 and devices[0].device_kind == "TPU v5 lite"
+    return Mesh(devices.reshape(4, 1), (DATA_AXIS, MODEL_AXIS))
+
+
+def _custom_calls(hlo: str) -> list[str]:
+    """The HLO instructions that ARE Mosaic kernel calls."""
+    return [ln for ln in hlo.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln]
+
+
+def _assert_kernels_local(hlo: str, rows: int):
+    """Three Mosaic calls (flash PAM, CAM energy, CAM apply), each on
+    ``rows`` batch rows, none fed by an all-gather."""
+    calls = _custom_calls(hlo)
+    assert len(calls) == 3, f"{len(calls)} tpu_custom_call(s)"
+    gathered = {m.group(1) for m in
+                re.finditer(r"(%[\w.\-]+) = [^\n]*\ball-gather", hlo)}
+    for ln in calls:
+        out_shape = re.search(r"= \(?\w+\[(\d+),", ln)
+        assert out_shape and int(out_shape.group(1)) == rows, ln[:160]
+        operands = set(re.findall(r"%[\w.\-]+", ln.split("custom-call(", 1)[1]))
+        assert not operands & gathered, ln[:160]
+
+
+def test_kernels_compile_at_flagship_head_shape(v5e_mesh):
+    """B8 · N4096 · C512 bf16 — the DANet-R101 os8 512² head on one chip:
+    forward and value_and_grad of both kernels through Mosaic."""
+    one = NamedSharding(Mesh(v5e_mesh.devices[:1], (DATA_AXIS, MODEL_AXIS)),
+                        P())
+    b, n, c = 8, 4096, 512
+    qk = jax.ShapeDtypeStruct((b, n, c // 8), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((b, n, c), jnp.bfloat16, sharding=one)
+
+    def both(q, k, v):
+        return (pa.flash_position_attention(q, k, v).astype(jnp.float32).sum()
+                + pa.flash_channel_attention(v).astype(jnp.float32).sum())
+
+    fwd = jax.jit(both).lower(qk, qk, v).compile().as_text()
+    assert len(_custom_calls(fwd)) == 3
+    grad = jax.jit(jax.value_and_grad(both, argnums=(0, 1, 2))).lower(
+        qk, qk, v).compile().as_text()
+    assert len(_custom_calls(grad)) == 3
+
+
+@pytest.fixture(scope="module")
+def r18_bf16(v5e_mesh):
+    """The bf16 policy and optimizer for DANet-r18 os8 at 64², with
+    ``auto`` resolving as it does on a TPU host for the module's tests."""
+    policy = precision_policy("bfloat16")
+    tx = optax.sgd(1e-3, momentum=0.9)
+    mp = pytest.MonkeyPatch()
+    mp.setattr(danet_mod, "_on_tpu", lambda: True)
+    try:
+        yield policy, tx
+    finally:
+        mp.undo()
+
+
+def _model_and_state(mesh, tx, cross_replica: bool):
+    model = build_model(
+        "danet", nclass=1, backbone="resnet18", output_stride=8,
+        dtype=jnp.bfloat16,
+        bn_cross_replica_axis=DATA_AXIS if cross_replica else None)
+    assert model.pam_impl == "auto" and model.cam_impl == "auto"
+    shapes = jax.eval_shape(lambda: create_train_state(
+        jax.random.PRNGKey(0), model, tx, (1, 64, 64, 4)))
+    repl = NamedSharding(mesh, P())
+    state = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=repl),
+        shapes)
+    return model, state
+
+
+def _batch(mesh, rows: int):
+    data = NamedSharding(mesh, P(DATA_AXIS))
+    return {
+        "concat": jax.ShapeDtypeStruct((rows, 64, 64, 4), jnp.float32,
+                                       sharding=data),
+        "crop_gt": jax.ShapeDtypeStruct((rows, 64, 64), jnp.float32,
+                                        sharding=data),
+    }
+
+
+@pytest.mark.parametrize("reduce_buckets", [0, 4])
+def test_train_step_compiles_on_four_chips(v5e_mesh, r18_bf16,
+                                           reduce_buckets):
+    """The GSPMD step (the Config default) and the bucketed shard_map
+    step both lower with the kernels on B/4 rows."""
+    policy, tx = r18_bf16
+    model, state = _model_and_state(v5e_mesh, tx,
+                                    cross_replica=bool(reduce_buckets))
+    step = make_train_step(model, tx, mesh=v5e_mesh, precision=policy,
+                           reduce_buckets=reduce_buckets)
+    hlo = step.lower(state, _batch(v5e_mesh, 8)).compile().as_text()
+    _assert_kernels_local(hlo, rows=2)
+    assert "all-reduce" in hlo  # the gradient reduction is still there
+
+
+def test_eval_step_compiles_on_four_chips(v5e_mesh, r18_bf16):
+    _, tx = r18_bf16
+    model, state = _model_and_state(v5e_mesh, tx, cross_replica=False)
+    ev = make_eval_step(model, mesh=v5e_mesh)
+    hlo = ev.lower(state, _batch(v5e_mesh, 8)).compile().as_text()
+    _assert_kernels_local(hlo, rows=2)
+
+
+def test_init_compiles_on_four_chips(v5e_mesh, r18_bf16):
+    """``create_train_state`` traces the forward on a 1-row dummy with no
+    context mesh.  That is sound only because the parameters do not depend
+    on the forward, so it is dead code by the time Mosaic would refuse a
+    4-device program — this is the guard for that assumption."""
+    _, tx = r18_bf16
+    model = build_model("danet", nclass=1, backbone="resnet18",
+                        output_stride=8, dtype=jnp.bfloat16)
+    init = jax.jit(lambda: create_train_state(
+        jax.random.PRNGKey(0), model, tx, (1, 64, 64, 4), mesh=v5e_mesh))
+    assert "tpu_custom_call" not in init.lower().compile().as_text()

@@ -1,4 +1,4 @@
-"""Val fast path (data.val_prepared, VERDICT r3 item 3): prepared eval
+"""Val fast path (data.val_prepared): prepared eval
 caches, the uint8 val wire, device-guidance eval preprocessing, and metric
 parity against the plain (uncached) validation protocol.
 
@@ -11,8 +11,7 @@ cacheable.  What these tests pin down:
   + host-side ``gt``/``void_pixels``/``bbox``), with the full-res masks
   BIT-EXACT vs the plain pipeline (they feed the metric; rounding there
   would change reported Jaccards);
-* the uint8 wire serves uint8 (the measured 25 MB f32 semantic val batch
-  was the 1 img/s bound, BASELINE.md ‡);
+* the uint8 wire serves uint8 (the f32 semantic val batch is 25 MB);
 * the end-to-end metric matches the plain path.
 """
 
