@@ -105,6 +105,60 @@ def _first_batch(loader) -> dict:
     return {k: batch[k] for k in DEVICE_KEYS if k in batch}
 
 
+def _scope_capture(trainer) -> dict:
+    """Arm one 4-step ``TraceCapture`` (what ``kill -USR2`` does), train an
+    epoch under it and one without, and check what the capture left: the
+    scope table of the step that ran, not stale, resolving the trace, with
+    backbone / head / loss / optimizer in it, and the device's idle gaps
+    named by the program's own host spans."""
+    from distributedpytorch_tpu.telemetry import scopes
+
+    def epoch_step_s(epoch):
+        before = int(trainer.state.step)
+        t0 = time.perf_counter()
+        trainer.train_epoch(epoch)
+        steps = int(trainer.state.step) - before
+        return (time.perf_counter() - t0) / steps, steps
+
+    target = trainer._trace.request(steps=4)
+    assert target is not None
+    with_capture_s, steps = epoch_step_s(1)
+    trainer._trace.close()  # a short epoch ends before the fourth tick
+    without_s, _ = epoch_step_s(2)
+    with open(os.path.join(target, "scope_summary.json")) as f:
+        summary = json.load(f)
+    with open(os.path.join(target, "scope_table.json")) as f:
+        table = json.load(f)
+    assert not summary["stale"] and not table["stale"], table["differing"]
+    assert summary["devices"] == len(jax.devices()), summary["devices"]
+    assert summary["steps"] >= min(4, steps), summary["steps"]
+    assert summary["unresolved_share"] < 0.01, summary["unresolved_share"]
+    layers = summary["ms_per_step_by_layer"]
+    for layer in ("backbone", "head", scopes.LOSS, scopes.OPTIMIZER):
+        assert layers.get(layer, 0) > 0, (layer, layers)
+    # host and device on one clock: the goodput buckets are spans of the
+    # trace, and an idle gap is named by one of the program's spans or by
+    # none (the loop's logging between two steps is in no bucket)
+    spans = summary["host_spans"]
+    for name in ("goodput/step", "goodput/input_wait", scopes.STEP_ANNOTATION):
+        assert spans.get(name, 0) > 0, (name, spans)
+    assert all(g[0] in spans or g[0] == "no host span"
+               for g in summary["idle_gaps"]), summary["idle_gaps"]
+    return {"step_s_with_capture": round(with_capture_s, 4),
+            "step_s_without": round(without_s, 4), "epoch_steps": steps,
+            "traced_steps": summary["steps"],
+            "busy_ms_per_step": round(summary["busy_ms_per_step"], 3),
+            "ms_per_step_by_layer_phase": {
+                k: round(v, 3) for k, v in
+                summary["ms_per_step_by_layer_phase"].items()},
+            "collectives_ms_per_step": {
+                k: round(v, 3) for k, v in
+                summary["ms_per_step_collectives_by_layer"].items()},
+            "mixed_share": round(summary["mixed_share"], 4),
+            "unresolved_share": round(summary["unresolved_share"], 5),
+            "host_spans": spans, "idle_gaps": summary["idle_gaps"][:5]}
+
+
 def main(argv: list[str]) -> int:
     dev = jax.devices()
     if dev[0].platform != "tpu":
@@ -229,6 +283,10 @@ def main(argv: list[str]) -> int:
                     n_calls = hlo.count(
                         'custom_call_target="tpu_custom_call"')
                     assert n_calls == 3, f"{name}: {n_calls} tpu_custom_call"
+            # --- a capture armed on the live trainer leaves its answer:
+            # one more epoch with a 4-step capture, one without, both warm
+            with stage("scope_capture"):
+                report["scope_capture"] = _scope_capture(trainer)
         finally:
             trainer.close()
 

@@ -48,6 +48,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..telemetry import scopes
 from .attention import blocked_position_attention, channel_attention
 
 _NEG_INF = -1e30
@@ -146,6 +147,10 @@ def _flash_local(q, k, v, *, block_q: int, block_k: int,
             pltpu.VMEM((block_q, cv), jnp.float32),    # accumulator
         ],
         interpret=interpret,
+        # the call's name is its innermost scope, and the TPU compiler names
+        # the custom-call after that: the trace shows ``%pam`` whatever
+        # encloses the call (a module, a shard_map)
+        name=scopes.PAM_KERNEL,
     )(q, k, v)
     return out[:, :n, :]
 
@@ -183,8 +188,9 @@ def _bwd(block_q, block_k, scale, interpret, res, g):
         if scale is not None:  # score scaling == scaling q
             q_ = q_ * scale
         return blocked_position_attention(q_, k_, v_, block_size=block_k)
-    _, vjp = jax.vjp(ref, q, k, v)
-    return vjp(g)
+    with jax.named_scope(scopes.PAM_BWD):
+        _, vjp = jax.vjp(ref, q, k, v)
+        return vjp(g)
 
 
 flash_position_attention.defvjp(_fwd, _bwd)
@@ -244,6 +250,7 @@ def _cam_local(x, *, block_n: int, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((b, c, c), jnp.float32),
         scratch_shapes=[pltpu.VMEM((c, c), jnp.float32)],
         interpret=interpret,
+        name=scopes.CAM_ENERGY,
     )(x)
     out = pl.pallas_call(
         _cam_apply_kernel,
@@ -255,6 +262,7 @@ def _cam_local(x, *, block_n: int, interpret: bool):
         out_specs=pl.BlockSpec((1, block_n, c), lambda b_, j: (b_, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b, nb * block_n, c), x.dtype),
         interpret=interpret,
+        name=scopes.CAM_APPLY,
     )(attn, x)
     return out[:, :n, :]
 
@@ -285,8 +293,9 @@ def _cam_bwd(block_n, interpret, res, g):
     # Recompute with the jnp reference form and differentiate that — the
     # gram is cheap to rebuild (one (C, C) matmul) vs storing the
     # attention map's softmax residuals.
-    _, vjp = jax.vjp(channel_attention, x)
-    return vjp(g)
+    with jax.named_scope(scopes.CAM_BWD):
+        _, vjp = jax.vjp(channel_attention, x)
+        return vjp(g)
 
 
 flash_channel_attention.defvjp(_cam_fwd, _cam_bwd)

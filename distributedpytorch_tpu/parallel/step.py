@@ -40,6 +40,7 @@ from flax import struct
 from flax.core import unfreeze
 
 from ..ops import multi_output_loss, se_presence_loss, softmax_xent_ignore
+from ..telemetry import scopes
 from . import mesh as mesh_lib
 
 Batch = Mapping[str, jax.Array]
@@ -192,8 +193,9 @@ def _bucketed_psum(grads, n_buckets: int, axis_name: str):
     the later buckets)."""
     leaves, treedef = jax.tree.flatten(grads)
     out = list(leaves)
-    for bucket in bucket_grad_leaves(leaves, n_buckets):
-        reduced = jax.lax.psum([leaves[i] for i in bucket], axis_name)
+    for k, bucket in enumerate(bucket_grad_leaves(leaves, n_buckets)):
+        with jax.named_scope(scopes.bucket_scope(k)):
+            reduced = jax.lax.psum([leaves[i] for i in bucket], axis_name)
         for i, g in zip(bucket, reduced):
             out[i] = g
     return jax.tree.unflatten(treedef, out)
@@ -383,11 +385,12 @@ def _loss_and_updates(model, params, batch_stats, batch: Batch, rng,
         outputs = model.apply(variables, inputs, train=False)
         new_stats = batch_stats
         aux = jnp.float32(0.0)
-    if precision is not None:
-        outputs = precision.cast_to_loss(outputs)
-    loss = _compute_loss(outputs, batch, loss_weights, loss_type)
-    if aux_loss_weight:
-        loss = loss + aux_loss_weight * aux
+    with jax.named_scope(scopes.LOSS):
+        if precision is not None:
+            outputs = precision.cast_to_loss(outputs)
+        loss = _compute_loss(outputs, batch, loss_weights, loss_type)
+        if aux_loss_weight:
+            loss = loss + aux_loss_weight * aux
     return loss, new_stats
 
 
@@ -526,7 +529,8 @@ def make_train_step(
         (_, (loss, new_stats)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)
         if loss_scale != 1.0:
-            grads = jax.tree.map(lambda g: g / loss_scale, grads)
+            with jax.named_scope(scopes.OPTIMIZER):
+                grads = jax.tree.map(lambda g: g / loss_scale, grads)
         return loss, new_stats, grads
 
     def accum_grads_of(params, batch_stats, batch, rng):
@@ -573,10 +577,11 @@ def make_train_step(
             loss, new_stats, grads = accum_grads_of(
                 params, batch_stats, batch, rng)
             n = jax.lax.axis_size(mesh_lib.DATA_AXIS)
-            grads = _bucketed_psum(grads, reduce_buckets,
-                                   mesh_lib.DATA_AXIS)
-            grads = jax.tree.map(lambda g: g / n, grads)
-            loss = jax.lax.pmean(loss, mesh_lib.DATA_AXIS)
+            with jax.named_scope(scopes.GRAD_REDUCE):
+                grads = _bucketed_psum(grads, reduce_buckets,
+                                       mesh_lib.DATA_AXIS)
+                grads = jax.tree.map(lambda g: g / n, grads)
+                loss = jax.lax.pmean(loss, mesh_lib.DATA_AXIS)
             # new_stats are already identical across devices (the model's
             # cross-replica BN pmean'd them) — returned replicated as-is
             return loss, new_stats, grads
@@ -606,8 +611,19 @@ def make_train_step(
         loss, new_stats, grads = differentiate(
             state.params, state.batch_stats, dict(batch), rng)
 
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, new_opt = tx.update(grads, state.opt_state,
+                                         state.params)
+            new_params = optax.apply_updates(state.params, updates)
+            if sentinel_metrics:
+                # sentinel.monitor_grads: global grad norm + the
+                # update/param ratio (a single update rewriting a
+                # macroscopic fraction of the weights is divergence even at
+                # a plausible loss)
+                gnorm = optax.global_norm(grads)
+                ratio = optax.global_norm(updates) / (
+                    optax.global_norm(state.params) + 1e-12)
+                loss = (loss, jnp.stack([gnorm, ratio]))
         new_state = state.replace(
             step=state.step + 1,
             params=new_params,
@@ -615,14 +631,6 @@ def make_train_step(
             opt_state=new_opt,
             rng=new_rng,
         )
-        if sentinel_metrics:
-            # sentinel.monitor_grads: global grad norm + the update/param
-            # ratio (a single update rewriting a macroscopic fraction of
-            # the weights is divergence even at a plausible loss)
-            gnorm = optax.global_norm(grads)
-            ratio = optax.global_norm(updates) / (
-                optax.global_norm(state.params) + 1e-12)
-            return new_state, (loss, jnp.stack([gnorm, ratio]))
         return new_state, loss
 
     if steps_per_call > 1:
@@ -694,7 +702,8 @@ def make_eval_step(model, loss_weights: tuple[float, ...] | None = None,
         variables = {"params": state.params,
                      "batch_stats": state.batch_stats}
         outputs = model.apply(variables, batch[INPUT_KEY], train=False)
-        loss = _compute_loss(outputs, batch, loss_weights, loss_type)
+        with jax.named_scope(scopes.LOSS):
+            loss = _compute_loss(outputs, batch, loss_weights, loss_type)
         return outputs, loss
 
     if mesh is None:

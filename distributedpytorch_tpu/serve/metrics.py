@@ -20,7 +20,8 @@ totals, the service reports its own.
 Latency is end-to-end request latency (submit -> mask handed back), the
 number a client actually experiences: queue wait + batching wait + forward
 + paste-back.  Percentiles use the nearest-rank rule shared with the train
-side (:func:`utils.profiling.percentile` — StepTimer-style accounting)
+side (:func:`utils.profiling.percentile` — an observed sample, never an
+interpolation)
 over a bounded reservoir of the most recent samples, so a long-lived
 service reports its CURRENT tail, not a mush of every request since boot.
 """

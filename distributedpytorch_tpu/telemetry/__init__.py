@@ -12,6 +12,9 @@ One process-wide surface for "what is this process doing":
 * :mod:`prometheus` — text exposition for ``GET /metrics``;
 * :mod:`trace`      — on-demand bounded ``jax.profiler`` capture
   (SIGUSR2 / ``POST /debug/trace``) without restarting the process;
+* :mod:`scopes`     — the join of a traced device op and the part of the
+  step it belongs to (backbone / head / loss / optimizer / kernel, forward
+  or backward), written beside every capture as ``scope_summary.json``;
 * :mod:`lowering`   — process-wide trace/lower/compile cache shared by
   the MFU estimator and the IR auditor (``analysis.ir``), so each hot
   program is lowered exactly once;
@@ -29,7 +32,8 @@ Every future perf PR reports into this layer; the train loop, the
 checkpoint manager, the evaluator and the serve front are already wired.
 """
 
-from . import events, goodput, lowering, prometheus, registry, spans, timeline, trace
+from . import (events, goodput, lowering, prometheus, registry, scopes, spans,
+               timeline, trace)
 from .events import EventLog, events_block
 from .timeline import Timeline, load_timeline
 from .goodput import (
@@ -54,5 +58,5 @@ __all__ = [
     "goodput", "is_enabled", "load_timeline", "lower_cached", "lowering",
     "mfu_estimate",
     "peak_flops_for", "prometheus", "registry", "render_text",
-    "set_enabled", "span", "spans", "timeline", "trace",
+    "scopes", "set_enabled", "span", "spans", "timeline", "trace",
 ]

@@ -148,6 +148,15 @@ def goodput_breakdown(tl: Timeline) -> dict:
     }
 
 
+def trace_summaries(tl: Timeline) -> list[dict]:
+    """Every ``trace_summary`` a capture left on the timeline
+    (:class:`telemetry.trace.TraceCapture`): where the trace is, and device
+    milliseconds per step by layer."""
+    return [dict(e.get("payload") or {}, step=e.get("step"))
+            for e in tl.events
+            if e["source"] == "telemetry" and e["kind"] == "trace_summary"]
+
+
 def detect_findings(tl: Timeline, path: str,
                     metrics: dict[str, float] | None = None,
                     thresholds: dict | None = None) -> list[dict]:
@@ -374,6 +383,7 @@ def diagnose(path: str, metrics: dict[str, float] | None = None,
         "verdict": worst,
         "timeline": tl.to_dict(),
         "goodput": goodput_breakdown(tl),
+        "traces": trace_summaries(tl),
         "fit_summaries": [name for name, _ in _fit_summaries(path)],
         "findings": findings,
     }
@@ -399,6 +409,14 @@ def render(report: dict) -> str:
             f"{gp['total_s']}s ({gp['fits']} fit(s))")
         for sink in gp["top_sinks"]:
             add(f"  sink: {sink['bucket']:<12} {sink['seconds']}s")
+    for tr in report.get("traces", ()):
+        layers = ", ".join(
+            f"{k} {v:.2f}" for k, v in sorted(
+                (tr.get("ms_per_step_by_layer") or {}).items(),
+                key=lambda kv: -kv[1]))
+        add(f"trace: {tr.get('trace_dir')} ({tr.get('steps')} steps"
+            f"{', STALE table' if tr.get('stale') else ''}) "
+            f"ms/step by layer: {layers}")
     add(f"episodes: {len(tl['episodes'])}")
     for ep in tl["episodes"]:
         state = "resolved" if ep["resolved"] else "UNRESOLVED"

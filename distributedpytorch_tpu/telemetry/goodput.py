@@ -36,7 +36,9 @@ import contextlib
 import threading
 import time
 
+from . import trace as trace_lib
 from .registry import MetricsRegistry, get_registry
+from .scopes import GOODPUT_PREFIX
 
 #: the closed attribution set (order = reporting order)
 BUCKETS = ("step", "compile", "checkpoint", "eval", "input_wait")
@@ -135,7 +137,7 @@ class _Account:
     the generator-based form costs ~2x more per entry, and this sits on
     the step loop's per-iteration path (the <=2%-overhead contract)."""
 
-    __slots__ = ("_a", "bucket")
+    __slots__ = ("_a", "bucket", "_annotation")
 
     def __init__(self, a: "GoodputAccountant", bucket: str):
         if bucket not in a._seconds:
@@ -143,9 +145,19 @@ class _Account:
                              f"(one of {BUCKETS})")
         self._a = a
         self.bucket = bucket
+        self._annotation = None
 
     def __enter__(self) -> "_Account":
         a = self._a
+        if trace_lib.capturing():
+            # one clock for host and device: while a capture records, the
+            # bucket is a span on the profiler's own timeline, so a device
+            # idle gap under it has a name.  Off: the one read above.
+            import jax
+
+            self._annotation = jax.profiler.TraceAnnotation(
+                GOODPUT_PREFIX + self.bucket)
+            self._annotation.__enter__()
         stack = a._stack()
         now = time.perf_counter()
         if stack:  # pause the outer bucket's clock
@@ -165,6 +177,9 @@ class _Account:
         a._credit(self.bucket, now - t0)
         if stack:  # resume the outer bucket's clock
             stack[-1] = (stack[-1][0], now)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
         return False
 
 

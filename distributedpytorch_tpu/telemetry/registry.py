@@ -1,7 +1,7 @@
 """Process-wide metrics registry: counters, gauges, histograms.
 
 Before this package, every subsystem kept private observability state —
-``train/logging.py`` writers, ``utils/profiling.StepTimer`` lists,
+``train/logging.py`` writers, per-step timing lists,
 ``serve/metrics.ServeMetrics`` counters, the CompileWatchdog's counts —
 with no shared surface, so "what is this process doing" had no single
 answer.  The registry is that surface: one thread-safe, process-wide
@@ -15,7 +15,7 @@ Three primitive kinds, deliberately small:
 * :class:`Gauge`    — last-write-wins float (queue depth, goodput ratio);
 * :class:`Histogram`— bounded reservoir of recent samples with
   nearest-rank percentiles (:func:`utils.profiling.percentile` — the
-  same rule StepTimer and the serve latency tail already use) plus
+  same rule the serve latency tail already uses) plus
   monotonic ``count``/``sum`` so rates stay derivable after the
   reservoir wraps.
 
@@ -106,7 +106,7 @@ class Histogram:
     not sit in p99 forever); ``count``/``sum`` stay monotonic over the
     process lifetime so Prometheus-side rate() works across the wrap.
     Percentiles are nearest-rank — an observed sample, never an
-    interpolation (the convention shared with StepTimer and serve).
+    interpolation (the convention shared with the serve tail).
     """
 
     __slots__ = ("labels", "_lock", "_samples", "_count", "_sum")

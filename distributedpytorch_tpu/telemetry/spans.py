@@ -23,6 +23,14 @@ import time
 from .registry import MetricsRegistry, get_registry, is_enabled
 
 _tls = threading.local()
+#: every path a span has been entered under in this process: how a captured
+#: trace's reader (telemetry.scopes) tells the program's own host spans from
+#: the profiler's
+_seen: set = set()
+
+
+def seen_paths() -> frozenset:
+    return frozenset(_seen)
 
 
 def current_span() -> str:
@@ -50,6 +58,7 @@ def span(name: str, registry: MetricsRegistry | None = None):
         stack = _tls.stack = []
     stack.append(name)
     path = "/".join(stack)
+    _seen.add(path)
     annotation = None
     try:
         # deferred import: jax must not load just because telemetry did

@@ -1,14 +1,26 @@
 """On-demand, bounded ``jax.profiler`` trace capture — no restart needed.
 
-The existing profiling story required deciding BEFORE launch
-(``profile_epoch`` config, ``utils.profiling.trace`` around a region);
-the interesting step regression always shows up mid-run.
-:class:`TraceCapture` arms a capture from the outside of a live process —
+Deciding BEFORE launch (``profile_epoch`` config) is one way in; the
+interesting step regression always shows up mid-run.  :class:`TraceCapture`
+is the one capture path for both: :meth:`TraceCapture.region` profiles a
+whole region (``profile_epoch``), and a capture is armed from the outside
+of a live process —
 ``SIGUSR2`` on the trainer, ``POST /debug/trace?steps=N`` on the serve
 front — and the owning loop drives it with one cheap :meth:`tick` per
 step/batch: the next tick after a request starts the trace, N ticks later
 it stops, and the XPlane files land under the run dir
 (``trace_on_demand/trace_NNN``) for tensorboard/xprof.
+
+Every capture comes with its answer: where the owning loop has named the
+step program that ran (:meth:`note_program`), ``scope_table.json`` (each
+instruction of that program put down to a layer, a module path and
+forward / backward / optimizer, :mod:`telemetry.scopes`) and
+``scope_summary.json`` (device milliseconds per step by layer, by layer and
+phase, the heaviest module paths, the longest idle gaps by host span) land
+beside the XPlane files, and one ``trace_summary`` event goes into the
+flight recorder.  While a capture is active :func:`capturing` is true:
+``GoodputAccountant.account`` and the trainer's dispatch then annotate the
+profiler's own timeline, and make no profiler call otherwise.
 
 Safety properties, each deliberate:
 
@@ -30,12 +42,23 @@ Safety properties, each deliberate:
 
 from __future__ import annotations
 
+import contextlib
+import json
 import os
 import signal
 import threading
 import time
 
 from .registry import MetricsRegistry, get_registry
+
+#: a capture is recording in this process (``jax.profiler`` allows one)
+_capturing = False
+
+
+def capturing() -> bool:
+    """Whether a :class:`TraceCapture` is recording right now: the one read
+    that host annotations (``goodput/<bucket>``, the train step) are behind."""
+    return _capturing
 
 
 class TraceCapture:
@@ -65,10 +88,13 @@ class TraceCapture:
         self._want = 0
         # active-capture state: owned exclusively by the tick()er's thread
         self._active = False
-        self._remaining = 0
+        self._remaining = 0   # None: a region, closed by its ``with``
         self._started = 0.0
         self._dir = ""
         self._captures = 0
+        #: the step program that ran in this capture: (jitted, abstract
+        #: args), named by the owning loop (note_program)
+        self._program = None
 
     # ------------------------------------------------------------- arming
     @property
@@ -116,6 +142,8 @@ class TraceCapture:
         """Advance by ``n`` imminent steps (0 = just service the time
         backstop).  Called from exactly one thread — the step loop."""
         if self._active:
+            if self._remaining is None:
+                return  # a region: its ``with`` closes it
             if self._remaining <= 0 or \
                     time.perf_counter() - self._started > self.max_seconds:
                 self._stop()
@@ -136,27 +164,64 @@ class TraceCapture:
         if self._active:
             self._stop()
 
+    @contextlib.contextmanager
+    def region(self, log_dir: str):
+        """Capture the enclosed region into ``log_dir``, however long it
+        runs (``profile_epoch``).  Same start, stop and scope files as an
+        armed capture; refused (a no-op) while another is active."""
+        ours = not self._active
+        if ours:
+            self._pending_dir = log_dir
+            self._start(None)
+            self._remaining = None
+        try:
+            yield self
+        finally:
+            if ours and self._active:
+                self._stop()
+
+    def note_program(self, jitted, args) -> None:
+        """Name the step program this capture is tracing, so that the
+        capture can be attributed when it stops.  ``args`` may be concrete:
+        only their shapes and dtypes are kept.  The owning loop calls it at
+        each dispatch while :attr:`active`; the first program named stays."""
+        if not self._active or self._program is not None:
+            return
+        import jax
+
+        self._program = (jitted, jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args))
+
     # ------------------------------------------------------------ internals
     def _reg(self) -> MetricsRegistry:
         return self._registry or get_registry()
 
-    def _start(self, steps: int) -> None:
+    def _failed(self, doing: str, e: Exception) -> None:
+        """Counted and printed, never raised into the owning loop."""
+        self._reg().counter("trace_capture_failures_total",
+                            "on-demand trace captures that failed").inc()
+        print(f"telemetry: trace capture failed to {doing}: {e!r}",
+              flush=True)
+
+    def _start(self, steps: int | None) -> None:
         import jax
 
+        global _capturing
         self._dir = getattr(self, "_pending_dir", None) or os.path.join(
             self.log_dir, f"trace_{self._captures:03d}")
+        self._pending_dir = None
+        self._program = None
         try:
             os.makedirs(self._dir, exist_ok=True)
             jax.profiler.start_trace(self._dir)
         except Exception as e:  # another trace active, or profiler error
-            self._reg().counter("trace_capture_failures_total",
-                                "on-demand trace captures that failed").inc()
-            print(f"telemetry: trace capture failed to start: {e}",
-                  flush=True)
+            self._failed("start", e)
             return
         self._active = True
+        _capturing = True
         self._started = time.perf_counter()
-        print(f"telemetry: capturing {steps}-step trace -> {self._dir}",
+        what = "a region's" if steps is None else f"{steps}-step"
+        print(f"telemetry: capturing {what} trace -> {self._dir}",
               flush=True)
 
     def _stop(self) -> None:
@@ -165,16 +230,49 @@ class TraceCapture:
         try:
             jax.profiler.stop_trace()
         except Exception as e:
-            self._reg().counter("trace_capture_failures_total",
-                                "on-demand trace captures that failed").inc()
-            print(f"telemetry: trace capture failed to stop: {e}",
-                  flush=True)
+            self._failed("stop", e)
         else:
             self._reg().counter("trace_captures_total",
                                 "on-demand trace captures completed").inc()
             print(f"telemetry: trace written -> {self._dir}", flush=True)
+            self._write_scope_files()
+        global _capturing
+        _capturing = False
         self._active = False
         self._captures += 1
+
+    def _write_scope_files(self) -> None:
+        """``scope_table.json`` and ``scope_summary.json`` beside the trace,
+        and the ``trace_summary`` event.  Nothing where no program was named
+        (the serve front: the served forward has no table yet).  A failure
+        is counted and printed, never raised."""
+        if self._program is None:
+            return
+        from . import events, scopes
+
+        t0 = time.perf_counter()
+        try:
+            jitted, args = self._program
+            # never recompile under a live loop: a table from a cache entry
+            # older than this tree's scopes is written marked ``stale``
+            table = scopes.table_for(jitted, *args, allow_recompile=False)
+            summary = scopes.summarize_capture(
+                scopes.read_device_events(self._dir), table)
+            summary["trace_dir"] = self._dir
+            for name, doc in (("scope_table.json", table.to_json()),
+                              ("scope_summary.json", summary)):
+                with open(os.path.join(self._dir, name), "w") as f:
+                    json.dump(doc, f)
+            events.emit("telemetry", "trace_summary", payload={
+                k: summary.get(k) for k in (
+                    "trace_dir", "stale", "devices", "steps",
+                    "busy_ms_per_step", "ms_per_step_by_layer",
+                    "mixed_share", "unresolved_share", "idle_gaps")})
+            print(f"telemetry: scope summary -> {self._dir}/"
+                  f"scope_summary.json ({time.perf_counter() - t0:.1f} s)",
+                  flush=True)
+        except Exception as e:
+            self._failed("write its scope summary", e)
 
 
 #: serve-side convenience: arm via HTTP thread, driven by the worker loop

@@ -56,7 +56,7 @@ from ..parallel import (
     prefetch_to_device,
 )
 from ..parallel import plan as plan_lib
-from ..telemetry import TraceCapture, get_accountant, mfu_estimate
+from ..telemetry import TraceCapture, get_accountant, mfu_estimate, scopes
 from ..telemetry import events as events_lib
 from ..telemetry import set_enabled as telemetry_set_enabled
 from ..utils.helpers import generate_param_report
@@ -85,6 +85,10 @@ from .optim import make_optimizer
 from .precision import precision_policy
 from .preemption import PreemptionGuard
 from .sentinel import StepSentinel
+
+
+#: the dispatch's step annotation while no capture records
+_NO_STEP_MARK = contextlib.nullcontext()
 
 
 class _RollbackBudgetTick(Exception):
@@ -801,7 +805,8 @@ class Trainer:
         self._flops_per_step: float | None = None
         self._trace = TraceCapture(
             os.path.join(self.run_dir, "trace_on_demand")) \
-            if (cfg.telemetry and self.is_main) else None
+            if ((cfg.telemetry or cfg.profile_epoch is not None)
+                and self.is_main) else None
         # --- input-feed governor (data/governor.py): closes the loop
         # from the measured input_wait fraction to the pipeline knobs.
         # `observe` builds on the main process only (secondary hosts
@@ -1605,10 +1610,20 @@ class Trainer:
                 'compile'; repeats are productive 'step' time.  The trace
                 trigger ticks BEFORE the call so an armed capture starts
                 on (not after) the step it was requested for."""
+                step_mark = _NO_STEP_MARK
                 if self._trace is not None:
                     self._trace.tick(n)
+                    if self._trace.active:
+                        # a capture records: the dispatch is a step on the
+                        # profiler's timeline, and the capture learns which
+                        # program to put its device ops down to.  Off: the
+                        # one attribute read above, no JAX call.
+                        step_mark = jax.profiler.StepTraceAnnotation(
+                            scopes.STEP_ANNOTATION,
+                            step_num=step0 + steps_done)
+                        self._trace.note_program(fn, (self.state, *args))
                 first = key not in self._programs_seen
-                with acct.account("compile" if first else "step"):
+                with step_mark, acct.account("compile" if first else "step"):
                     self.state, out = fn(self.state, *args)
                 if first:
                     self._programs_seen.add(key)
@@ -2288,12 +2303,14 @@ class Trainer:
                 sb = self._resume_start_batch  # only the run's first epoch
                 self._resume_start_batch = 0
                 estep0 = int(self.state.step)
-                if cfg.profile_epoch == epoch and self.is_main:
-                    # On-demand op-level device trace (SURVEY §5.1: the
-                    # reference had only wall-clock prints).  One epoch,
-                    # written under the run dir for tensorboard/xprof.
-                    from ..utils.profiling import trace
-                    ctx = trace(os.path.join(self.run_dir, "profile"))
+                if cfg.profile_epoch == epoch and self._trace is not None:
+                    # Op-level device trace of one epoch (SURVEY §5.1: the
+                    # reference had only wall-clock prints), through the
+                    # one capture path: XPlane files for tensorboard/xprof
+                    # plus scope_table.json / scope_summary.json under the
+                    # run dir.
+                    ctx = self._trace.region(
+                        os.path.join(self.run_dir, "profile"))
                 else:
                     ctx = contextlib.nullcontext()
                 try:

@@ -2,9 +2,8 @@
 
 from . import compile_watchdog, helpers, profiling, torch_interop
 from .compile_watchdog import CompileWatchdog, RecompileError
-from .profiling import (StepTimer, annotate, device_memory_stats,
-                        throughput, trace)
+from .profiling import device_memory_stats, throughput
 
-__all__ = ["CompileWatchdog", "RecompileError", "StepTimer", "annotate",
-           "compile_watchdog", "device_memory_stats", "helpers",
-           "profiling", "throughput", "torch_interop", "trace"]
+__all__ = ["CompileWatchdog", "RecompileError", "compile_watchdog",
+           "device_memory_stats", "helpers", "profiling", "throughput",
+           "torch_interop"]

@@ -1,46 +1,25 @@
-"""Tracing / profiling utilities.
+"""Measurement helpers that are not the profiler.
 
 The reference's only performance instrumentation was wall-clock epoch timing
 with ``timeit.default_timer`` printed to stdout (reference
-train_pascal.py:12,181,307-308) — no profiler, no NVTX, no per-step numbers
-(SURVEY.md §5.1).  TPU-native replacements:
+train_pascal.py:12,181,307-308) — no profiler, no per-step numbers
+(SURVEY.md §5.1).  Device traces are :class:`telemetry.trace.TraceCapture`'s
+(one capture path: ``profile_epoch``, SIGUSR2, ``POST /debug/trace``), and
+what a trace means is :mod:`telemetry.scopes`'.  Here:
 
-* :func:`trace` — context manager around ``jax.profiler`` writing a
-  TensorBoard-loadable XPlane trace (op-level device timeline, HBM usage,
-  fusion view) for any code region;
-* :class:`StepTimer` — per-step *latency* timing (block on a representative
-  output, read the clock, skip warmup).  Measures launch + sync round-trip,
-  which is the right number for interactive latency but NOT for throughput:
-  a per-step sync drains the dispatch pipeline every step, so for throughput
-  always use :func:`throughput` instead;
-* :func:`annotate` — named ``TraceAnnotation`` regions that show up inside
-  the device trace (host-side markers).
+* :func:`throughput` — steady-state rate with dispatch fully pipelined
+  (dispatch everything, synchronize once);
+* :func:`percentile` — the nearest-rank rule the serve tail and the metrics
+  registry share;
+* :func:`device_memory_stats` — HBM in use / peak / limit of one device.
 """
 
 from __future__ import annotations
 
-import contextlib
 import math
-import statistics
 import time
 
 import jax
-
-
-@contextlib.contextmanager
-def trace(log_dir: str):
-    """Profile the enclosed region into ``log_dir`` (XPlane format;
-    `tensorboard --logdir` or xprof reads it)."""
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named region visible in profiler timelines."""
-    return jax.profiler.TraceAnnotation(name)
 
 
 def throughput(step_fn, steps: int, warmup: int = 2,
@@ -50,9 +29,10 @@ def throughput(step_fn, steps: int, warmup: int = 2,
     Dispatches all ``steps`` calls and synchronizes ONCE on the final
     output — measuring device throughput with async dispatch fully
     pipelined.  This is the right shape for benchmarks: dispatch is
-    asynchronous, so a host sync after every step (``StepTimer``) stalls
-    the device while the host catches up and times launch + round trip,
-    not the rate the device sustains with its queue full.  Warmup steps
+    asynchronous, so a host sync after every step stalls the device while
+    the host catches up (30 ms a step at DANet b8, PERF.md) and times
+    launch + round trip, not the rate the device sustains with its queue
+    full.  Warmup steps
     (compile) are synchronized and excluded.
 
     Synchronization is ``jax.device_get`` of the final output: the value
@@ -81,8 +61,8 @@ def percentile(values, q: float) -> float:
 
     The latency-reporting convention: p99 is an actually-observed sample,
     never an interpolation between two samples (an interpolated tail value
-    can be a latency no request ever experienced).  Shared by
-    :class:`StepTimer` and the serve metrics (serve/metrics.py).
+    can be a latency no request ever experienced).  Shared by the serve
+    metrics (serve/metrics.py) and the registry's histograms.
     """
     if not values:
         raise ValueError("percentile of no samples")
@@ -93,52 +73,6 @@ def percentile(values, q: float) -> float:
         return ordered[0]
     rank = math.ceil(q / 100.0 * len(ordered))
     return ordered[min(len(ordered), rank) - 1]
-
-
-class StepTimer:
-    """Accumulates per-step wall times, async-dispatch-aware.
-
-    >>> timer = StepTimer(warmup=2)
-    >>> for batch in loader:
-    ...     state, loss = step(state, batch)
-    ...     timer.tick(loss)          # blocks on loss, records dt
-    >>> timer.summary()               # {'mean_s': ..., 'p50_s': ..., ...}
-    """
-
-    def __init__(self, warmup: int = 2):
-        self.warmup = warmup
-        self._seen = 0
-        self._last: float | None = None
-        self.times: list[float] = []
-
-    def tick(self, *outputs) -> float | None:
-        """Record one step boundary; pass any step outputs to block on."""
-        if outputs:
-            jax.block_until_ready(outputs)
-        now = time.perf_counter()
-        dt = None
-        if self._last is not None:
-            self._seen += 1
-            if self._seen > self.warmup:
-                dt = now - self._last
-                self.times.append(dt)
-        self._last = now
-        return dt
-
-    def summary(self, items_per_step: int | None = None) -> dict:
-        if not self.times:
-            return {"steps": 0}
-        out = {
-            "steps": len(self.times),
-            "mean_s": statistics.fmean(self.times),
-            "p50_s": statistics.median(self.times),
-            "p99_s": percentile(self.times, 99.0),
-            "min_s": min(self.times),
-            "max_s": max(self.times),
-        }
-        if items_per_step:
-            out["items_per_sec"] = items_per_step / out["mean_s"]
-        return out
 
 
 def device_memory_stats(device=None) -> dict:

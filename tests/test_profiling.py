@@ -1,57 +1,13 @@
-"""Profiling utilities: StepTimer semantics, annotate/trace no-crash."""
-
-import os
+"""Measurement helpers: percentile, throughput, device memory.  (Device
+traces are TraceCapture's: tests/test_scopes.py, tests/test_telemetry.py.)"""
 
 import pytest
 
 import jax.numpy as jnp
 
-from distributedpytorch_tpu.utils import StepTimer, annotate, trace
-
-
-class TestStepTimer:
-    def test_warmup_skipped(self):
-        t = StepTimer(warmup=2)
-        for _ in range(5):
-            t.tick(jnp.zeros(()))
-        # 5 ticks = 4 intervals; first 2 are warmup
-        assert t.summary()["steps"] == 2
-
-    def test_items_per_sec(self):
-        t = StepTimer(warmup=0)
-        for _ in range(3):
-            t.tick()
-        s = t.summary(items_per_step=10)
-        assert s["steps"] == 2
-        assert s["items_per_sec"] > 0
-        assert s["min_s"] <= s["p50_s"] <= s["max_s"]
-
-    def test_empty_summary(self):
-        assert StepTimer().summary() == {"steps": 0}
-
-    def test_summary_percentiles(self):
-        t = StepTimer(warmup=0)
-        for _ in range(6):
-            t.tick()
-        s = t.summary()
-        assert s["p50_s"] <= s["p99_s"] <= s["max_s"]
-
-    def test_tick_blocks_without_materializing(self, monkeypatch):
-        # a tick waits for the device (block_until_ready) and never copies
-        # the outputs to the host
-        import distributedpytorch_tpu.utils.profiling as prof
-        calls = []
-        monkeypatch.setattr(prof.jax, "block_until_ready",
-                            lambda o: calls.append(("block", o)))
-        monkeypatch.setattr(prof.jax, "device_get",
-                            lambda o: calls.append(("get", o)))
-        t = StepTimer(warmup=0)
-        t.tick(jnp.zeros(()))
-        assert [kind for kind, _ in calls] == ["block"]
-
 
 class TestPercentile:
-    """Nearest-rank percentile — shared by StepTimer and serve/metrics."""
+    """Nearest-rank percentile — shared by serve/metrics and the registry."""
 
     def test_nearest_rank_is_an_observed_sample(self):
         from distributedpytorch_tpu.utils.profiling import percentile
@@ -70,23 +26,6 @@ class TestPercentile:
             percentile([], 50.0)
         with pytest.raises(ValueError, match="0, 100"):
             percentile([1.0], 101.0)
-
-
-class TestTrace:
-    def test_annotate_context(self):
-        with annotate("region"):
-            x = jnp.ones((4,)) * 2
-        assert float(x.sum()) == 8.0
-
-    @pytest.mark.slow  # tier-1 budget (PR 18): a real XPlane capture
-    # start/stop costs ~30s on the CPU mesh; the annotate path keeps its
-    # fast gate (test_annotate_context) and the captured-trace contents
-    # stay covered by test_telemetry's slow XPlane lowering test
-    def test_trace_writes_files(self, tmp_path):
-        d = str(tmp_path / "prof")
-        with trace(d):
-            jnp.ones((8, 8)).sum().block_until_ready()
-        assert os.path.isdir(d) and len(os.listdir(d)) > 0
 
 
 class TestThroughput:
