@@ -277,12 +277,15 @@ def main(argv: list[str]) -> int:
                 programs = trainer.audit_programs(
                     train_batch=_first_batch(trainer.train_loader),
                     val_batch=_first_batch(trainer.val_loader))
-                for name in ("train_step", "eval_step"):
+                # forward: flash PAM, CAM energy, CAM apply; the train step
+                # adds the PAM reverse pass's one fused sweep
+                for name, want in (("train_step", 4), ("eval_step", 3)):
                     fn, args = programs[name]
                     hlo = lower_cached(fn, *args).compiled.as_text()
                     n_calls = hlo.count(
                         'custom_call_target="tpu_custom_call"')
-                    assert n_calls == 3, f"{name}: {n_calls} tpu_custom_call"
+                    assert n_calls == want, \
+                        f"{name}: {n_calls} tpu_custom_call"
             # --- a capture armed on the live trainer leaves its answer:
             # one more epoch with a 4-step capture, one without, both warm
             with stage("scope_capture"):

@@ -24,9 +24,17 @@ the off-TPU fallback:
   Only the C×C attention map (≤1 MB at C=512) crosses HBM between the
   two.
 
-Backward for both: a ``jax.custom_vjp`` whose reverse pass recomputes
-with the O(N·block) / jnp reference form and differentiates that —
-recompute-not-store, the standard flash trade.
+Backward, both ``jax.custom_vjp``:
+
+* position attention: the flash backward as Mosaic kernels.  Under
+  differentiation the forward also emits the per-row log-sum-exp; the
+  reverse pass rebuilds each probability tile from it (``P = exp(S −
+  lse)``, no second online softmax) and accumulates dV, dK and dQ on the
+  MXU without writing an N×N intermediate to HBM — see
+  :func:`_flash_backward_local` for the two schedules and how the shapes
+  choose between them.
+* channel attention: the reverse pass recomputes with the jnp reference
+  form and differentiates that (the gram is one (C, C) matmul).
 
 Mosaic compiles the kernels unless a caller passes ``interpret=True`` —
 only the CPU test suite does (pallas's interpreter executes the same
@@ -49,9 +57,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..telemetry import scopes
-from .attention import blocked_position_attention, channel_attention
+from .attention import channel_attention
 
 _NEG_INF = -1e30
+# dot_general dimension numbers of the kernels' 2-D matmuls
+_NT = (((1,), (1,)), ((), ()))   # a · bᵀ
+_NN = (((1,), (0,)), ((), ()))   # a · b
+_TN = (((0,), (0,)), ((), ()))   # aᵀ · b
 
 
 def _on_local_batch(kernel, *operands):
@@ -73,9 +85,12 @@ def _on_local_batch(kernel, *operands):
                          out_specs=batch_spec(), check_vma=False)(*operands)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, s_ref, acc_ref,
-                  *, n_real: int, block_k: int, scale: float | None):
-    """One (q-block, k-block) tile of online-softmax attention."""
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *refs,
+                  n_real: int, block_k: int, scale: float | None):
+    """One (q-block, k-block) tile of online-softmax attention.  ``refs``:
+    the running (max, sum, accumulator) scratch, after the log-sum-exp
+    output where the call was built with one (the differentiated forward)."""
+    *lse_ref, m_ref, s_ref, acc_ref = refs
     j = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -89,8 +104,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, s_ref, acc_ref,
     k = k_ref[0]          # (bk, ck)
     v = v_ref[0]          # (bk, cv)
     scores = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)          # (bq, bk)
+        q, k, _NT, preferred_element_type=jnp.float32)   # (bq, bk)
     if scale is not None:
         scores = scores * scale
     # Mask keys past the true token count (N was padded to a block multiple).
@@ -104,8 +118,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, s_ref, acc_ref,
     p = jnp.exp(scores - m_new)                      # (bq, bk)
     s_new = s_ref[:, :1] * corr + p.sum(axis=-1, keepdims=True)
     acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32)
     m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
     s_ref[:] = jnp.broadcast_to(s_new, s_ref.shape)
 
@@ -113,25 +126,40 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, s_ref, acc_ref,
     def _finalize():
         o_ref[0] = (acc_ref[:] / jnp.maximum(s_ref[:, :1], 1e-30)
                     ).astype(o_ref.dtype)
+        if lse_ref:  # every lane of a row holds the row's value
+            lse_ref[0][0] = m_ref[:] + jnp.log(s_ref[:])
+
+
+def _pad_tokens(x, n_padded: int):
+    """Zero-pad the token dim (1) of a (B, N, ...) array to ``n_padded``."""
+    pad = n_padded - x.shape[1]
+    if not pad:
+        return x
+    return jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
 
 
 def _flash_local(q, k, v, *, block_q: int, block_k: int,
-                 scale: float | None, interpret: bool):
+                 scale: float | None, interpret: bool, with_lse: bool):
     b, n, ck = q.shape
     cv = v.shape[-1]
     nq = pl.cdiv(n, block_q)
     nk = pl.cdiv(n, block_k)
-    pad_q = nq * block_q - n
-    pad_k = nk * block_k - n
-    if pad_q:
-        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0)))
-    if pad_k:
-        k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0)))
+    q = _pad_tokens(q, nq * block_q)
+    k = _pad_tokens(k, nk * block_k)
+    v = _pad_tokens(v, nk * block_k)
 
     kernel = functools.partial(_flash_kernel, n_real=n, block_k=block_k,
                                scale=scale)
-    out = pl.pallas_call(
+    out_specs = [pl.BlockSpec((1, block_q, cv), lambda b_, i, j: (b_, i, 0))]
+    out_shape = [jax.ShapeDtypeStruct((b, nq * block_q, cv), v.dtype)]
+    if with_lse:
+        # float32, the row's value on all 128 lanes (the layout the running
+        # max and sum already have); lane 0 is what the reverse pass keeps
+        out_specs.append(
+            pl.BlockSpec((1, block_q, 128), lambda b_, i, j: (b_, i, 0)))
+        out_shape.append(
+            jax.ShapeDtypeStruct((b, nq * block_q, 128), jnp.float32))
+    res = pl.pallas_call(
         kernel,
         grid=(b, nq, nk),
         in_specs=[
@@ -139,8 +167,8 @@ def _flash_local(q, k, v, *, block_q: int, block_k: int,
             pl.BlockSpec((1, block_k, ck), lambda b_, i, j: (b_, j, 0)),
             pl.BlockSpec((1, block_k, cv), lambda b_, i, j: (b_, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, cv), lambda b_, i, j: (b_, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, nq * block_q, cv), v.dtype),
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),   # running max
             pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
@@ -152,14 +180,211 @@ def _flash_local(q, k, v, *, block_q: int, block_k: int,
         # encloses the call (a module, a shard_map)
         name=scopes.PAM_KERNEL,
     )(q, k, v)
-    return out[:, :n, :]
+    out = res[0][:, :n, :]
+    if with_lse:
+        return out, res[1][:, :n, 0]
+    return out
 
 
 def _flash_forward(q, k, v, block_q: int, block_k: int,
-                   scale: float | None, interpret: bool):
+                   scale: float | None, interpret: bool,
+                   with_lse: bool = False):
     return _on_local_batch(
         functools.partial(_flash_local, block_q=block_q, block_k=block_k,
-                          scale=scale, interpret=interpret), q, k, v)
+                          scale=scale, interpret=interpret,
+                          with_lse=with_lse), q, k, v)
+
+
+# ------------------------------------------------- position reverse pass
+#: tokens per side of a reverse-pass tile (keys on sublanes, queries on
+#: lanes); fewer tokens than that run as one tile, padded to the lane.
+#: On the v5e at 4,096 tokens 256 / 512 / 1,024 a side take 2.70 / 2.30 /
+#: 2.20 ms (PERF.md, PR 27): 512 has the gain and a quarter of the VMEM
+_BWD_BLOCK = 512
+#: what the reverse-pass calls may take of VMEM (a v5e core has 128 MiB;
+#: Mosaic's default scope of 16 MiB is below one 512-tile's working set
+#: beside a resident dQ)
+_BWD_VMEM_LIMIT = 64 * 2 ** 20
+#: the fused schedule keeps one image's float32 dQ in VMEM for a whole
+#: (key-block, query-block) sweep; beyond this many bytes of it (as VMEM
+#: lays it out: 128 lanes a row, two buffers) the two-sweep schedule runs
+_BWD_DQ_RESIDENT_LIMIT = 16 * 2 ** 20
+
+def _bwd_plan(n: int, ck: int) -> tuple[int, bool]:
+    """``(block, fused)`` of the reverse pass at ``n`` tokens with
+    ``ck``-channel queries, from the shapes alone."""
+    block = min(_BWD_BLOCK, 128 * pl.cdiv(n, 128))
+    n_padded = block * pl.cdiv(n, block)
+    resident = 2 * n_padded * 128 * pl.cdiv(ck, 128) * 4
+    return block, resident <= _BWD_DQ_RESIDENT_LIMIT
+
+
+def _bwd_tile(q, k, v, do, lse, delta, *, key_block, n_real: int,
+              scale: float | None):
+    """``(Pᵀ, dSᵀ)`` of one tile, keys on sublanes and queries on lanes —
+    the orientation in which ``lse`` and ``delta`` (per query) are lane-
+    dense rows and dV, dK need no transpose.  float32 throughout."""
+    st = jax.lax.dot_general(k, q, _NT,
+                             preferred_element_type=jnp.float32)  # (bk, bq)
+    if scale is not None:
+        st = st * scale
+    pt = jnp.exp(st - lse)
+    block = k.shape[0]
+    if n_real % block:  # keys past the true token count (zero-padded)
+        key_idx = key_block * block + jax.lax.broadcasted_iota(
+            jnp.int32, pt.shape, 0)
+        pt = jnp.where(key_idx < n_real, pt, 0.0)
+    dpt = jax.lax.dot_general(v, do, _NT,
+                              preferred_element_type=jnp.float32)
+    dst = pt * (dpt - delta)
+    if scale is not None:
+        dst = dst * scale
+    return pt, dst
+
+
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    dk_ref, dv_ref, *refs, n_real: int,
+                    scale: float | None):
+    """The key-block sweep, queries innermost: dK and dV of the block
+    accumulate in float32 scratch.  Built with a dQ output (the fused
+    schedule) it also adds the tile's ``dS·k`` into the image's dQ, which
+    stays in VMEM until the grid moves to the next image."""
+    *dq_ref, dk_acc, dv_acc = refs
+    j, i = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(i == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    q, k, do = q_ref[0], k_ref[0], do_ref[0]
+    pt, dst = _bwd_tile(q, k, v_ref[0], do, lse_ref[0], delta_ref[0],
+                        key_block=j, n_real=n_real, scale=scale)
+    dst = dst.astype(q.dtype)
+    dv_acc[:] += jax.lax.dot_general(pt.astype(do.dtype), do, _NN,
+                                     preferred_element_type=jnp.float32)
+    dk_acc[:] += jax.lax.dot_general(dst, q, _NN,
+                                     preferred_element_type=jnp.float32)
+    if dq_ref:
+        dq = jax.lax.dot_general(dst, k, _TN,
+                                 preferred_element_type=jnp.float32)
+        block = q.shape[0]
+        rows = pl.ds(pl.multiple_of(i * block, block), block)
+
+        @pl.when(j == 0)
+        def _first():
+            dq_ref[0][0, rows, :] = dq
+
+        @pl.when(j > 0)
+        def _add():
+            dq_ref[0][0, rows, :] += dq
+
+    @pl.when(i == pl.num_programs(2) - 1)
+    def _finalize():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   dq_ref, dq_acc, *, n_real: int, scale: float | None):
+    """The query-block sweep of the two-sweep schedule, keys innermost."""
+    j = pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    k = k_ref[0]
+    _, dst = _bwd_tile(q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0],
+                       delta_ref[0], key_block=j, n_real=n_real,
+                       scale=scale)
+    dq_acc[:] += jax.lax.dot_general(dst.astype(k.dtype), k, _TN,
+                                     preferred_element_type=jnp.float32)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finalize():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+
+def _flash_backward_local(q, k, v, out, lse, do, *, scale: float | None,
+                          interpret: bool):
+    """dq, dk, dv of flash position attention from the saved output and
+    log-sum-exp.  Per tile: ``S = q·kᵀ`` (× ``scale``), ``P = exp(S − lse)``
+    with padded keys at 0, ``dP = dO·vᵀ``, ``dS = P ∘ (dP − δ)`` with ``δ =
+    rowsum(dO ∘ out)``; then ``dV += Pᵀ·dO``, ``dK += dSᵀ·q``, ``dQ +=
+    dS·k``.  MXU operands in the inputs' dtype, everything else float32.
+
+    Two schedules, chosen by :func:`_bwd_plan` from the shapes.  *Fused*
+    (one call, ``pam_bwd_fused``): grid ``(batch, k_blocks, q_blocks)``; the
+    whole float32 dQ of an image is the call's resident output block, so S
+    and dP are computed once.  *Two sweeps* (``pam_bwd_dkv`` without the dQ
+    output, then ``pam_bwd_dq`` on grid ``(batch, q_blocks, k_blocks)``):
+    O(block) VMEM at any N, at the price of computing S and dP twice."""
+    b, n, ck = q.shape
+    cv = v.shape[-1]
+    block, fused = _bwd_plan(n, ck)
+    nb = pl.cdiv(n, block)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)
+    # padded query rows: q = 0, dO = 0, δ = 0 and lse = 0, so P = 1 there
+    # and dS = 0; they add nothing to dK, dV and their own dQ is cut off
+    q, k, v, do = (_pad_tokens(x, nb * block) for x in (q, k, v, do))
+    lse, delta = (_pad_tokens(x, nb * block)[:, None, :]
+                  for x in (lse, delta))
+
+    static = dict(n_real=n, scale=scale)
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        vmem_limit_bytes=_BWD_VMEM_LIMIT)
+
+    def specs(at_q, at_k):
+        """In-specs of (q, k, v, dO, lse, δ); ``at_q`` / ``at_k``: which
+        grid axis walks the query blocks / the key blocks."""
+        def tokens(axis, c):
+            return pl.BlockSpec((1, block, c),
+                                lambda *g: (g[0], g[axis], 0))
+        row = pl.BlockSpec((1, 1, block), lambda *g: (g[0], 0, g[at_q]))
+        return [tokens(at_q, ck), tokens(at_k, ck), tokens(at_k, cv),
+                tokens(at_q, cv), row, row]
+
+    # the key-block sweep: grid (batch, k_blocks, q_blocks)
+    dkv_specs = specs(2, 1)
+    out_specs = [dkv_specs[1], dkv_specs[2]]
+    out_shape = [jax.ShapeDtypeStruct(k.shape, k.dtype),
+                 jax.ShapeDtypeStruct(v.shape, v.dtype)]
+    if fused:
+        out_specs.append(
+            pl.BlockSpec((1,) + q.shape[1:], lambda b_, j, i: (b_, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct(q.shape, jnp.float32))
+    res = pl.pallas_call(
+        functools.partial(_bwd_dkv_kernel, **static),
+        grid=(b, nb, nb),
+        in_specs=dkv_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((block, ck), jnp.float32),
+                        pltpu.VMEM((block, cv), jnp.float32)],
+        compiler_params=params,
+        interpret=interpret,
+        name=scopes.PAM_BWD_FUSED if fused else scopes.PAM_BWD_DKV,
+    )(q, k, v, do, lse, delta)
+    dk, dv = res[0], res[1]
+    if fused:
+        dq = res[2].astype(q.dtype)
+    else:
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, **static),
+            grid=(b, nb, nb),
+            in_specs=specs(1, 2),
+            out_specs=pl.BlockSpec((1, block, ck),
+                                   lambda b_, i, j: (b_, i, 0)),
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            scratch_shapes=[pltpu.VMEM((block, ck), jnp.float32)],
+            compiler_params=params,
+            interpret=interpret,
+            name=scopes.PAM_BWD_DQ,
+        )(q, k, v, do, lse, delta)
+    return dq[:, :n], dk[:, :n], dv[:, :n]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -168,7 +393,8 @@ def flash_position_attention(q, k, v, block_q: int = 256, block_k: int = 256,
                              interpret: bool = False):
     """Flash position attention: same math as
     :func:`ops.attention.position_attention` (unscaled DANet energies unless
-    ``scale``), O(N·block) memory, MXU-scheduled.
+    ``scale``), O(N·block) memory, MXU-scheduled.  ``block_q`` / ``block_k``
+    tile the forward; the reverse pass sizes its own tiles from the shapes.
 
     ``q``/``k``: (B, N, Ck); ``v``: (B, N, Cv) -> (B, N, Cv).
     """
@@ -176,21 +402,18 @@ def flash_position_attention(q, k, v, block_q: int = 256, block_k: int = 256,
 
 
 def _fwd(q, k, v, block_q, block_k, scale, interpret):
-    out = _flash_forward(q, k, v, block_q, block_k, scale, interpret)
-    return out, (q, k, v)
+    out, lse = _flash_forward(q, k, v, block_q, block_k, scale, interpret,
+                              with_lse=True)
+    return out, (q, k, v, out, lse)
 
 
 def _bwd(block_q, block_k, scale, interpret, res, g):
-    q, k, v = res
-    # Recompute with the O(N*block) jnp form and differentiate that — the
-    # flash backward without a second hand-written kernel.
-    def ref(q_, k_, v_):
-        if scale is not None:  # score scaling == scaling q
-            q_ = q_ * scale
-        return blocked_position_attention(q_, k_, v_, block_size=block_k)
+    # the flash backward as Mosaic calls: no recompute of the forward's
+    # recurrence, no N×N array in HBM (see _flash_backward_local)
     with jax.named_scope(scopes.PAM_BWD):
-        _, vjp = jax.vjp(ref, q, k, v)
-        return vjp(g)
+        return _on_local_batch(
+            functools.partial(_flash_backward_local, scale=scale,
+                              interpret=interpret), *res, g)
 
 
 flash_position_attention.defvjp(_fwd, _bwd)
@@ -212,8 +435,7 @@ def _cam_energy_kernel(x_ref, attn_ref, energy_ref):
 
     x = x_ref[0]  # (block_n, C)
     energy_ref[:] += jax.lax.dot_general(
-        x, x, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)          # (C, C)
+        x, x, _TN, preferred_element_type=jnp.float32)   # (C, C)
 
     @pl.when(j == nb - 1)
     def _finalize():
@@ -232,16 +454,13 @@ def _cam_apply_kernel(attn_ref, x_ref, o_ref):
     x = x_ref[0].astype(jnp.float32)  # (block_n, C)
     attn = attn_ref[0]                # (C, C), f32
     o_ref[0] = jax.lax.dot_general(
-        x, attn, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+        x, attn, _NT, preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 def _cam_local(x, *, block_n: int, interpret: bool):
     b, n, c = x.shape
     nb = pl.cdiv(n, block_n)
-    pad = nb * block_n - n
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+    x = _pad_tokens(x, nb * block_n)
     attn = pl.pallas_call(
         _cam_energy_kernel,
         grid=(b, nb),

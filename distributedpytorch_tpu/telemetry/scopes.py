@@ -39,6 +39,12 @@ OPTIMIZER = "optimizer"
 #: that), the CAM calls become ``%cam_energy`` and ``%cam_apply``.
 PAM_KERNEL = "pam"
 PAM_BWD = "pam_bwd"
+#: the reverse pass's own Mosaic calls, entered under :data:`PAM_BWD`: their
+#: names begin with it (``%pam_bwd_fused.1`` ... in the trace), so the
+#: forward's pattern ``%pam(.N)? custom-call`` does not match them
+PAM_BWD_FUSED = "pam_bwd_fused"
+PAM_BWD_DKV = "pam_bwd_dkv"
+PAM_BWD_DQ = "pam_bwd_dq"
 CAM_BWD = "cam_bwd"
 CAM_ENERGY = "cam_energy"
 CAM_APPLY = "cam_apply"

@@ -117,11 +117,11 @@ class TestFlashAttention:
         assert len(outs) == 3 and outs[0].shape == (1, 32, 32, 1)
 
     def test_interpret_backward_parity_vs_blocked_vjp(self):
-        """The custom_vjp backward IS blocked_position_attention's VJP
-        (recompute-not-store) — pin fwd AND grad parity against the
-        blocked form directly, interpret mode, scale-aware tolerances.
-        N=300 is not a block multiple, so the padded-key masking is in
-        the differentiated path too."""
+        """blocked_position_attention's VJP is the oracle of the Mosaic
+        reverse pass (which the custom_vjp ran until PR 27) — pin fwd AND
+        grad parity against the blocked form directly, interpret mode,
+        scale-aware tolerances.  N=300 is not a block multiple, so the
+        padded-key masking is in the differentiated path too."""
         q, k, v = qkv(n=300, seed=5)
 
         def flash_loss(q_, k_, v_):
@@ -141,8 +141,8 @@ class TestFlashAttention:
         _assert_grads_close(g_blocked, g_flash)
 
     def test_scaled_backward_parity_vs_blocked_vjp(self):
-        # the scale term routes through _bwd's `q * scale` re-expression
-        # — pin that path too (score scaling == scaling q)
+        # the reverse pass scales S and dS itself — pin that against the
+        # oracle's re-expression (score scaling == scaling q)
         q, k, v = qkv(n=128, seed=6)
         scale = 0.125
 
@@ -157,6 +157,143 @@ class TestFlashAttention:
         g0 = jax.grad(blocked_loss, argnums=(0, 1, 2))(q, k, v)
         g1 = jax.grad(flash_loss, argnums=(0, 1, 2))(q, k, v)
         _assert_grads_close(g0, g1)
+
+
+def _pallas_calls(jaxpr) -> list:
+    """Every ``pallas_call`` equation of a jaxpr, sub-jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub)
+    return found
+
+
+def _sq_loss(fn):
+    return lambda q_, k_, v_: (fn(q_, k_, v_).astype(jnp.float32) ** 2).sum()
+
+
+_GRAD = functools.partial(jax.grad, argnums=(0, 1, 2))
+
+
+class TestFlashBackwardKernels:
+    """The reverse pass as Mosaic kernels (interpret mode here): dq, dk, dv
+    from the saved output and log-sum-exp, one fused sweep or two."""
+
+    @pytest.fixture()
+    def schedule(self, monkeypatch, request):
+        """The reverse pass's plan as the shapes give it, or forced to
+        3 x 3 tiles of 128 at N=300 in either schedule."""
+        forced = getattr(request, "param", None)
+        if forced is not None:
+            monkeypatch.setattr(pallas_attention, "_bwd_plan",
+                                lambda n, ck: (128, forced == "fused"))
+        return forced
+
+    @pytest.mark.parametrize("n,ck,cv", [(64, 16, 32), (300, 16, 32),
+                                         (64, 8, 64)])
+    @pytest.mark.parametrize("scale", [None, 0.125])
+    def test_grads_match_full_attention(self, n, ck, cv, scale):
+        """N=64 under 256-blocks is one padded tile, forward and reverse;
+        N=300 pads 84 keys and queries of the reverse pass's one 384-tile;
+        8 / 64 channels keep the flagship's 64 / 512 ratio."""
+        q, k, v = qkv(n=n, ck=ck, cv=cv, seed=11)
+        g = _GRAD(_sq_loss(lambda a, b, c: flash_position_attention(
+            a, b, c, 256, 256, scale)))(q, k, v)
+        gr = _GRAD(_sq_loss(lambda a, b, c: position_attention(
+            a * (scale or 1.0), b, c)))(q, k, v)
+        _assert_grads_close(gr, g)
+
+    @pytest.mark.parametrize("schedule", ["fused", "two_sweeps"],
+                             indirect=True)
+    @pytest.mark.parametrize("scale", [None, 0.125])
+    def test_both_schedules_accumulate_over_tiles(self, schedule, scale):
+        q, k, v = qkv(n=300, seed=12)
+        jaxpr = jax.make_jaxpr(_GRAD(_sq_loss(
+            lambda a, b, c: flash_position_attention(
+                a, b, c, 128, 128, scale))))(q, k, v)
+        names = [e.params["name"] for e in _pallas_calls(jaxpr.jaxpr)]
+        assert names == {"fused": ["pam", "pam_bwd_fused"],
+                         "two_sweeps": ["pam", "pam_bwd_dkv", "pam_bwd_dq"]
+                         }[schedule]
+        g = _GRAD(_sq_loss(lambda a, b, c: flash_position_attention(
+            a, b, c, 128, 128, scale)))(q, k, v)
+        gr = _GRAD(_sq_loss(lambda a, b, c: blocked_position_attention(
+            a * (scale or 1.0), b, c, block_size=128)))(q, k, v)
+        _assert_grads_close(gr, g)
+
+    @pytest.mark.parametrize("schedule", [None, "fused", "two_sweeps"],
+                             indirect=True)
+    def test_bfloat16_inputs(self, schedule):
+        """bfloat16 in, bfloat16 out, against autodiff of the einsum form on
+        the same bfloat16 inputs.  Both round P and dS to 8 significant bits
+        as matmul operands (relative 2^-8) and their results once more, at
+        different places (the kernel rounds the unnormalised P, the einsum
+        the normalised one): each is within a few 2^-8 of the float32
+        gradient, so they differ by at most 4 * 2^-8 of its norm."""
+        q, k, v = (x.astype(jnp.bfloat16) for x in qkv(n=300, seed=13))
+        g = _GRAD(_sq_loss(lambda a, b, c: flash_position_attention(
+            a, b, c, 128, 128)))(q, k, v)
+        gr = _GRAD(_sq_loss(position_attention))(q, k, v)
+        exact = _GRAD(_sq_loss(position_attention))(
+            *(x.astype(jnp.float32) for x in (q, k, v)))
+        for got, ref, want in zip(g, gr, exact):
+            assert got.dtype == jnp.bfloat16
+            norm = float(jnp.linalg.norm(want))
+            def off(x):
+                return float(jnp.linalg.norm(
+                    x.astype(jnp.float32) - want)) / norm
+            assert off(got) <= 2 * 2.0 ** -8, off(got)
+            assert float(jnp.linalg.norm(
+                got.astype(jnp.float32) - ref.astype(jnp.float32))
+            ) <= 4 * 2.0 ** -8 * norm
+
+    @pytest.mark.parametrize("n,ck,want", [
+        (64, 64, (128, True)), (300, 64, (384, True)),
+        (4096, 64, (512, True)), (16384, 64, (512, True)),
+        (65536, 64, (512, False)), (16384, 256, (512, False))])
+    def test_plan_follows_the_shapes(self, n, ck, want):
+        # one padded tile below 512 tokens; the image's float32 dQ stays
+        # resident (fused) up to 16 MiB of VMEM, two sweeps beyond
+        assert pallas_attention._bwd_plan(n, ck) == want
+
+    def test_forward_only_program_has_one_single_output_call(self):
+        # the primal (eval step, serve, Predictor) did not grow an lse
+        q, k, v = qkv(n=128)
+        fwd = jax.make_jaxpr(
+            lambda *a: flash_position_attention(*a, 64, 64))(q, k, v)
+        (call,) = _pallas_calls(fwd.jaxpr)
+        assert call.params["name"] == "pam" and len(call.outvars) == 1
+        # under differentiation the same call also emits the log-sum-exp
+        grad = jax.make_jaxpr(_GRAD(_sq_loss(
+            lambda *a: flash_position_attention(*a, 64, 64))))(q, k, v)
+        pam = [e for e in _pallas_calls(grad.jaxpr)
+               if e.params["name"] == "pam"]
+        assert len(pam) == 1 and len(pam[0].outvars) == 2
+        assert pam[0].outvars[1].aval.dtype == jnp.float32
+
+    def test_backward_on_four_devices_equals_one(self):
+        """Through ``_on_local_batch``: the reverse pass's calls shard_map
+        onto each device's rows under a context mesh."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from distributedpytorch_tpu.parallel.mesh import traced_on
+
+        mesh = make_mesh(data=4, devices=jax.devices()[:4])
+        q, k, v = qkv(b=8, n=128, seed=14)
+        grad = _GRAD(_sq_loss(
+            lambda a, b, c: flash_position_attention(a, b, c, 64, 64)))
+        one = grad(q, k, v)
+        sh = NamedSharding(mesh, P("data"))
+        sharded = jax.jit(traced_on(mesh, grad), in_shardings=(sh, sh, sh),
+                          out_shardings=sh)
+        assert "shard_map" in str(jax.make_jaxpr(traced_on(mesh, grad))(
+            q, k, v))
+        got = sharded(q, k, v)
+        assert all(g.sharding.spec == P("data") for g in got)
+        # the same tiles on other batch shapes: float32 reassociation only
+        _assert_grads_close(one, got)
 
 
 class TestFlashChannelAttention:

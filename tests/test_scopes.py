@@ -276,6 +276,15 @@ def test_kernel_reverse_passes_sit_under_their_scopes():
     bwd = [s for s in scopes.scope_table(text).values()
            if s.path.endswith(("pam_bwd", "cam_bwd"))]
     assert bwd and all(s.layer == "head" for s in bwd)
+    # the PAM reverse pass is Mosaic calls of its own, named so that the
+    # trace's %pam_bwd_... is told from the forward's %pam
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 2))).lower(q, q, v).as_text(
+        dialect="hlo", debug_info=True)
+    calls = {p.rsplit("/", 1)[-1] for p in scopes.scope_paths(lowered, 3)
+             if p.startswith("head/pam_bwd/")}
+    assert calls and all(c.startswith(scopes.PAM_BWD) for c in calls)
+    assert calls <= {scopes.PAM_BWD_FUSED, scopes.PAM_BWD_DKV,
+                     scopes.PAM_BWD_DQ}
     # the calls carry their names (the trace's %pam, %cam_energy, %cam_apply)
     fwd = scopes.scope_paths(jax.jit(loss).lower(q, q, v).as_text(
         dialect="hlo", debug_info=True), depth=3)

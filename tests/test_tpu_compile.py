@@ -32,6 +32,7 @@ from distributedpytorch_tpu.parallel import (
     make_train_step,
 )
 from distributedpytorch_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
+from distributedpytorch_tpu.telemetry import scopes
 from distributedpytorch_tpu.train.precision import precision_policy
 
 
@@ -46,17 +47,29 @@ def v5e_mesh():
     return Mesh(devices.reshape(4, 1), (DATA_AXIS, MODEL_AXIS))
 
 
+def _one_chip(mesh) -> NamedSharding:
+    """Replicated on the described topology's first chip."""
+    return NamedSharding(Mesh(mesh.devices[:1], (DATA_AXIS, MODEL_AXIS)), P())
+
+
 def _custom_calls(hlo: str) -> list[str]:
     """The HLO instructions that ARE Mosaic kernel calls."""
     return [ln for ln in hlo.splitlines()
             if 'custom_call_target="tpu_custom_call"' in ln]
 
 
-def _assert_kernels_local(hlo: str, rows: int):
-    """Three Mosaic calls (flash PAM, CAM energy, CAM apply), each on
-    ``rows`` batch rows, none fed by an all-gather."""
+#: the forward's Mosaic calls (flash PAM, CAM energy, CAM apply) and, in a
+#: differentiated program, the PAM reverse pass's one fused sweep (64 and
+#: 4,096 tokens both keep the image's dQ resident: ``_bwd_plan``)
+FORWARD_CALLS = 3
+TRAIN_CALLS = FORWARD_CALLS + 1
+
+
+def _assert_kernels_local(hlo: str, rows: int, n_calls: int):
+    """``n_calls`` Mosaic calls, each on ``rows`` batch rows, none fed by an
+    all-gather."""
     calls = _custom_calls(hlo)
-    assert len(calls) == 3, f"{len(calls)} tpu_custom_call(s)"
+    assert len(calls) == n_calls, f"{len(calls)} tpu_custom_call(s)"
     gathered = {m.group(1) for m in
                 re.finditer(r"(%[\w.\-]+) = [^\n]*\ball-gather", hlo)}
     for ln in calls:
@@ -69,8 +82,7 @@ def _assert_kernels_local(hlo: str, rows: int):
 def test_kernels_compile_at_flagship_head_shape(v5e_mesh):
     """B8 · N4096 · C512 bf16 — the DANet-R101 os8 512² head on one chip:
     forward and value_and_grad of both kernels through Mosaic."""
-    one = NamedSharding(Mesh(v5e_mesh.devices[:1], (DATA_AXIS, MODEL_AXIS)),
-                        P())
+    one = _one_chip(v5e_mesh)
     b, n, c = 8, 4096, 512
     qk = jax.ShapeDtypeStruct((b, n, c // 8), jnp.bfloat16, sharding=one)
     v = jax.ShapeDtypeStruct((b, n, c), jnp.bfloat16, sharding=one)
@@ -80,10 +92,40 @@ def test_kernels_compile_at_flagship_head_shape(v5e_mesh):
                 + pa.flash_channel_attention(v).astype(jnp.float32).sum())
 
     fwd = jax.jit(both).lower(qk, qk, v).compile().as_text()
-    assert len(_custom_calls(fwd)) == 3
+    assert len(_custom_calls(fwd)) == FORWARD_CALLS
     grad = jax.jit(jax.value_and_grad(both, argnums=(0, 1, 2))).lower(
         qk, qk, v).compile().as_text()
-    assert len(_custom_calls(grad)) == 3
+    calls = _custom_calls(grad)
+    assert len(calls) == TRAIN_CALLS
+    assert sum("%pam_bwd_fused" in ln.split(" = ")[0] for ln in calls) == 1
+    # the flash backward: no token-pair (N x N) array of any dtype reaches
+    # HBM, and the scan that used to rebuild the forward is gone
+    assert not re.search(r"\[(\d+,)?4096,4096\]", grad)
+    under_bwd = [s for s in scopes.scope_table(grad).values()
+                 if scopes.PAM_BWD in s.path.split("/")]
+    assert "custom-call" in {s.opcode for s in under_bwd}
+    assert "while" not in {s.opcode for s in under_bwd}
+
+
+@pytest.mark.parametrize("tokens", [300, 65536])
+def test_reverse_pass_compiles_off_the_flagship_shape(v5e_mesh, tokens):
+    """300 tokens (no tile multiple: one padded, key-masked 384-tile) and
+    65,536 (a 2,048-squared crop at os8: the image's dQ no longer fits the
+    resident budget, so the two sweeps run) through Mosaic."""
+    one = _one_chip(v5e_mesh)
+    qk = jax.ShapeDtypeStruct((1, tokens, 64), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((1, tokens, 512), jnp.bfloat16, sharding=one)
+
+    def pam(q, k, v):
+        return pa.flash_position_attention(q, k, v).astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(pam, argnums=(0, 1, 2))).lower(
+        qk, qk, v).compile().as_text()
+    reverse = sorted(re.findall(r"%(pam_bwd\w*?)(?:\.\d+)? = ",
+                                "\n".join(_custom_calls(hlo))))
+    _, fused = pa._bwd_plan(tokens, 64)
+    assert reverse == (
+        ["pam_bwd_fused"] if fused else ["pam_bwd_dkv", "pam_bwd_dq"])
 
 
 @pytest.fixture(scope="module")
@@ -136,7 +178,7 @@ def test_train_step_compiles_on_four_chips(v5e_mesh, r18_bf16,
     step = make_train_step(model, tx, mesh=v5e_mesh, precision=policy,
                            reduce_buckets=reduce_buckets)
     hlo = step.lower(state, _batch(v5e_mesh, 8)).compile().as_text()
-    _assert_kernels_local(hlo, rows=2)
+    _assert_kernels_local(hlo, rows=2, n_calls=TRAIN_CALLS)
     assert "all-reduce" in hlo  # the gradient reduction is still there
 
 
@@ -145,7 +187,7 @@ def test_eval_step_compiles_on_four_chips(v5e_mesh, r18_bf16):
     model, state = _model_and_state(v5e_mesh, tx, cross_replica=False)
     ev = make_eval_step(model, mesh=v5e_mesh)
     hlo = ev.lower(state, _batch(v5e_mesh, 8)).compile().as_text()
-    _assert_kernels_local(hlo, rows=2)
+    _assert_kernels_local(hlo, rows=2, n_calls=FORWARD_CALLS)
 
 
 def test_init_compiles_on_four_chips(v5e_mesh, r18_bf16):
