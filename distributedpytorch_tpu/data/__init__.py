@@ -30,6 +30,7 @@ from .prepared import (
     PreparedSemanticDataset,
     cache_fingerprint,
 )
+from .tokens import PackedTokens, SyntheticTokens, write_token_file
 from .voc import (
     CATEGORY_NAMES,
     VOCInstanceSegmentation,
@@ -50,6 +51,9 @@ __all__ = [
     "VOCSemanticSegmentation",
     "HAVE_GRAIN",
     "PackedDataset",
+    "PackedTokens",
+    "SyntheticTokens",
+    "write_token_file",
     "PackedRecordError",
     "PackFormatError",
     "pack_dataset",

@@ -18,8 +18,20 @@ from .ccnet import CCNet, CrissCrossAttention, RCCAHead
 from .danet import DANet, DANetHead
 from .deeplab import ASPP, DeepLabV3, FCN, FCNHead
 from .encnet import EncNet, EncNetHead, Encoding
+from .nemotron_h import NemotronH, build_nemotron_h
 from .pspnet import PSPNet, PyramidPooling
 from .resnet import ResNet, resnet50, resnet101
+
+#: the tasks (``Config.task``) a model trains under, by its ``build_model``
+#: name; a name not listed is a segmentation net.  What the trainer checks a
+#: configuration against: it knows tasks, not models.
+SEGMENTATION_TASKS = ("instance", "semantic")
+MODEL_TASKS = {"nemotron_h": ("tokens",)}
+
+
+def model_tasks(name: str) -> tuple:
+    return MODEL_TASKS.get(name, SEGMENTATION_TASKS)
+
 
 _BACKBONE_DEPTH = {"resnet18": 18, "resnet34": 34, "resnet50": 50,
                    "resnet101": 101, "resnet152": 152}
@@ -35,12 +47,23 @@ def build_model(
     bn_fp32_stats: bool = True,
     **kw,
 ):
-    """Construct a segmentation model by name.
+    """Construct a segmentation model by name — or, by ``nemotron_h``, the
+    token model of the ``tokens`` task (``lm_config``: a preset's name, a
+    JSON file of the published keys, or that dict; ``remat``; the image
+    options do not apply to it).
 
     ``dtype`` may be a string ('float32' / 'bfloat16') for config-file use.
     """
     if isinstance(dtype, str):
         dtype = jnp.dtype(dtype)
+    if name == "nemotron_h":
+        return build_nemotron_h(
+            kw.get("lm_config", ""), dtype=dtype,
+            remat=kw.get("remat", True))
+    if kw.pop("lm_config", ""):
+        raise ValueError(
+            f"lm_config is nemotron_h-only; model {name!r} does not "
+            "support it")
     if isinstance(kw.get("pam_score_dtype"), str):
         kw["pam_score_dtype"] = jnp.dtype(kw["pam_score_dtype"])
     depth = _BACKBONE_DEPTH[backbone]
@@ -163,10 +186,11 @@ def build_model(
         )
     raise ValueError(
         f"unknown model: {name!r} (danet | deeplabv3 | deeplabv3plus | fcn "
-        "| pspnet | encnet | ccnet)")
+        "| pspnet | encnet | ccnet | nemotron_h)")
 
 
 __all__ = [
+    "model_tasks",
     "ASPP",
     "CCNet",
     "CrissCrossAttention",
@@ -179,6 +203,7 @@ __all__ = [
     "RCCAHead",
     "FCN",
     "FCNHead",
+    "NemotronH",
     "PSPNet",
     "PyramidPooling",
     "ResNet",

@@ -21,6 +21,7 @@ from .pallas_attention import (
 from .losses import (
     sigmoid_balanced_bce,
     multi_output_loss,
+    next_token_xent,
     se_presence_loss,
     softmax_xent_ignore,
 )
@@ -43,6 +44,7 @@ __all__ = [
     "flash_position_attention",
     "sigmoid_balanced_bce",
     "multi_output_loss",
+    "next_token_xent",
     "se_presence_loss",
     "softmax_xent_ignore",
     "jaccard",

@@ -136,3 +136,21 @@ def softmax_xent_ignore(
     ).sum(axis=-1)
     per_pix = (logz - gold) * valid
     return per_pix.sum() / jnp.maximum(valid.sum(), 1)
+
+
+def next_token_xent(logits: jax.Array, tokens: jax.Array,
+                    shift: int = 1) -> jax.Array:
+    """Mean cross-entropy of ``logits[:, t]`` against ``tokens[:, t +
+    shift]`` over the positions that have such a target, in float32.
+
+    ``shift=1`` is the next-token loss; a multi-token-prediction head at
+    depth ``k`` is scored with ``shift=k + 1``.  The sequence keeps its
+    length (the targets are rolled and the tail masked), so the logits are
+    never sliced to an unaligned size."""
+    length = tokens.shape[1]
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    target = jnp.roll(tokens, -shift, axis=1)
+    nll = -jnp.take_along_axis(logp, target[..., None], axis=-1)[..., 0]
+    valid = (jnp.arange(length) < length - shift)[None, :]
+    return jnp.sum(jnp.where(valid, nll, 0.0)) / (
+        tokens.shape[0] * (length - shift))

@@ -64,7 +64,9 @@ from .ulysses import make_ulysses_attention, ulysses_attention_local
 from .step import (
     DEVICE_KEYS,
     INPUT_KEY,
+    NEXT_TOKEN,
     TARGET_KEY,
+    TOKENS_KEY,
     WIRE_KEY,
     TrainState,
     create_train_state,
@@ -118,6 +120,8 @@ __all__ = [
     "make_ulysses_attention",
     "make_train_step",
     "DEVICE_KEYS",
+    "NEXT_TOKEN",
+    "TOKENS_KEY",
     "WIRE_KEY",
     "pack_wire",
     "unpack_wire",

@@ -23,18 +23,27 @@ TPU-native design — the GShard dense-dispatch idiom, not dynamic routing:
 ``MoEMlp`` wraps the functional core as a Flax module for use inside model
 heads; :func:`ep_param_specs` + :func:`make_moe_apply` give the meshed
 expert-parallel execution path.
+
+The second half of the module is the *dropless* form that a token model's
+expert layer uses (``models/nemotron_h.py``): the layer is told which experts
+it holds, the router stays as wide as all of them, and the held experts run
+as one grouped product (``jax.lax.ragged_dot``) over token rows put in expert
+order by a counting sort — no capacity, no ``(N, E, C)`` tensor, no token
+dropped whatever the imbalance.  :func:`dropless_dispatch`,
+:func:`dropless_experts` and :func:`dropless_combine` are its three steps.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..telemetry import counters
 from .mesh import make_mesh_1d
 
 #: canonical expert axis name
@@ -216,3 +225,92 @@ class MoEMlp(nn.Module):
                          capacity_factor=self.capacity_factor)
         self.sow("losses", "moe_aux", aux)
         return x + y.reshape(b, n, d)
+
+
+# ------------------------------------------------------- dropless dispatch
+#: what an expert layer counts (``telemetry/counters.py``): assignments that
+#: found no row of the buffer, summed over layers and steps; the fullest held
+#: expert's rows over the mean, the worst of them
+COUNTER_DROPPED = counters.declare("moe_tokens_dropped", "sum")
+COUNTER_LOAD = counters.declare("moe_expert_load_max_over_mean", "max")
+
+
+class Dispatch(NamedTuple):
+    """Where each (token, held expert) assignment sits in the expert-ordered
+    row buffer.  ``rows``: (M,) token of each buffer row (``N``, out of
+    range, for a row that holds none); ``pos``: (N, held) row of each
+    assignment; ``kept``: (N, held) whether the assignment exists and has a
+    row; ``group_sizes``: (held,) rows of each expert; ``dropped``: the
+    assignments that found no row (0 while the buffer is the worst case's,
+    :func:`dropless_buffer_rows`)."""
+
+    rows: jax.Array
+    pos: jax.Array
+    kept: jax.Array
+    group_sizes: jax.Array
+    dropped: jax.Array
+
+
+def dropless_buffer_rows(n_tokens: int, k: int, n_held: int) -> int:
+    """Rows that hold every assignment whatever the routing: a token picks
+    an expert at most once, so at most ``min(k, held)`` of its choices are
+    held here."""
+    return n_tokens * min(k, n_held)
+
+
+def dropless_dispatch(idx: jax.Array, *, expert_offset: int,
+                      n_held: int) -> Dispatch:
+    """Order the assignments of ``idx`` (N, k: each token's chosen experts,
+    numbered over ALL experts) that fall on the ``n_held`` experts from
+    ``expert_offset`` on, by expert: a counting sort over an (N, held) table,
+    which for a chip's share of the experts is far smaller than the (N, k)
+    list a general sort would order."""
+    n, k = idx.shape
+    m = dropless_buffer_rows(n, k, n_held)
+    held = expert_offset + jnp.arange(n_held, dtype=idx.dtype)
+    sel = (idx[:, :, None] == held[None, None, :]).any(axis=1)   # (N, held)
+    counts = sel.sum(axis=0, dtype=jnp.int32)
+    starts = jnp.cumsum(counts) - counts
+    rank = jnp.cumsum(sel, axis=0, dtype=jnp.int32) - sel
+    pos = starts[None, :] + rank
+    kept = sel & (pos < m)
+    group_sizes = jnp.clip(m - starts, 0, counts)
+    token = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[:, None],
+                             pos.shape)
+    rows = jnp.full((m,), n, jnp.int32).at[
+        jnp.where(kept, pos, m)].set(token, mode="drop")
+    dropped = counts.sum() - group_sizes.sum()
+    return Dispatch(rows, pos, kept, group_sizes, dropped)
+
+
+def dropless_gather(x: jax.Array, d: Dispatch) -> jax.Array:
+    """(N, d) tokens -> (M, d) rows in expert order; empty rows are zero."""
+    return jnp.take(x, d.rows, axis=0, mode="fill", fill_value=0)
+
+
+def dropless_experts(xs: jax.Array, w1: jax.Array, w2: jax.Array,
+                     d: Dispatch, act) -> jax.Array:
+    """The held experts as one grouped product each way: ``act(xs @ w1[e])
+    @ w2[e]`` for the rows of expert ``e``.  ``w1``: (held, d, h), ``w2``:
+    (held, h, d).  Rows past the last group come back as zeros (the grouped
+    product leaves them unwritten on some backends)."""
+    hidden = act(jax.lax.ragged_dot(xs, w1, d.group_sizes))
+    out = jax.lax.ragged_dot(hidden.astype(xs.dtype), w2, d.group_sizes)
+    live = jnp.arange(xs.shape[0]) < d.group_sizes.sum()
+    return jnp.where(live[:, None], out, 0)
+
+
+def dropless_combine(ys: jax.Array, weights: jax.Array,
+                     d: Dispatch) -> jax.Array:
+    """(M, d) expert outputs -> (N, d): each token's weighted sum over the
+    held experts it chose.  ``weights``: (N, held) in float32."""
+    picked = jnp.take(ys, jnp.where(d.kept, d.pos, 0), axis=0)  # (N,held,d)
+    w = jnp.where(d.kept, weights, 0.0).astype(jnp.float32)
+    return jnp.einsum("ne,ned->nd", w, picked,
+                      preferred_element_type=jnp.float32)
+
+
+def expert_load_max_over_mean(d: Dispatch) -> jax.Array:
+    """The fullest held expert's rows over the mean (1 = balanced)."""
+    sizes = d.group_sizes.astype(jnp.float32)
+    return sizes.max() / jnp.maximum(sizes.mean(), 1.0)

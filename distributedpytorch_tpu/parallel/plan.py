@@ -388,7 +388,9 @@ def plan_from_config(cfg, n_devices: int | None = None,
     ``memory_inputs`` (required for ``strategy=auto``) returns
     ``(state_struct, batch_bytes)`` — a shape-only ``TrainState`` and
     the global batch's byte count — the :func:`auto_plan` memory-model
-    inputs.
+    inputs; a third element, where the model knows it, is the live
+    activation bytes on one device (a token model's batch is a few KB of
+    ids, so the input-bytes rule would cost its activations at nothing).
     """
     p = cfg.parallel
     m = cfg.mesh
@@ -438,10 +440,11 @@ def plan_from_config(cfg, n_devices: int | None = None,
                 "strategy=auto needs the memory model's inputs "
                 "(state struct + batch bytes) — construct the plan via "
                 "Trainer, or call auto_plan() directly")
-        state_struct, batch_bytes = memory_inputs()
+        state_struct, batch_bytes, *activation = memory_inputs()
         return stamp(auto_plan(
             n_devices=n_devices, state_struct=state_struct,
             batch_bytes=batch_bytes, slices=m.slices,
+            activation_bytes=activation[0] if activation else None,
             hbm_bytes=(int(p.hbm_budget_gb * 2**30)
                        if p.hbm_budget_gb else None),
             process_is_granule=m.process_is_granule))

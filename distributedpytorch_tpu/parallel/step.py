@@ -39,7 +39,13 @@ import optax
 from flax import struct
 from flax.core import unfreeze
 
-from ..ops import multi_output_loss, se_presence_loss, softmax_xent_ignore
+from ..ops import (
+    multi_output_loss,
+    next_token_xent,
+    se_presence_loss,
+    softmax_xent_ignore,
+)
+from ..telemetry import counters as counters_lib
 from ..telemetry import scopes
 from . import mesh as mesh_lib
 
@@ -80,6 +86,13 @@ def _unpack_mask_bits(batch: Batch) -> dict:
 #: explicit in one place.
 INPUT_KEY = "concat"
 TARGET_KEY = "crop_gt"
+
+#: the one batch key of the ``tokens`` task: ``(B, S)`` int32 token ids, input
+#: and (shifted) target at once
+TOKENS_KEY = "tokens"
+#: the loss type whose models read :data:`TOKENS_KEY` and carry no
+#: ``batch_stats``
+NEXT_TOKEN = "next_token"
 
 #: key under which a coalesced batch ships (data.coalesce_wire)
 WIRE_KEY = "wire"
@@ -224,6 +237,7 @@ def create_train_state(
     mesh=None,
     shard_params: bool = False,
     shard_opt_state: bool = False,
+    input_dtype=jnp.float32,
 ) -> TrainState:
     """Initialize params/batch-stats with a dummy batch and wrap with the
     optimizer state.  ``input_shape`` is (N, H, W, C) — NHWC, the TPU-native
@@ -243,6 +257,9 @@ def create_train_state(
     partitioned over the ``data`` axis (:mod:`parallel.zero`), composing
     with the TP layout when both are on.  Default is fully replicated —
     the reference-parity data-parallel state.
+
+    ``input_dtype``: ``int32`` for a token model, whose ``input_shape`` is
+    (N, S) token ids.
     """
     if shard_opt_state and mesh is None:
         raise ValueError("shard_opt_state requires a mesh (the data axis "
@@ -250,7 +267,7 @@ def create_train_state(
     init_rng, state_rng = jax.random.split(rng)
 
     def make_state():
-        variables = model.init(init_rng, jnp.zeros(input_shape, jnp.float32),
+        variables = model.init(init_rng, jnp.zeros(input_shape, input_dtype),
                                train=False)
         params = unfreeze(variables["params"])
         batch_stats = unfreeze(variables.get("batch_stats", {}))
@@ -311,7 +328,23 @@ def _compute_loss(outputs, batch: Batch, weights, loss_type: str):
     (binary interactive segmentation, SegmentationMultiLosses semantics).
     ``multi_softmax`` — per-output softmax CE with ignore_index=255 (the
     multi-class DeepLabV3 configs; aux outputs default to 0.4 weight).
+    ``next_token`` — a token model's ``(logits, mtp_logits...)``: output
+    ``k`` is scored against the token ``k + 1`` places on; the weights are
+    ``(1, lambda, ...)`` and every multi-token-prediction output needs one.
     """
+    if loss_type == NEXT_TOKEN:
+        if weights is None:
+            weights = (1.0,) * len(outputs)
+        if len(weights) != len(outputs):
+            raise ValueError(
+                f"loss weights {tuple(weights)} for {len(outputs)} token "
+                "outputs — give the next-token head and every "
+                "multi-token-prediction head a weight")
+        tokens = batch[TOKENS_KEY]
+        total = jnp.float32(0.0)
+        for k, (out, w) in enumerate(zip(outputs, weights)):
+            total = total + w * next_token_xent(out, tokens, shift=k + 1)
+        return total
     inputs = batch[INPUT_KEY]
     target = batch[TARGET_KEY]
     void = batch.get("crop_void")
@@ -353,7 +386,11 @@ def _compute_loss(outputs, batch: Batch, weights, loss_type: str):
 def _loss_and_updates(model, params, batch_stats, batch: Batch, rng,
                       loss_weights, train: bool, loss_type: str,
                       aux_loss_weight: float = 0.0, precision=None):
-    """Forward + loss; returns (loss, new_batch_stats).
+    """Forward + loss; returns (loss, new_batch_stats, counters).
+
+    ``counters``: what the model sowed into its ``counters`` collection
+    (``telemetry/counters.py``), each name combined over the layers that
+    sowed it; ``{}`` for a model that sows none.
 
     ``aux_loss_weight`` scales any auxiliary losses the model ``sow``s into
     its ``losses`` collection (e.g. the MoE router's load-balancing term,
@@ -369,18 +406,25 @@ def _loss_and_updates(model, params, batch_stats, batch: Batch, rng,
     is explicit and auditable.
     """
     variables = {"params": params, "batch_stats": batch_stats}
-    inputs = batch[INPUT_KEY]
-    if precision is not None:
-        inputs = precision.cast_to_compute(inputs)
+    counters = {}
+    if loss_type == NEXT_TOKEN:
+        inputs = batch[TOKENS_KEY]  # ids: nothing to cast
+    else:
+        inputs = batch[INPUT_KEY]
+        if precision is not None:
+            inputs = precision.cast_to_compute(inputs)
     if train:
         outputs, mutated = model.apply(
             variables, inputs, train=True,
-            mutable=["batch_stats", "losses"], rngs={"dropout": rng},
+            mutable=["batch_stats", "losses", counters_lib.COLLECTION],
+            rngs={"dropout": rng},
         )
-        new_stats = unfreeze(mutated["batch_stats"])
+        new_stats = unfreeze(mutated.get("batch_stats", {}))
         aux = sum((jnp.sum(x) for x in
                    jax.tree.leaves(mutated.get("losses", {}))),
                   jnp.float32(0.0))
+        counters = counters_lib.reduce_sown(
+            mutated.get(counters_lib.COLLECTION, {}))
     else:
         outputs = model.apply(variables, inputs, train=False)
         new_stats = batch_stats
@@ -391,7 +435,7 @@ def _loss_and_updates(model, params, batch_stats, batch: Batch, rng,
         loss = _compute_loss(outputs, batch, loss_weights, loss_type)
         if aux_loss_weight:
             loss = loss + aux_loss_weight * aux
-    return loss, new_stats
+    return loss, new_stats, counters
 
 
 def make_train_step(
@@ -453,6 +497,16 @@ def make_train_step(
     readback stays on the trainer's existing loss-fetch boundary (no
     extra host syncs).  Multi-step programs return ``((K,), (K, 2))``.
 
+    ``loss_type="next_token"`` (the ``tokens`` task): the batch is
+    ``{"tokens": (B, S) int32}`` and the model has no ``batch_stats``.
+
+    A model that sows into its ``counters`` collection
+    (``telemetry/counters.py``; an expert layer's dropped tokens and load)
+    makes the step's second output ``(loss, counters)`` — the dict of every
+    name sown, combined over layers and micro-batches — after ``aux`` where
+    ``sentinel_metrics`` is on too.  A model that sows none leaves the
+    output as it was.
+
     ``precision`` (train.precision policy, train/precision.py): the
     mixed-precision dtype boundaries — inputs cast to the compute dtype
     at the model, outputs upcast to f32 at the loss.  The model itself
@@ -491,6 +545,12 @@ def make_train_step(
         from .plan import PlanError, reduce_buckets_conflict, \
             shardings_use_axis
 
+        if loss_type == NEXT_TOKEN:
+            raise PlanError(
+                "train.reduce_buckets is the convolutional nets' bucketed "
+                "reduce (cross-replica BatchNorm inside shard_map); the "
+                "tokens task runs the GSPMD step — drop "
+                "train.reduce_buckets")
         if mesh is None:
             raise ValueError("reduce_buckets needs a mesh (the data axis "
                              "the buckets psum over)")
@@ -521,17 +581,17 @@ def make_train_step(
 
     def grads_of(params, batch_stats, batch, rng):
         def loss_fn(p):
-            loss, new_stats = _loss_and_updates(
+            loss, new_stats, counters = _loss_and_updates(
                 model, p, batch_stats, batch, rng, loss_weights, train=True,
                 loss_type=loss_type, aux_loss_weight=aux_loss_weight,
                 precision=precision)
-            return loss * loss_scale, (loss, new_stats)
-        (_, (loss, new_stats)), grads = jax.value_and_grad(
+            return loss * loss_scale, (loss, new_stats, counters)
+        (_, (loss, new_stats, counters)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)
         if loss_scale != 1.0:
             with jax.named_scope(scopes.OPTIMIZER):
                 grads = jax.tree.map(lambda g: g / loss_scale, grads)
-        return loss, new_stats, grads
+        return (loss, counters), new_stats, grads
 
     def accum_grads_of(params, batch_stats, batch, rng):
         """(loss, new_stats, grads) over the (possibly accumulated)
@@ -556,10 +616,12 @@ def make_train_step(
             gsum = jax.tree.map(jnp.add, gsum, g)
             return (gsum, new_stats), loss
 
-        (gsum, new_stats), losses = jax.lax.scan(
+        (gsum, new_stats), (losses, counters) = jax.lax.scan(
             body, (zero_grads, batch_stats), (micro, rngs))
         grads = jax.tree.map(lambda g: g / accum_steps, gsum)
-        return losses.mean(), new_stats, grads
+        counters = {k: counters_lib.combine(k, v)
+                    for k, v in counters.items()}
+        return (losses.mean(), counters), new_stats, grads
 
     def bucketed_grads_of(params, batch_stats, batch, rng):
         """The shard_map twin of :func:`accum_grads_of`: per-device
@@ -574,7 +636,7 @@ def make_train_step(
             # is a different slice of the batch and must not share masks
             rng = jax.random.fold_in(
                 rng, jax.lax.axis_index(mesh_lib.DATA_AXIS))
-            loss, new_stats, grads = accum_grads_of(
+            (loss, _), new_stats, grads = accum_grads_of(
                 params, batch_stats, batch, rng)
             n = jax.lax.axis_size(mesh_lib.DATA_AXIS)
             with jax.named_scope(scopes.GRAD_REDUCE):
@@ -586,11 +648,12 @@ def make_train_step(
             # cross-replica BN pmean'd them) — returned replicated as-is
             return loss, new_stats, grads
 
-        return jax.shard_map(
+        loss, new_stats, grads = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(), P(mesh_lib.DATA_AXIS), P()),
             out_specs=(P(), P(), P()),
             check_vma=False)(params, batch_stats, batch, rng)
+        return (loss, {}), new_stats, grads
 
     def step_fn(state: TrainState, batch: Batch):
         if wire_spec is not None:
@@ -608,7 +671,7 @@ def make_train_step(
             batch = augment(batch, aug_rng)
         differentiate = bucketed_grads_of if reduce_buckets \
             else accum_grads_of
-        loss, new_stats, grads = differentiate(
+        (loss, counters), new_stats, grads = differentiate(
             state.params, state.batch_stats, dict(batch), rng)
 
         with jax.named_scope(scopes.OPTIMIZER):
@@ -624,6 +687,9 @@ def make_train_step(
                 ratio = optax.global_norm(updates) / (
                     optax.global_norm(state.params) + 1e-12)
                 loss = (loss, jnp.stack([gnorm, ratio]))
+        if len(counters):   # which names were sown is structure, not value
+            loss = (*loss, counters) if sentinel_metrics \
+                else (loss, counters)
         new_state = state.replace(
             step=state.step + 1,
             params=new_params,
@@ -691,7 +757,18 @@ def make_eval_step(model, loss_weights: tuple[float, ...] | None = None,
 
     ``packbits_masks`` mirrors the train step's 1-bit ``crop_gt`` wire for
     the prepared val path (data.val_prepared + data.packbits_masks): the
-    mask is 25% of the 3-channel uint8 val batch's bytes."""
+    mask is 25% of the 3-channel uint8 val batch's bytes.
+
+    ``loss_type="next_token"``: ``(state, {"tokens"}) -> ((), loss)`` — the
+    mean next-token cross-entropy alone (no prediction module, no logits
+    handed back: they are the vocabulary times the batch)."""
+
+    def token_step_fn(state: TrainState, batch: Batch):
+        (logits,) = model.apply(
+            {"params": state.params, "batch_stats": state.batch_stats},
+            batch[TOKENS_KEY], train=False)
+        with jax.named_scope(scopes.LOSS):
+            return (), next_token_xent(logits, batch[TOKENS_KEY])
 
     def step_fn(state: TrainState, batch: Batch):
         if packbits_masks:
@@ -706,6 +783,8 @@ def make_eval_step(model, loss_weights: tuple[float, ...] | None = None,
             loss = _compute_loss(outputs, batch, loss_weights, loss_type)
         return outputs, loss
 
+    if loss_type == NEXT_TOKEN:
+        step_fn = token_step_fn
     if mesh is None:
         return jax.jit(step_fn)
     repl = mesh_lib.replicated_sharding(mesh)

@@ -48,6 +48,27 @@ PAM_BWD_DQ = "pam_bwd_dq"
 CAM_BWD = "cam_bwd"
 CAM_ENERGY = "cam_energy"
 CAM_APPLY = "cam_apply"
+#: layers of a token model (``models/nemotron_h.py``): a block runs under its
+#: layer's name with its own (``l03``) as the sub-path; the parts of a block
+#: that a metric reads alone have a scope each
+EMBED = "embed"
+MAMBA = "mamba"
+ATTN = "attn"
+MOE = "moe"
+MTP = "mtp"
+LM_HEAD = "lm_head"
+MAMBA_IN_PROJ = "in_proj"
+MAMBA_CONV = "conv"
+#: not ``scan``: that is an element JAX itself puts on the name stack
+MAMBA_SCAN = "ssd_scan"
+MAMBA_OUT_PROJ = "out_proj"
+MOE_ROUTER = "router"
+MOE_DISPATCH = "dispatch"
+MOE_ROUTED_EXPERTS = "routed_experts"
+MOE_COMBINE = "combine"
+MOE_SHARED_EXPERT = "shared_expert"
+MOE_LATENT = "latent"
+TOKEN_LAYERS = (EMBED, MAMBA, ATTN, MOE, MTP, LM_HEAD)
 #: ops of the model that sit in no sub-module (the logits' final upsample)
 MODEL = "model"
 #: no ``op_name``, or only a parameter's
@@ -146,7 +167,11 @@ def module_path(op_name: str) -> tuple[list, bool]:
     path = [p for p in map(_scope_element, parts) if p]
     if path and path[0] not in STEP_SCOPES \
             and path[0] not in KERNEL_SCOPE_LAYER:
-        return path[1:], True
+        # the reverse pass of a rematerialised block re-enters the root
+        # inside the block's own scope (``NemotronH/mamba/NemotronH/mamba/
+        # l03``): the path starts after the root's last occurrence
+        last = len(path) - 1 - path[::-1].index(path[0])
+        return path[last + 1:], True
     return path, False
 
 

@@ -26,6 +26,16 @@ from typing import Any
 
 @dataclass
 class DataConfig:
+    seq_len: int = 128                  # task=tokens: ids per sequence
+    token_file: str = ""                # task=tokens: a flat uint32 file
+                                        # of ids (data/tokens.py), cut
+                                        # into seq_len windows; the last
+                                        # token_val_samples windows are
+                                        # the val split.  "" = the seeded
+                                        # synthetic source
+    token_samples: int = 64             # task=tokens, synthetic source:
+                                        # sequences per epoch
+    token_val_samples: int = 8          # task=tokens: val sequences
     source: str = "fs"                  # fs | packed: where samples come
                                         # from.  'fs' decodes JPEG/PNG
                                         # per sample off the dataset
@@ -228,7 +238,13 @@ class DataConfig:
 @dataclass
 class ModelConfig:
     name: str = "danet"                 # danet | deeplabv3 | deeplabv3plus
-                                        # | fcn | pspnet | encnet
+                                        # | fcn | pspnet | encnet |
+                                        # nemotron_h (task=tokens)
+    lm_config: str = ""                 # nemotron_h: a preset's name
+                                        # (models/nemotron_h.py PRESETS;
+                                        # "" = tiny) or a JSON file of
+                                        # the published config keys (the
+                                        # benchmark's configs/*.json)
     nclass: int = 1                     # binary/sigmoid head (DANet(1, ...))
     backbone: str = "resnet101"
     output_stride: int | None = None
@@ -497,6 +513,8 @@ class SentinelConfig:
 @dataclass
 class Config:
     task: str = "instance"              # instance (reference) | semantic
+                                        # | tokens (next-token training
+                                        # of model.name=nemotron_h)
     data: DataConfig = field(default_factory=DataConfig)
     model: ModelConfig = field(default_factory=ModelConfig)
     train: TrainConfig = field(default_factory=TrainConfig)

@@ -44,10 +44,12 @@ from ..data import (
 )
 from ..data.governor import GOVERNOR_MODES, FeedActuators, FeedGovernor
 from ..chaos import sites as chaos_sites
-from ..models import build_model
+from ..models import build_model, model_tasks
 from ..parallel import (
     DATA_AXIS,
     DEVICE_KEYS,
+    NEXT_TOKEN,
+    TOKENS_KEY,
     WIRE_KEY,
     create_train_state,
     make_eval_step,
@@ -199,6 +201,14 @@ class Trainer:
         else:
             self.writer = MetricWriter()  # no-op on non-main hosts
 
+        #: the tokens task (next-token training of a token model): another
+        #: batch ({tokens}), another loss, no BatchNorm statistics
+        self._token_task = cfg.task == "tokens"
+        if cfg.task not in model_tasks(cfg.model.name):
+            raise ValueError(
+                f"task={cfg.task!r} with model.name={cfg.model.name!r}: "
+                f"that model trains under task="
+                f"{' | '.join(model_tasks(cfg.model.name))}")
         if cfg.task == "instance" and cfg.model.nclass != 1:
             # The instance protocol is binary by construction (sigmoid
             # prediction pasted back per object, reference
@@ -617,9 +627,11 @@ class Trainer:
                         geom=not (cfg.data.device_augment
                                   and cfg.data.device_augment_geom),
                         uint8_wire=cfg.data.uint8_transfer))
+        elif self._token_task:
+            self.train_set, self.val_set = self._token_sets()
         else:
             raise ValueError(
-                f"unknown task: {cfg.task!r} (instance | semantic)")
+                f"unknown task: {cfg.task!r} (instance | semantic | tokens)")
         # Batch sizes are GLOBAL (the reference's trainBatch=16 spans its 4
         # GPUs; BASELINE speaks of global batches); each host's loader feeds
         # its 1/process_count share, which shard_batch assembles into the
@@ -726,28 +738,34 @@ class Trainer:
             aux_head=cfg.model.aux_head,
             encnet_codes=cfg.model.encnet_codes,
             ccnet_recurrence=cfg.model.ccnet_recurrence,
-            guidance_inject=cfg.model.guidance_inject)
+            guidance_inject=cfg.model.guidance_inject,
+            lm_config=cfg.model.lm_config)
         steps_per_epoch = len(self.train_loader)  # > 0: guarded above
         # Each loaded batch is stepped data.echo times, so schedules (poly
         # decay, warmup fractions) must span echo x the loader length or
         # they exhaust early and clamp the LR.
         total_steps = steps_per_epoch * cfg.epochs * cfg.data.echo
         self.tx, self.schedule = make_optimizer(cfg.optim, total_steps)
-        h, w = cfg.data.crop_size
         with self.mesh:
             self.state = create_train_state(
                 jax.random.PRNGKey(cfg.seed), self.model, self.tx,
-                (1, h, w, cfg.model.in_channels), mesh=self.mesh,
+                **self._init_input(), mesh=self.mesh,
                 shard_params=self.plan.shard_params,
                 shard_opt_state=self.plan.shard_opt_state)
-        loss_type = ("multi_softmax" if cfg.task == "semantic"
+        loss_type = (NEXT_TOKEN if self._token_task else
+                     "multi_softmax" if cfg.task == "semantic"
                      else "multi_sigmoid")
+        loss_weights = cfg.model.loss_weights
+        if loss_weights is None:
+            # a model may state its own (a token model: the next-token
+            # head, then its prediction module's lambda)
+            loss_weights = getattr(self.model, "loss_weights", None)
         # The plan's TP / ZeRO-1 layouts flow from the created state
         # into the compiled steps (live shardings — exactly what
         # create_train_state placed); the plan owns the threading rule.
         st_sh = self.plan.state_shardings(self.state, self.mesh)
-        augment = self._build_device_stage(cfg.data.device_augment,
-                                           cfg.data.device_guidance)
+        augment = None if self._token_task else self._build_device_stage(
+            cfg.data.device_augment, cfg.data.device_guidance)
         # --- self-healing sentinel (train/sentinel.py; see fit()): built
         # before the steps because monitor_grads changes their outputs
         sc = cfg.sentinel
@@ -775,7 +793,7 @@ class Trainer:
         self.sentinel_quarantined_steps = 0
         self._rollback_seconds: list[float] = []
         step_kwargs = dict(
-            loss_weights=cfg.model.loss_weights,
+            loss_weights=loss_weights,
             accum_steps=cfg.optim.accum_steps, mesh=self.mesh,
             loss_type=loss_type, state_shardings=st_sh, augment=augment,
             aux_loss_weight=(cfg.model.moe_aux_weight
@@ -849,8 +867,10 @@ class Trainer:
 
             def eval_preprocess(b, _g=gstage, _k=fixed_key):
                 return _g(b, _k)
+        #: what the last dispatch's model counted, if it sows counters
+        self._last_counters: dict | None = None
         self.eval_step = make_eval_step(
-            self.model, loss_weights=cfg.model.loss_weights, mesh=self.mesh,
+            self.model, loss_weights=loss_weights, mesh=self.mesh,
             loss_type=loss_type, state_shardings=st_sh,
             preprocess=eval_preprocess,
             packbits_masks=self._val_packbits)
@@ -859,7 +879,10 @@ class Trainer:
         self.ckpt = CheckpointManager(
             os.path.join(self.run_dir, "checkpoints"),
             keep_latest=cfg.checkpoint.keep_latest,
-            best_metric_init=cfg.checkpoint.best_metric_init,
+            # a token model is gated on the negated val loss, which never
+            # reaches the Jaccard scale's 0
+            best_metric_init=(-1e30 if self._token_task
+                              else cfg.checkpoint.best_metric_init),
             async_save=cfg.checkpoint.async_save,
             digest=cfg.checkpoint.digest,
             # every save's meta names the plan that laid the state out —
@@ -997,15 +1020,62 @@ class Trainer:
             aux_head=cfg.model.aux_head,
             encnet_codes=cfg.model.encnet_codes,
             ccnet_recurrence=cfg.model.ccnet_recurrence,
-            guidance_inject=cfg.model.guidance_inject)
+            guidance_inject=cfg.model.guidance_inject,
+            remat=cfg.model.remat, lm_config=cfg.model.lm_config)
         tx, _ = make_optimizer(cfg.optim, 100)  # shapes don't see steps
         state_struct = jax.eval_shape(
             lambda: create_train_state(
-                jax.random.PRNGKey(0), model, tx, (1, h, w, in_ch)))
+                jax.random.PRNGKey(0), model, tx, **self._init_input()))
+        if cfg.task == "tokens":
+            # ids are a few KB: the input-bytes rule of the image nets
+            # would cost the activations at nothing.  The model knows its
+            # own (block inputs kept, one block live, the logits); the
+            # batch shards over at most every device.
+            per_device = max(1, cfg.data.train_batch // len(jax.devices()))
+            return (state_struct, cfg.data.train_batch * cfg.data.seq_len * 4,
+                    model.activation_bytes(per_device, cfg.data.seq_len))
         # device-bound train tensors, f32 on device (the uint8 wire
         # dequantizes inside the step): concat + crop_gt (+void)
         batch_bytes = cfg.data.train_batch * h * w * (in_ch + 2) * 4
         return state_struct, batch_bytes
+
+    def _init_input(self) -> dict:
+        """``create_train_state``'s description of the dummy batch the
+        state is initialised on: one NHWC crop, or one sequence of ids."""
+        cfg = self.cfg
+        if cfg.task == "tokens":
+            return {"input_shape": (1, cfg.data.seq_len),
+                    "input_dtype": jnp.int32}
+        h, w = cfg.data.crop_size
+        return {"input_shape": (1, h, w, cfg.model.in_channels)}
+
+    def _token_sets(self) -> tuple:
+        """(train, val) token sources (data/tokens.py): the packed uint32
+        file when ``data.token_file`` names one — its last
+        ``token_val_samples`` windows are the val split — else the seeded
+        synthetic source."""
+        from ..data.tokens import PackedTokens, SyntheticTokens
+
+        cfg = self.cfg
+        # the ids a source may draw are the model's to say; the module is a
+        # description until it is initialised, so building it here is free
+        vocab = build_model(cfg.model.name,
+                            lm_config=cfg.model.lm_config).vocab_size
+        n_val = cfg.data.token_val_samples
+        if cfg.data.token_file:
+            whole = PackedTokens(cfg.data.token_file, cfg.data.seq_len)
+            if len(whole) <= n_val:
+                raise ValueError(
+                    f"{whole} holds {len(whole)} sequences, not more than "
+                    f"data.token_val_samples={n_val}: nothing to train on")
+            return (PackedTokens(cfg.data.token_file, cfg.data.seq_len,
+                                 vocab, count=len(whole) - n_val),
+                    PackedTokens(cfg.data.token_file, cfg.data.seq_len,
+                                 vocab, first=-n_val))
+        return (SyntheticTokens(cfg.data.token_samples, cfg.data.seq_len,
+                                vocab, seed=cfg.seed),
+                SyntheticTokens(n_val, cfg.data.seq_len, vocab,
+                                seed=cfg.seed + 1))
 
     def _warm_start(self, path: str, partial: bool) -> None:
         """Import model weights from a torch ``.pth`` state_dict — the
@@ -1555,7 +1625,7 @@ class Trainer:
                 idx = start_batch + i
                 if qset and idx in qset:
                     continue
-                if cfg.debug_asserts:
+                if cfg.debug_asserts and not self._token_task:
                     if cfg.task == "instance":
                         batch_debug_asserts(
                             batch, packed_masks=cfg.data.packbits_masks)
@@ -1625,6 +1695,12 @@ class Trainer:
                 first = key not in self._programs_seen
                 with step_mark, acct.account("compile" if first else "step"):
                     self.state, out = fn(self.state, *args)
+                if isinstance(out, tuple) and isinstance(out[-1], dict):
+                    # (loss[, aux], counters) of a model that sows counters
+                    # (telemetry/counters.py): they go aside (read at the
+                    # log cadence), the rest is what every step hands back
+                    *out, self._last_counters = out
+                    out = out[0] if len(out) == 1 else tuple(out)
                 if first:
                     self._programs_seen.add(key)
                     # the cost-analysis re-trace books as compile too —
@@ -1683,7 +1759,7 @@ class Trainer:
                 size=lambda: max(self._device_prefetch,
                                  cfg.data.steps_per_dispatch),
                 keys=(WIRE_KEY,) if cfg.data.coalesce_wire
-                else DEVICE_KEYS,
+                else (TOKENS_KEY,) if self._token_task else DEVICE_KEYS,
                 transform=(self._pack_wire_transform
                            if cfg.data.coalesce_wire else None))
             if echo > 1:
@@ -1782,6 +1858,7 @@ class Trainer:
                         # every multiple of the cadence inside
                         # (step - n_steps, step] gets its own point.  For
                         # K=1 this is exactly one (loss_vec[0], step).
+                        self._log_counters(step)
                         L = cfg.log_every_steps
                         bstep = ((step - n_steps) // L + 1) * L
                         while bstep <= step:
@@ -1860,6 +1937,29 @@ class Trainer:
                 scalars["train/peak_hbm_gb"] = round(peak / 2**30, 3)
             self.writer.scalars(scalars, int(self.state.step))
         return mean_loss
+
+    def _log_counters(self, step: int) -> None:
+        """The last dispatch's counters (a model that sows them,
+        telemetry/counters.py) into the writer stack (=> metrics.jsonl) and
+        the registry (=> /metrics).  Rides the log cadence's existing sync;
+        a multi-step dispatch hands back (K,) vectors, combined as each
+        counter's declaration says."""
+        if not self._last_counters:
+            return
+        from ..telemetry import counters as counters_lib
+
+        got = {k: float(counters_lib.combine(k, np.atleast_1d(v)))
+               for k, v in jax.device_get(self._last_counters).items()}
+        self.writer.scalars({f"train/{k}": v for k, v in got.items()}, step)
+        if self.cfg.telemetry:
+            from ..telemetry import get_registry
+            from ..telemetry.registry import is_enabled
+
+            if is_enabled():
+                for k, v in got.items():
+                    get_registry().gauge(
+                        f"train_{k}", "Model counter of the last logged "
+                        "train step (telemetry/counters.py)").set(v)
 
     # ------------------------------------------------- sentinel rollback
     def _divergence(self, epoch: int, step0: int, report, end_step: int,
@@ -2065,7 +2165,17 @@ class Trainer:
                             ) -> tuple[dict, dict | None]:
         self.val_loader.set_epoch(0)
         with self.mesh:
-            if self.cfg.task == "semantic":
+            if self._token_task:
+                # mean next-token loss over the val sequences (the loader
+                # wrap-pads its last batch: every sequence is scored)
+                losses = [self.eval_step(state, b)[1] for b in
+                          prefetch_to_device(
+                              iter(self.val_loader), self.mesh,
+                              size=self.cfg.data.device_prefetch,
+                              keys=(TOKENS_KEY,))]
+                loss = float(np.mean(jax.device_get(losses)))
+                metrics = {"loss": loss, "perplexity": float(np.exp(loss))}
+            elif self.cfg.task == "semantic":
                 metrics = evaluate_semantic(
                     self.eval_step, state, self.val_loader,
                     nclass=self.cfg.model.nclass, mesh=self.mesh,
@@ -2109,8 +2219,10 @@ class Trainer:
                  log_panels: bool = True) -> None:
         """Writer half of validation — main thread only."""
         if self.is_main:
-            flat = {"val/loss": metrics["loss"],
-                    "val/jaccard": metrics["jaccard"]}
+            flat = {"val/loss": metrics["loss"]}
+            for key in ("jaccard", "perplexity"):
+                if key in metrics:
+                    flat[f"val/{key}"] = metrics[key]
             if "best_threshold" in metrics:
                 flat["val/best_threshold"] = metrics["best_threshold"]
             for th, v in metrics.get("jaccard_per_threshold", {}).items():
@@ -2213,12 +2325,15 @@ class Trainer:
             # entries of epochs it is about to replay (see
             # _handle_divergence) without positional guesswork
             history["val"].append(dict(metrics, epoch=epoch))
-        is_best = self.ckpt.save(step, state, metric=metrics["jaccard"],
+        # best-gating metric, higher is better: threshold-max Jaccard, or
+        # for a token model the negated val loss
+        name, best = ("neg_loss", -metrics["loss"]) if self._token_task \
+            else ("jaccard", metrics["jaccard"])
+        is_best = self.ckpt.save(step, state, metric=best,
                                  extra={"epoch": epoch})
         if is_best and self.is_main:
             self.writer.scalars(
-                {"val/new_best_jaccard": metrics["jaccard"],
-                 "val/epoch": epoch}, step)
+                {f"val/new_best_{name}": best, "val/epoch": epoch}, step)
 
     # -------------------------------------------------------------------- fit
     def fit(self, guard: PreemptionGuard | None = None) -> dict:

@@ -201,3 +201,43 @@ def test_init_compiles_on_four_chips(v5e_mesh, r18_bf16):
     init = jax.jit(lambda: create_train_state(
         jax.random.PRNGKey(0), model, tx, (1, 64, 64, 4), mesh=v5e_mesh))
     assert "tpu_custom_call" not in init.lower().compile().as_text()
+
+
+def test_latent_moe_block_compiles_at_published_widths(v5e_mesh):
+    """One LatentMoE block of the benchmark's token configuration (hidden
+    4,096, latent 1,024, experts 2,688 wide, 8 of 512 held, top-22) over
+    8,192 tokens in bfloat16, forward and reverse: the dropless grouped
+    product (``jax.lax.ragged_dot``) and its two transposes go through the
+    TPU compiler's own grouped-matmul calls, over the worst-case row buffer
+    (8,192 x 8 rows), and nothing of it is an (N, E, C) dispatch tensor."""
+    import json
+    import os
+
+    from distributedpytorch_tpu.models import nemotron_h as nh
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "nemotron3_super_stage_tp8_ep64.json")) as f:
+        cfg = nh.LMConfig.from_dict(json.load(f))
+    one = _one_chip(v5e_mesh)
+    layer = nh.LatentMoE(cfg, jnp.bfloat16)
+    u = jax.ShapeDtypeStruct((1, 8192, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, cfg.hidden_size),
+                                             jnp.bfloat16)))["params"])
+
+    def loss(p, v):
+        out = layer.apply({"params": p}, v, mutable=["counters"])[0]
+        return out.astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, u).compile().as_text()
+    grouped = [ln for ln in _custom_calls(hlo) if "ragged" in ln.lower()]
+    # two products forward, and each one's two transposes
+    assert len(grouped) >= 6, len(grouped)
+    rows = 8192 * cfg.experts_held
+    assert re.search(rf"bf16\[{rows},{cfg.expert_hidden}\]", hlo)
+    assert not re.search(r"\[8192,512,\d+\]", hlo)
