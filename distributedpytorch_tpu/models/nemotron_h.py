@@ -387,13 +387,9 @@ class LatentMoE(nn.Module):
         with jax.named_scope(scopes.MOE_DISPATCH):
             disp = moe_lib.dropless_dispatch(
                 idx, expert_offset=off, n_held=held)
-            rows = moe_lib.dropless_gather(low, disp)
-        with jax.named_scope(scopes.MOE_ROUTED_EXPERTS):
-            ys = moe_lib.dropless_experts(
-                rows, w1.astype(self.dtype), w2.astype(self.dtype), disp,
-                relu2)
-        with jax.named_scope(scopes.MOE_COMBINE):
-            routed = moe_lib.dropless_combine(ys, weights, disp)
+        # names its own parts: dispatch / routed_experts / combine
+        routed, chunks_run = moe_lib.dropless_routed(
+            low, weights, w1, w2, disp, relu2)
         with jax.named_scope(scopes.MOE_LATENT):
             routed = _dot(routed, latent_up, self.dtype, out=F32)
         with jax.named_scope(scopes.MOE_SHARED_EXPERT):
@@ -402,6 +398,7 @@ class LatentMoE(nn.Module):
         self.sow(counters.COLLECTION, moe_lib.COUNTER_DROPPED, disp.dropped)
         self.sow(counters.COLLECTION, moe_lib.COUNTER_LOAD,
                  moe_lib.expert_load_max_over_mean(disp))
+        self.sow(counters.COLLECTION, moe_lib.COUNTER_CHUNKS, chunks_run)
         return u + (routed + shared).astype(u.dtype).reshape(u.shape)
 
 
@@ -513,13 +510,14 @@ class NemotronH(nn.Module):
         kept = n_blocks * t * c.hidden_size * item
         if not self.remat:
             kept *= 8
-        rows = moe_lib.dropless_buffer_rows(
-            t, c.experts_per_token, c.experts_held)
+        # an expert layer's wide arrays are one chunk of the row buffer
+        rows = moe_lib.chunk_rows_of(moe_lib.dropless_buffer_rows(
+            t, c.experts_per_token, c.experts_held))
         per_kind = {
             "*": 3 * batch * c.q_heads * seq_len * seq_len * 4,
             "E": 2 * (rows * (2 * c.latent_size + c.expert_hidden) * item
                       + t * c.shared_hidden * item
-                      + t * c.experts_held * c.latent_size * 4),
+                      + t * c.latent_size * 4),
             "M": 4 * t * c.mamba_heads * c.chunk_size * 4
             + 6 * t * (2 * c.mamba_inner + c.conv_dim) * 4,
         }

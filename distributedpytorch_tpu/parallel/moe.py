@@ -29,12 +29,20 @@ expert layer uses (``models/nemotron_h.py``): the layer is told which experts
 it holds, the router stays as wide as all of them, and the held experts run
 as one grouped product (``jax.lax.ragged_dot``) over token rows put in expert
 order by a counting sort — no capacity, no ``(N, E, C)`` tensor, no token
-dropped whatever the imbalance.  :func:`dropless_dispatch`,
-:func:`dropless_experts` and :func:`dropless_combine` are its three steps.
+dropped whatever the imbalance.  :func:`dropless_dispatch` orders the rows of
+a *logical* buffer sized for the worst routing, as index arrays only;
+:func:`dropless_routed` visits that buffer in chunks of :data:`CHUNK_ROWS`
+rows and runs only the chunks that hold a token.  In each it gathers the
+rows' tokens, runs the two grouped products with the chunk's own share of
+every expert's group, and scatter-adds each row's output, times the row's
+own routing weight, into the tokens' result.  Nothing wide is ever made at
+the buffer's size: the cost follows the rows that hold a token
+(``ceil(live / CHUNK_ROWS)`` chunks), not the worst case.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, NamedTuple
 
@@ -43,7 +51,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..telemetry import counters
+from ..telemetry import counters, scopes
 from .mesh import make_mesh_1d
 
 #: canonical expert axis name
@@ -230,17 +238,26 @@ class MoEMlp(nn.Module):
 # ------------------------------------------------------- dropless dispatch
 #: what an expert layer counts (``telemetry/counters.py``): assignments that
 #: found no row of the buffer, summed over layers and steps; the fullest held
-#: expert's rows over the mean, the worst of them
+#: expert's rows over the mean, the worst of them; the chunks of the row
+#: buffer that ran over the chunks in all, the worst layer's
 COUNTER_DROPPED = counters.declare("moe_tokens_dropped", "sum")
 COUNTER_LOAD = counters.declare("moe_expert_load_max_over_mean", "max")
+COUNTER_CHUNKS = counters.declare("moe_chunks_run_share", "max")
+
+#: rows of the buffer that :func:`dropless_routed` visits at a time: one
+#: constant, set from chip runs (PERF.md section 6, PR 32).  A multiple of
+#: the grouped product's row tile.
+CHUNK_ROWS = 4096
 
 
 class Dispatch(NamedTuple):
     """Where each (token, held expert) assignment sits in the expert-ordered
-    row buffer.  ``rows``: (M,) token of each buffer row (``N``, out of
-    range, for a row that holds none); ``pos``: (N, held) row of each
-    assignment; ``kept``: (N, held) whether the assignment exists and has a
-    row; ``group_sizes``: (held,) rows of each expert; ``dropped``: the
+    row buffer, which exists as these index arrays only.  ``rows``: (M,)
+    token of each buffer row (``N``, out of range, for a row that holds
+    none); ``pos``: (N, held) row of each assignment; ``kept``: (N, held)
+    whether the assignment exists and has a row; ``group_sizes``: (held,)
+    rows of each expert, one group after the other from row 0, so the rows
+    from ``group_sizes.sum()`` on hold no token; ``dropped``: the
     assignments that found no row (0 while the buffer is the worst case's,
     :func:`dropless_buffer_rows`)."""
 
@@ -283,31 +300,149 @@ def dropless_dispatch(idx: jax.Array, *, expert_offset: int,
     return Dispatch(rows, pos, kept, group_sizes, dropped)
 
 
-def dropless_gather(x: jax.Array, d: Dispatch) -> jax.Array:
-    """(N, d) tokens -> (M, d) rows in expert order; empty rows are zero."""
-    return jnp.take(x, d.rows, axis=0, mode="fill", fill_value=0)
+def chunk_rows_of(buffer_rows: int, chunk_rows: int | None = None) -> int:
+    """Rows of one chunk of a ``buffer_rows``-row buffer: ``chunk_rows``
+    (:data:`CHUNK_ROWS`), or the whole of a smaller buffer."""
+    return max(1, min(chunk_rows or CHUNK_ROWS, buffer_rows))
 
 
-def dropless_experts(xs: jax.Array, w1: jax.Array, w2: jax.Array,
-                     d: Dispatch, act) -> jax.Array:
-    """The held experts as one grouped product each way: ``act(xs @ w1[e])
-    @ w2[e]`` for the rows of expert ``e``.  ``w1``: (held, d, h), ``w2``:
-    (held, h, d).  Rows past the last group come back as zeros (the grouped
-    product leaves them unwritten on some backends)."""
-    hidden = act(jax.lax.ragged_dot(xs, w1, d.group_sizes))
-    out = jax.lax.ragged_dot(hidden.astype(xs.dtype), w2, d.group_sizes)
-    live = jnp.arange(xs.shape[0]) < d.group_sizes.sum()
-    return jnp.where(live[:, None], out, 0)
+class _Chunk(NamedTuple):
+    """One chunk's view of the row buffer: ``r`` rows from ``c * r``."""
+
+    rows: jax.Array      # (r,) token of each row; N where the row has none
+    sizes: jax.Array     # (held,) the chunk's rows of each expert
+    expert: jax.Array    # (r, held) whether the row belongs to the expert
+    live: jax.Array      # (r,) whether the row holds a token
 
 
-def dropless_combine(ys: jax.Array, weights: jax.Array,
-                     d: Dispatch) -> jax.Array:
-    """(M, d) expert outputs -> (N, d): each token's weighted sum over the
-    held experts it chose.  ``weights``: (N, held) in float32."""
-    picked = jnp.take(ys, jnp.where(d.kept, d.pos, 0), axis=0)  # (N,held,d)
-    w = jnp.where(d.kept, weights, 0.0).astype(jnp.float32)
-    return jnp.einsum("ne,ned->nd", w, picked,
-                      preferred_element_type=jnp.float32)
+def _chunk_view(rows: jax.Array, group_sizes: jax.Array, c, r: int) -> _Chunk:
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        start = c * r
+        ends = jnp.cumsum(group_sizes)
+        starts = ends - group_sizes
+        sizes = jnp.clip(ends - start, 0, r) - jnp.clip(starts - start, 0, r)
+        row = (start + jnp.arange(r, dtype=jnp.int32))[:, None]
+        expert = (row >= starts[None, :]) & (row < ends[None, :])
+        return _Chunk(jax.lax.dynamic_slice(rows, (start,), (r,)), sizes,
+                      expert, expert.any(-1))
+
+
+def _chunk_inputs(low, weights, ch: _Chunk):
+    """The chunk's token rows, and each row's own routing weight."""
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        xs = jnp.take(low, ch.rows, axis=0, mode="fill", fill_value=0)
+    with jax.named_scope(scopes.MOE_COMBINE):
+        w_rows = jnp.take(weights, ch.rows, axis=0, mode="fill",
+                          fill_value=0)
+        w_row = jnp.where(ch.expert, w_rows, 0).sum(-1)
+    return xs, w_row.astype(jnp.float32)
+
+
+def _chunk_outputs(xs, w_row, w1, w2, ch: _Chunk, act) -> jax.Array:
+    """(r, d) float32: ``w_row * (act(xs @ w1[e]) @ w2[e])`` on the rows of
+    expert ``e``, zeros on a row that holds no token (the grouped product
+    leaves rows past its last group unwritten on some backends)."""
+    with jax.named_scope(scopes.MOE_ROUTED_EXPERTS):
+        hidden = act(jax.lax.ragged_dot(xs, w1, ch.sizes))
+        ys = jax.lax.ragged_dot(hidden.astype(xs.dtype), w2, ch.sizes)
+        ys = jnp.where(ch.live[:, None], ys, 0)
+    with jax.named_scope(scopes.MOE_COMBINE):
+        return ys.astype(jnp.float32) * w_row[:, None]
+
+
+def _zeros_after(ready, *like) -> tuple:
+    """Float32 zeros shaped like each of ``like``, for a loop to accumulate
+    into, that exist only once ``ready`` does.  A loop
+    whose accumulators start from a constant is free to be scheduled away
+    from the work around it, and XLA:TPU then keeps every layer's weight
+    gradient until the end of the step before it applies any update: 1.5 GB
+    more at the step's peak (PERF.md section 6, PR 32)."""
+    zeros = tuple(jnp.zeros(a.shape, jnp.float32) for a in like)
+    return jax.lax.optimization_barrier((ready, zeros))[1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _routed(low, weights, w1, w2, rows, group_sizes, n_run, act, r):
+    return _routed_fwd(low, weights, w1, w2, rows, group_sizes, n_run, act,
+                       r)[0]
+
+
+def _routed_fwd(low, weights, w1, w2, rows, group_sizes, n_run, act, r):
+    w1c, w2c = w1.astype(low.dtype), w2.astype(low.dtype)
+
+    def chunk(c, out):
+        ch = _chunk_view(rows, group_sizes, c, r)
+        xs, w_row = _chunk_inputs(low, weights, ch)
+        ys = _chunk_outputs(xs, w_row, w1c, w2c, ch, act)
+        with jax.named_scope(scopes.MOE_COMBINE):
+            return out.at[ch.rows].add(ys, mode="drop")
+
+    out = jax.lax.fori_loop(0, n_run, chunk, _zeros_after(low, low)[0])
+    return out, (low, weights, w1, w2, rows, group_sizes, n_run)
+
+
+def _routed_bwd(act, r, res, g):
+    """The reverse pass over the same chunks: each recomputes its rows and
+    its products (only the layer's inputs were kept), and adds its share of
+    the four gradients in float32."""
+    low, weights, w1, w2, rows, group_sizes, n_run = res
+    w1c, w2c = w1.astype(low.dtype), w2.astype(low.dtype)
+
+    def chunk(c, acc):
+        d_low, d_weights, d_w1, d_w2 = acc
+        ch = _chunk_view(rows, group_sizes, c, r)
+        xs, w_row = _chunk_inputs(low, weights, ch)
+        with jax.named_scope(scopes.MOE_COMBINE):
+            g_rows = jnp.take(g, ch.rows, axis=0, mode="fill", fill_value=0)
+        _, vjp = jax.vjp(
+            lambda xs, w_row, w1c, w2c: _chunk_outputs(
+                xs, w_row, w1c, w2c, ch, act), xs, w_row, w1c, w2c)
+        d_xs, d_w_row, c_w1, c_w2 = vjp(g_rows)
+        with jax.named_scope(scopes.MOE_DISPATCH):
+            d_low = d_low.at[ch.rows].add(d_xs.astype(jnp.float32),
+                                          mode="drop")
+        with jax.named_scope(scopes.MOE_COMBINE):
+            d_weights = d_weights.at[ch.rows].add(
+                jnp.where(ch.expert, d_w_row[:, None], 0.0), mode="drop")
+        with jax.named_scope(scopes.MOE_ROUTED_EXPERTS):
+            d_w1 = d_w1 + c_w1.astype(jnp.float32)
+            d_w2 = d_w2 + c_w2.astype(jnp.float32)
+        return d_low, d_weights, d_w1, d_w2
+
+    acc = jax.lax.fori_loop(0, n_run, chunk,
+                            _zeros_after(g, low, weights, w1, w2))
+    return tuple(a.astype(p.dtype) for a, p in
+                 zip(acc, (low, weights, w1, w2))) + (None, None, None)
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
+
+
+def dropless_routed(low: jax.Array, weights: jax.Array, w1: jax.Array,
+                    w2: jax.Array, d: Dispatch, act,
+                    chunk_rows: int | None = None):
+    """The held experts over the rows of ``d``: ``(N, d)`` float32, each
+    token's sum over the held experts ``e`` it chose of ``weights[t, e] *
+    (act(low[t] @ w1[e]) @ w2[e])``, and the share of the buffer's chunks
+    that ran.  ``low``: (N, d) tokens in the compute dtype; ``weights``:
+    (N, held); ``w1``: (held, d, h), ``w2``: (held, h, d), cast to ``low``'s
+    dtype for the products, which accumulate in float32.
+
+    The buffer is visited in chunks of ``chunk_rows`` rows
+    (:data:`CHUNK_ROWS`; a whole small buffer is one chunk) by a loop whose
+    trip count is the chunks that hold a token, decided on the device from
+    ``d.group_sizes``: no shape depends on the data and no row is left out,
+    a routing that fills the buffer runs every chunk.  Each chunk's three
+    parts run under the ``dispatch`` / ``routed_experts`` / ``combine``
+    scopes, forward and reverse (a custom VJP: the loop's trip count is
+    data, which JAX cannot differentiate through)."""
+    n, m = low.shape[0], d.rows.shape[0]
+    r = chunk_rows_of(m, chunk_rows)
+    n_chunks = -(-m // r)
+    rows = jnp.pad(d.rows, (0, n_chunks * r - m), constant_values=n)
+    n_run = (d.group_sizes.sum() + r - 1) // r
+    out = _routed(low, weights, w1, w2, rows, d.group_sizes, n_run, act, r)
+    return out, n_run.astype(jnp.float32) / n_chunks
 
 
 def expert_load_max_over_mean(d: Dispatch) -> jax.Array:

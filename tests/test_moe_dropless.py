@@ -96,24 +96,141 @@ def test_the_dropped_counter_is_live(monkeypatch):
     assert int(short.kept.sum()) == 20
 
 
-def test_grouped_product_equals_the_dense_loop():
-    n, total, k, held, d_in, d_h = 29, 8, 3, 8, 12, 20
-    ks = jax.random.split(jax.random.PRNGKey(2), 5)
-    idx = _random_idx(ks[0], n, total, k)
-    x = jax.random.normal(ks[1], (n, d_in))
-    w1 = jax.random.normal(ks[2], (held, d_in, d_h)) / 3
-    w2 = jax.random.normal(ks[3], (held, d_h, d_in)) / 4
-    weights = jax.random.uniform(ks[4], (n, held))
-    d = moe_lib.dropless_dispatch(idx, expert_offset=0, n_held=held)
-    ys = moe_lib.dropless_experts(moe_lib.dropless_gather(x, d), w1, w2, d,
-                                  relu2)
-    got = moe_lib.dropless_combine(ys, weights, d)
-    want = jnp.zeros((n, d_in))
-    for e in range(held):
-        chosen = (idx == e).any(-1)
+def _dense_loop(idx, x, weights, w1, w2, offset=0):
+    """Each held expert over every token, masked to the tokens that chose
+    it: what the chunked grouped products must add up to."""
+    want = jnp.zeros(x.shape, jnp.float32)
+    for e in range(w1.shape[0]):
+        chosen = (idx == offset + e).any(-1)
         want = want + jnp.where(chosen, weights[:, e], 0.0)[:, None] * (
             relu2(x @ w1[e]) @ w2[e])
-    _close(got, want)
+    return want
+
+
+def _operands(key, n, held, d_in=12, d_h=20):
+    ks = jax.random.split(key, 4)
+    return (jax.random.normal(ks[0], (n, d_in)),
+            jax.random.uniform(ks[1], (n, held)),
+            jax.random.normal(ks[2], (held, d_in, d_h)) / 3,
+            jax.random.normal(ks[3], (held, d_h, d_in)) / 4)
+
+
+def test_grouped_product_equals_the_dense_loop():
+    n, total, k, held = 29, 8, 3, 8
+    idx = _random_idx(jax.random.PRNGKey(2), n, total, k)
+    x, weights, w1, w2 = _operands(jax.random.PRNGKey(12), n, held)
+    d = moe_lib.dropless_dispatch(idx, expert_offset=0, n_held=held)
+    got, _ = moe_lib.dropless_routed(x, weights, w1, w2, d, relu2)
+    _close(got, _dense_loop(idx, x, weights, w1, w2))
+
+
+def _check_chunked(idx, held, offset, chunk_rows, seed=0):
+    """Result, gradients (x, weights, w1, w2) and the chunks counter of the
+    chunked path against the dense loop; returns (live rows, chunks, share,
+    result, gradients) for the caller's own assertions."""
+    n = idx.shape[0]
+    x, weights, w1, w2 = _operands(jax.random.PRNGKey(20 + seed), n, held)
+    d = moe_lib.dropless_dispatch(idx, expert_offset=offset, n_held=held)
+    assert int(d.dropped) == 0
+
+    def chunked(*a):
+        return moe_lib.dropless_routed(*a, d, relu2, chunk_rows=chunk_rows)
+
+    def dense(*a):
+        return _dense_loop(idx, *a, offset=offset)
+
+    got, share = chunked(x, weights, w1, w2)
+    _close(got, dense(x, weights, w1, w2))
+    probe = jax.random.normal(jax.random.PRNGKey(30 + seed), x.shape)
+
+    def loss(fn):
+        return lambda *a: jnp.sum(fn(*a) * probe)
+
+    g_got = jax.grad(loss(lambda *a: chunked(*a)[0]),
+                     argnums=(0, 1, 2, 3))(x, weights, w1, w2)
+    g_want = jax.grad(loss(dense), argnums=(0, 1, 2, 3))(x, weights, w1, w2)
+    for name, a, b in zip(("x", "weights", "w1", "w2"), g_got, g_want):
+        assert bool(jnp.isfinite(a).all()), name
+        scale = float(jnp.abs(b).max())
+        assert float(jnp.abs(a - b).max()) <= 2e-5 * scale + 1e-12, name
+    live = int(d.group_sizes.sum())
+    m = d.rows.shape[0]
+    r = moe_lib.chunk_rows_of(m, chunk_rows)
+    chunks = -(-m // r)
+    # (d) the counter: chunks that hold a token over chunks in all
+    assert float(share) == pytest.approx(-(-live // r) / chunks)
+    return live, chunks, float(share), got, g_got
+
+
+def _idx_with_counts(n, counts, k, total):
+    """(N, k) choices that put exactly ``counts[e]`` tokens on held expert
+    ``e`` (numbered from 0): token ``t`` picks held experts by a fixed
+    stride, its other choices fall past the held ones."""
+    held = len(counts)
+    assert total >= held + k
+    idx = np.tile(np.arange(held, held + k, dtype=np.int32), (n, 1))
+    slot = np.zeros(n, dtype=np.int64)
+    for e, c in enumerate(counts):
+        tokens = (np.arange(c) * 5 + e) % n
+        assert len(set(tokens.tolist())) == c
+        for t in tokens:
+            idx[t, slot[t]] = e
+            slot[t] += 1
+    assert slot.max() <= k
+    return jnp.asarray(idx)
+
+
+#: 24 tokens, top-4 of 16, 4 held: a 96-row buffer.  (counts per held
+#: expert, chunk rows) -> what the case is about
+_CHUNK_CASES = {
+    "one_chunk": ([5, 3, 7, 2], None),                # 17 live, 1 chunk of 96
+    "several_chunks": ([5, 3, 7, 2], 8),              # 3 of 12 chunks run
+    "ends_on_a_boundary": ([5, 3, 6, 2], 8),          # 16 live = 2 chunks
+    "one_past_a_boundary": ([5, 3, 7, 2], 16),        # 17 live: 2 of 6 chunks
+    "group_straddles_a_boundary": ([5, 9, 1, 0], 8),  # expert 1: rows 5-13
+    "chunk_does_not_divide_the_buffer": ([5, 3, 7, 2], 7),   # 96 = 13*7 + 5
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CHUNK_CASES))
+def test_chunked_result_and_gradients_equal_the_dense_loop(case):
+    counts, chunk_rows = _CHUNK_CASES[case]
+    idx = _idx_with_counts(24, counts, k=4, total=16)
+    live, chunks, share, *_ = _check_chunked(idx, len(counts), 0,
+                                             chunk_rows)
+    assert live == sum(counts)
+    if case == "one_chunk":
+        assert (chunks, share) == (1, 1.0)
+    if case == "ends_on_a_boundary":
+        assert share == pytest.approx(2 / 12)
+    if case == "one_past_a_boundary":
+        assert share == pytest.approx(2 / 6)
+    if case == "several_chunks":
+        assert share == pytest.approx(3 / 12)
+
+
+@pytest.mark.parametrize("chunk_rows", [None, 16, 10])
+def test_worst_case_routing_runs_every_chunk(chunk_rows):
+    """Every token on every held expert: the whole buffer is live, every
+    chunk runs, nothing is dropped, and the result is the loop's."""
+    n, held = 20, 4
+    idx = jnp.tile(jnp.arange(held, dtype=jnp.int32)[None, :], (n, 1))
+    live, _, share, *_ = _check_chunked(idx, held, 0, chunk_rows, seed=1)
+    assert live == moe_lib.dropless_buffer_rows(n, held, held) == n * held
+    assert share == 1.0
+
+
+@pytest.mark.parametrize("chunk_rows", [None, 16])
+def test_nothing_routed_here_runs_no_chunk(chunk_rows):
+    """No token chooses a held expert: no chunk runs, the result and every
+    gradient are zeros, and nothing is NaN."""
+    n, held, offset = 20, 4, 4
+    idx = jnp.tile(jnp.array([[0, 1, 2, 9]], jnp.int32), (n, 1))
+    live, _, share, out, grads = _check_chunked(idx, held, offset,
+                                                chunk_rows, seed=2)
+    assert (live, share) == (0, 0.0)
+    assert not bool(out.any())
+    assert not any(bool(g.any()) for g in grads)
 
 
 def _layer(cfg, params, u, **kw):

@@ -270,6 +270,8 @@ def test_step_follows_the_reference_and_hands_back_counters(token_step,
     assert new.batch_stats == {}
     assert int(counters["moe_tokens_dropped"]) == 0
     assert float(counters["moe_expert_load_max_over_mean"]) >= 1.0
+    # every expert layer's row buffer is one chunk here, and it holds tokens
+    assert float(counters["moe_chunks_run_share"]) == 1.0
     zeros = jax.tree.map(jnp.zeros_like, state.params)
 
     def ref_step(**kw):
@@ -374,6 +376,17 @@ def test_scope_table_holds_every_new_layer(token_step):
             (scopes.MOE, scopes.MOE_LATENT),
             (scopes.MTP, scopes.MOE_DISPATCH), (scopes.MTP, scopes.ATTN)]:
         assert (layer, part) in parts, (layer, part)
+    # an expert layer's chunk loop (parallel/moe.py): its three parts keep
+    # their names inside the loop's body, forward and reverse, in the trunk
+    # and in the prediction module: the three per-layer metrics that read
+    # them cannot silently empty
+    for layer in (scopes.MOE, scopes.MTP):
+        for part in (scopes.MOE_DISPATCH, scopes.MOE_ROUTED_EXPERTS,
+                     scopes.MOE_COMBINE):
+            for phase in ("fwd", "bwd"):
+                assert any(s.layer == layer and s.phase == phase
+                           and s.path.endswith("/" + part)
+                           for s in table.values()), (layer, part, phase)
     # a block's reverse pass sits under the same block: the path of a
     # rematerialised block does not start over at the root
     assert not any("NemotronH" in s.path for s in table.values())
@@ -390,6 +403,12 @@ def test_scope_table_holds_every_new_layer(token_step):
     lost = [k for k, s in table.items() if s.layer == scopes.OTHER
             and "NemotronH" in named.get(k, "")]
     assert len(lost) < 0.01 * len(table), lost[:5]
+    # and those parts' ops do sit in the loop's body
+    in_loop = [n for n in named.values() if "/while/body/" in n]
+    for part in (scopes.MOE_DISPATCH, scopes.MOE_ROUTED_EXPERTS,
+                 scopes.MOE_COMBINE):
+        assert any(f"/{part}/" in n.partition("/while/body")[2]
+                   or f"({part})" in n for n in in_loop), part
 
 
 @pytest.mark.parametrize("op_name,want", [
@@ -402,6 +421,28 @@ def test_scope_table_holds_every_new_layer(token_step):
      ("mtp", "mtp/moe/l01/routed_experts", "fwd")),
     ("jit(step_fn)/jvp(NemotronH)/lm_head/dot_general",
      ("lm_head", "lm_head", "fwd")),
+    # the chunk loop of an expert layer: the loop's own elements (``while``,
+    # ``body``, a ``cond``'s branch) are stepped over, forward ...
+    ("jit(step_fn)/jvp(NemotronH)/moe/l01/while/body/dispatch/jit(_take)/"
+     "gather", ("moe", "moe/l01/dispatch", "fwd")),
+    ("jit(step_fn)/jvp(NemotronH)/mtp/moe/l01/while/body/routed_experts/"
+     "ragged_dot", ("mtp", "mtp/moe/l01/routed_experts", "fwd")),
+    ("jit(step_fn)/jvp(NemotronH)/moe/l05/while/body/cond/branch_1_fun/"
+     "combine/scatter-add", ("moe", "moe/l05/combine", "fwd")),
+    # ... recomputed in the reverse pass, and in the reverse pass's own loop,
+    # where the chunk's products are differentiated inside the body
+    ("jit(step_fn)/transpose(jvp(NemotronH))/moe/jvp(NemotronH)/moe/"
+     "checkpoint/rematted_computation/l01/while/body/combine/scatter-add",
+     ("moe", "moe/l01/combine", "bwd")),
+    ("jit(step_fn)/transpose(jvp(NemotronH))/moe/jvp(NemotronH)/moe/"
+     "checkpoint/l03/while/body/transpose(jvp(routed_experts))/dot_general",
+     ("moe", "moe/l03/routed_experts", "bwd")),
+    ("jit(step_fn)/transpose(jvp(NemotronH))/moe/jvp(NemotronH)/moe/"
+     "checkpoint/l03/while/body/transpose(jvp(combine))/mul",
+     ("moe", "moe/l03/combine", "bwd")),
+    ("jit(step_fn)/transpose(jvp(NemotronH))/mtp/moe/jvp(NemotronH)/mtp/moe/"
+     "checkpoint/l01/while/body/dispatch/scatter-add",
+     ("mtp", "mtp/moe/l01/dispatch", "bwd")),
 ])
 def test_token_op_names_resolve_to_their_layer(op_name, want):
     s = scopes.scope_of(op_name)

@@ -208,12 +208,14 @@ def test_latent_moe_block_compiles_at_published_widths(v5e_mesh):
     4,096, latent 1,024, experts 2,688 wide, 8 of 512 held, top-22) over
     8,192 tokens in bfloat16, forward and reverse: the dropless grouped
     product (``jax.lax.ragged_dot``) and its two transposes go through the
-    TPU compiler's own grouped-matmul calls, over the worst-case row buffer
+    TPU compiler's own grouped-matmul calls, over one chunk of the row
+    buffer at a time: nothing wide is made at the worst-case buffer's size
     (8,192 x 8 rows), and nothing of it is an (N, E, C) dispatch tensor."""
     import json
     import os
 
     from distributedpytorch_tpu.models import nemotron_h as nh
+    from distributedpytorch_tpu.parallel import moe as moe_lib
 
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(here, "benchmarks", "configs",
@@ -238,6 +240,11 @@ def test_latent_moe_block_compiles_at_published_widths(v5e_mesh):
     grouped = [ln for ln in _custom_calls(hlo) if "ragged" in ln.lower()]
     # two products forward, and each one's two transposes
     assert len(grouped) >= 6, len(grouped)
-    rows = 8192 * cfg.experts_held
-    assert re.search(rf"bf16\[{rows},{cfg.expert_hidden}\]", hlo)
+    assert re.search(rf"bf16\[{moe_lib.CHUNK_ROWS},{cfg.expert_hidden}\]",
+                     hlo)
+    rows = moe_lib.dropless_buffer_rows(8192, cfg.experts_per_token,
+                                        cfg.experts_held)
+    assert rows == 8192 * cfg.experts_held
+    assert not re.search(
+        rf"\[{rows},({cfg.latent_size}|{cfg.expert_hidden})\]", hlo)
     assert not re.search(r"\[8192,512,\d+\]", hlo)
