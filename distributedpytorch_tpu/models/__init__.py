@@ -189,6 +189,29 @@ def build_model(
         "| pspnet | encnet | ccnet | nemotron_h)")
 
 
+def build_from_config(mcfg, *, dtype, bn_cross_replica_axis=None,
+                      pam_sp_mesh=None):
+    """``ModelConfig`` -> model: the one place that names the config's model
+    fields as :func:`build_model`'s arguments, so the trainer, the planner's
+    shape-only template and ``predict`` rebuild the same module.  A caller
+    decides the compute ``dtype`` (a precision policy's, or ``mcfg.dtype``),
+    the mesh axis BatchNorm reduces over, the mesh ring PAM shards over."""
+    return build_model(
+        name=mcfg.name, nclass=mcfg.nclass, backbone=mcfg.backbone,
+        output_stride=mcfg.output_stride, dtype=dtype,
+        bn_fp32_stats=mcfg.bn_fp32_stats,
+        bn_cross_replica_axis=bn_cross_replica_axis,
+        pam_block_size=mcfg.pam_block_size,
+        attention_impl=mcfg.attention_impl, pam_impl=mcfg.pam_impl,
+        pam_score_dtype=mcfg.pam_score_dtype, pam_sp_mesh=pam_sp_mesh,
+        remat=mcfg.remat, remat_policy=mcfg.remat_policy or None,
+        moe_experts=mcfg.moe_experts, moe_hidden=mcfg.moe_hidden,
+        moe_k=mcfg.moe_k, moe_capacity_factor=mcfg.moe_capacity_factor,
+        aux_head=mcfg.aux_head, encnet_codes=mcfg.encnet_codes,
+        ccnet_recurrence=mcfg.ccnet_recurrence,
+        guidance_inject=mcfg.guidance_inject, lm_config=mcfg.lm_config)
+
+
 __all__ = [
     "model_tasks",
     "ASPP",
@@ -207,6 +230,7 @@ __all__ = [
     "PSPNet",
     "PyramidPooling",
     "ResNet",
+    "build_from_config",
     "build_model",
     "resnet50",
     "resnet101",

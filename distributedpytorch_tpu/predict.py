@@ -30,6 +30,7 @@ interactive-latency design point.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 from typing import Any, Sequence
 
@@ -122,29 +123,21 @@ def model_from_config(cfg):
     or checkpoint restore fails.  train.precision carries over: a
     bf16-trained run serves bf16 (master params are f32 either way, so
     restore is dtype-independent)."""
-    from .models import build_model
+    from .models import build_from_config
+    from .train.config import ModelConfig
     from .train.precision import precision_policy
 
     policy = precision_policy(
         getattr(getattr(cfg, "train", None), "precision", None))
-    return build_model(
-        name=cfg.model.name, nclass=cfg.model.nclass,
-        backbone=cfg.model.backbone,
-        output_stride=cfg.model.output_stride,
-        dtype=(policy.compute_dtype if policy else cfg.model.dtype),
-        pam_block_size=cfg.model.pam_block_size,
-        attention_impl=getattr(cfg.model, "attention_impl", "auto"),
-        pam_impl="einsum" if cfg.model.pam_impl == "ring"
-        else cfg.model.pam_impl,
-        pam_score_dtype=getattr(cfg.model, "pam_score_dtype", None),
-        remat=cfg.model.remat,
-        moe_experts=cfg.model.moe_experts,
-        moe_hidden=cfg.model.moe_hidden, moe_k=cfg.model.moe_k,
-        moe_capacity_factor=cfg.model.moe_capacity_factor,
-        aux_head=cfg.model.aux_head,
-        encnet_codes=getattr(cfg.model, "encnet_codes", 32),
-        ccnet_recurrence=getattr(cfg.model, "ccnet_recurrence", 2),
-        guidance_inject=getattr(cfg.model, "guidance_inject", "stem"))
+    # a config saved before a field existed carries that field's default
+    mcfg = ModelConfig(**{
+        f.name: getattr(cfg.model, f.name)
+        for f in dataclasses.fields(ModelConfig)
+        if hasattr(cfg.model, f.name)})
+    if mcfg.pam_impl == "ring":
+        mcfg.pam_impl = "einsum"
+    return build_from_config(
+        mcfg, dtype=policy.compute_dtype if policy else mcfg.dtype)
 
 
 def load_run(run_dir: str, best: bool = True, cfg=None):

@@ -32,24 +32,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..data import (
-    DataLoader,
-    VOCInstanceSegmentation,
-    VOCSemanticSegmentation,
-    build_eval_transform,
-    build_semantic_eval_transform,
-    build_semantic_train_transform,
-    build_train_transform,
-    make_fake_voc,
-)
+from ..data import DataLoader, make_fake_voc
 from ..data.governor import GOVERNOR_MODES, FeedActuators, FeedGovernor
 from ..chaos import sites as chaos_sites
-from ..models import build_model, model_tasks
+from ..models import build_from_config, model_tasks
 from ..parallel import (
     DATA_AXIS,
-    DEVICE_KEYS,
-    NEXT_TOKEN,
-    TOKENS_KEY,
     WIRE_KEY,
     create_train_state,
     make_eval_step,
@@ -65,17 +53,12 @@ from ..utils.helpers import generate_param_report
 from ..utils.profiling import device_memory_stats
 from ..chaos.policies import CircuitBreaker, CircuitOpenError
 from . import config as config_lib
+from . import tasks
 from .checkpoint import (
     CheckpointManager,
     atomic_write_json,
     latest_checkpoint_dir,
     next_run_dir,
-)
-from .evaluate import (
-    batch_debug_asserts,
-    evaluate,
-    evaluate_semantic,
-    semantic_batch_debug_asserts,
 )
 from .logging import (
     MetricWriter,
@@ -201,23 +184,15 @@ class Trainer:
         else:
             self.writer = MetricWriter()  # no-op on non-main hosts
 
-        #: the tokens task (next-token training of a token model): another
-        #: batch ({tokens}), another loss, no BatchNorm statistics
-        self._token_task = cfg.task == "tokens"
         if cfg.task not in model_tasks(cfg.model.name):
             raise ValueError(
                 f"task={cfg.task!r} with model.name={cfg.model.name!r}: "
                 f"that model trains under task="
                 f"{' | '.join(model_tasks(cfg.model.name))}")
-        if cfg.task == "instance" and cfg.model.nclass != 1:
-            # The instance protocol is binary by construction (sigmoid
-            # prediction pasted back per object, reference
-            # train_pascal.py:262,283-291); a multi-channel head would fail
-            # opaquely inside the evaluator's paste-back.
-            raise ValueError(
-                f"task='instance' requires model.nclass=1 (binary sigmoid "
-                f"head), got {cfg.model.nclass}; use task='semantic' for "
-                "multi-class")
+        #: what the loop is told of the task (train/tasks.py): it asks this
+        #: object, never the task's name
+        self.task = tasks.get(cfg.task)
+        self.task.check(cfg)
         if cfg.data.echo < 1:
             raise ValueError(f"data.echo must be >= 1, got {cfg.data.echo}")
         if cfg.data.source not in ("fs", "packed"):
@@ -275,17 +250,6 @@ class Trainer:
                 "per host batch in incompatible ways — pick one (echo "
                 "re-steps the SAME batch; steps_per_dispatch packs "
                 "DISTINCT batches into one dispatch)")
-        if (cfg.eval_tta_scales or cfg.eval_tta_flip) \
-                and cfg.task != "semantic":
-            raise ValueError(
-                "eval_tta_scales/eval_tta_flip apply to the semantic task "
-                "only (the instance protocol is the reference's fixed "
-                "threshold sweep)")
-        if cfg.eval_full_res and cfg.task != "semantic":
-            raise ValueError(
-                "eval_full_res applies to the semantic task only (the "
-                "instance protocol already scores at full resolution via "
-                "crop2fullmask paste-back)")
 
         # --- parallel plan (parallel/plan.py): the declarative strategy
         # -> validated mesh + composed sharding layout.  With
@@ -347,13 +311,6 @@ class Trainer:
         #: the resolved dataset root (fake fixtures land under the run
         #: dir) — the governor's pack_recommendation names it
         self._data_root = root
-        if cfg.data.packbits_masks and not (
-                cfg.data.uint8_transfer and cfg.task == "instance"):
-            raise ValueError(
-                "data.packbits_masks packs the BINARY instance mask for "
-                "the uint8 wire — it requires task=instance (semantic gt "
-                "is class ids, not bits) and data.uint8_transfer (the "
-                "packed row rides the uint8 fast path)")
         if cfg.data.coalesce_wire and not cfg.data.uint8_transfer:
             raise ValueError(
                 "data.coalesce_wire concatenates the batch's uint8 leaves "
@@ -366,26 +323,6 @@ class Trainer:
                 "prepared pipeline is uint8-exact end-to-end (the plain "
                 "pipeline's cubic resize leaves fractional float values "
                 "that quantization would silently alter)")
-        if cfg.data.uint8_transfer and cfg.task == "instance" \
-                and not (cfg.data.device_guidance
-                         or cfg.data.guidance == "none"):
-            raise ValueError(
-                "data.uint8_transfer with HOST-side guidance is a no-op on "
-                "the dominant tensor: concatenating the float guidance map "
-                "promotes 'concat' back to float32, so the advertised 4x "
-                "wire saving never happens — set data.device_guidance=true "
-                "(the map is synthesized on device from the uint8 crop_gt) "
-                "or data.guidance=none")
-        if cfg.data.device_guidance:
-            from ..ops.guidance_device import FAMILIES as _DEV_FAM
-            if cfg.task != "instance":
-                raise ValueError("data.device_guidance applies to the "
-                                 "instance task only (semantic has no "
-                                 "guidance channel)")
-            if cfg.data.guidance not in _DEV_FAM:
-                raise ValueError(
-                    f"data.device_guidance supports {_DEV_FAM}, not "
-                    f"{cfg.data.guidance!r}")
         if cfg.val_overlap and jax.process_count() > 1:
             raise ValueError(
                 "val_overlap is single-process only: the val thread and "
@@ -395,243 +332,11 @@ class Trainer:
         #: in-flight overlapped validation (val_overlap): set by
         #: _launch_overlapped_val, consumed by _join_overlapped_val
         self._pending_val = None
-        #: set by the instance branch when the prepared val wire ships
-        #: 3-channel batches and the eval step owns guidance synthesis
-        self._val_device_guidance = False
-        #: set by the instance branch when the prepared val wire ships the
-        #: packed 1-bit crop_gt (the eval step unpacks)
-        self._val_packbits = False
-        if cfg.task == "instance":
-            prepared = bool(cfg.data.prepared_cache)
-            # Prepared cache owns the deterministic crop stage itself; the
-            # wrapped dataset must stay untransformed.
-            train_tf = None if prepared else build_train_transform(
-                crop_size=cfg.data.crop_size, relax=cfg.data.relax,
-                zero_pad=cfg.data.zero_pad, rots=cfg.data.rots,
-                scales=cfg.data.scales, alpha=cfg.data.guidance_alpha,
-                # device guidance: host delivers bare image channels as
-                # 'concat'; the fused stage appends the map from crop_gt
-                guidance=("none" if cfg.data.device_guidance
-                          else cfg.data.guidance),
-                flip=not cfg.data.device_augment,
-                geom=not (cfg.data.device_augment
-                          and cfg.data.device_augment_geom),
-                fused_crop_resize=cfg.data.fused_crop_resize)
-            #: val fast path (data.val_prepared): eval is deterministic end
-            #: to end, so the whole per-epoch val front caches — decode,
-            #: crop, resize, full-res metric masks; with device_guidance
-            #: the wire also drops to 3-channel uint8 and the jitted eval
-            #: step appends the guidance channel (is_val semantics).
-            val_prep = prepared and cfg.data.val_prepared
-            self._val_device_guidance = val_prep and cfg.data.device_guidance
-            self._val_packbits = val_prep and cfg.data.packbits_masks
-            val_tf = None if val_prep else build_eval_transform(
-                crop_size=cfg.data.crop_size, relax=cfg.data.relax,
-                zero_pad=cfg.data.zero_pad, alpha=cfg.data.guidance_alpha,
-                guidance=cfg.data.guidance)
-            if cfg.data.source == "packed":
-                # pre-decoded mmap records (data/packed.py): no dataset
-                # walk, no per-sample decode — samples bit-identical to
-                # the fs classes by construction
-                self.train_set = self._open_pack(
-                    "voc", [cfg.data.train_split], train_tf,
-                    quarantine=cfg.data.pack_quarantine)
-                self.val_set = self._open_pack(
-                    "voc", [cfg.data.val_split], val_tf)
-            else:
-                # download (if requested) already happened above,
-                # gated+barriered
-                self.train_set = VOCInstanceSegmentation(
-                    root, split=cfg.data.train_split, transform=train_tf,
-                    preprocess=True, area_thres=cfg.data.area_thres,
-                    decode_cache=cfg.data.decode_cache)
-                self.val_set = VOCInstanceSegmentation(
-                    root, split=cfg.data.val_split, transform=val_tf,
-                    preprocess=True, area_thres=cfg.data.area_thres,
-                    decode_cache=cfg.data.decode_cache)
-            if val_prep:
-                from ..data import PreparedInstanceDataset
-                from ..data.pipeline import build_prepared_eval_post_transform
-                self.val_set = PreparedInstanceDataset(
-                    self.val_set, cfg.data.prepared_cache,
-                    crop_size=cfg.data.crop_size, relax=cfg.data.relax,
-                    zero_pad=cfg.data.zero_pad,
-                    fused_crop_resize=cfg.data.fused_crop_resize,
-                    uint8_arrays=cfg.data.uint8_transfer,
-                    eval_protocol=True,
-                    max_im_size=cfg.data.val_max_im_size,
-                    post_transform=build_prepared_eval_post_transform(
-                        alpha=cfg.data.guidance_alpha,
-                        guidance=("none" if cfg.data.device_guidance
-                                  else cfg.data.guidance),
-                        uint8_wire=cfg.data.uint8_transfer,
-                        packbits=cfg.data.packbits_masks))
-            if cfg.data.sbd_root:
-                # the reference's use_sbd recipe (train_pascal.py:150-154),
-                # live: merge SBD train+val, drop its VOC-val overlap
-                from ..data import CombinedDataset, SBDInstanceSegmentation
-                if cfg.data.source == "packed":
-                    sbd = self._open_pack("sbd", ["train", "val"],
-                                          train_tf)
-                else:
-                    sbd = SBDInstanceSegmentation(
-                        cfg.data.sbd_root, split=["train", "val"],
-                        transform=train_tf,
-                        preprocess=True,  # same always-rebuild as VOC
-                        area_thres=cfg.data.area_thres,
-                        decode_cache=cfg.data.decode_cache)
-                self.train_set = CombinedDataset(
-                    [self.train_set, sbd], excluded=[self.val_set])
-            if cfg.data.session_log:
-                # flywheel: serve session logs as training data
-                # (data/sessions.py).  session_only replays the EXACT
-                # serving inputs (the continuous mode's incremental
-                # fits); otherwise the log joins the VOC(+SBD) mix as a
-                # sampled source under the standard transform stack.
-                if prepared:
-                    raise ValueError(
-                        "data.session_log does not compose with "
-                        "data.prepared_cache — the session log already "
-                        "IS a pre-decoded, pre-cropped source; drop one "
-                        "of the two")
-                from ..data import CombinedDataset
-                from ..data.sessions import SessionLogDataset
-                if cfg.data.session_only:
-                    sessions = SessionLogDataset(
-                        cfg.data.session_log, mode="replay",
-                        quarantine=cfg.data.session_quarantine)
-                    if tuple(sessions.resolution) != \
-                            tuple(cfg.data.crop_size):
-                        raise ValueError(
-                            f"session log {cfg.data.session_log} was "
-                            f"captured at resolution "
-                            f"{sessions.resolution} but this run trains "
-                            f"at data.crop_size={cfg.data.crop_size} — "
-                            "replay feeds the serving inputs verbatim, "
-                            "so the two must match")
-                    self.train_set = sessions
-                else:
-                    sessions = SessionLogDataset(
-                        cfg.data.session_log, mode="sample",
-                        transform=train_tf,
-                        quarantine=cfg.data.session_quarantine)
-                    self.train_set = CombinedDataset(
-                        [self.train_set, sessions],
-                        excluded=[self.val_set])
-            elif cfg.data.session_only:
-                raise ValueError(
-                    "data.session_only requires data.session_log")
-            if prepared:
-                from ..data import (
-                    PreparedInstanceDataset,
-                    build_prepared_post_transform,
-                )
-                self.train_set = PreparedInstanceDataset(
-                    self.train_set, cfg.data.prepared_cache,
-                    crop_size=cfg.data.crop_size, relax=cfg.data.relax,
-                    zero_pad=cfg.data.zero_pad,
-                    fused_crop_resize=cfg.data.fused_crop_resize,
-                    uint8_arrays=cfg.data.uint8_transfer,
-                    post_transform=build_prepared_post_transform(
-                        rots=cfg.data.rots, scales=cfg.data.scales,
-                        alpha=cfg.data.guidance_alpha,
-                        guidance=("none" if cfg.data.device_guidance
-                                  else cfg.data.guidance),
-                        flip=not cfg.data.device_augment,
-                        geom=not (cfg.data.device_augment
-                                  and cfg.data.device_augment_geom),
-                        uint8_wire=cfg.data.uint8_transfer,
-                        packbits=cfg.data.packbits_masks))
-        elif cfg.task == "semantic":
-            prepared = bool(cfg.data.prepared_cache)
-            sem_train_tf = None if prepared else \
-                build_semantic_train_transform(
-                    crop_size=cfg.data.crop_size, rots=cfg.data.rots,
-                    scales=cfg.data.scales,
-                    flip=not cfg.data.device_augment,
-                    geom=not (cfg.data.device_augment
-                              and cfg.data.device_augment_geom))
-            if cfg.data.source == "packed":
-                self.train_set = self._open_pack(
-                    "voc", [cfg.data.train_split], sem_train_tf,
-                    quarantine=cfg.data.pack_quarantine)
-            else:
-                self.train_set = VOCSemanticSegmentation(
-                    root, split=cfg.data.train_split,
-                    transform=sem_train_tf,
-                    decode_cache=cfg.data.decode_cache)
-            # Val has no decode cache (one sample per image, scanned
-            # sequentially — an LRU smaller than the split gets zero hits).
-            # Built before the SBD merge so the merge can exclude its
-            # overlap (SBD train covers most of VOC val — the standard
-            # "train_aug" recipe needs the exclusion).
-            #
-            # val fast path (data.val_prepared): the semantic val front
-            # (decode → resize → clamp) is deterministic and identical to
-            # the prepared cache's stage1, so serve val from a prepared
-            # cache too — with uint8_transfer the 25 MB f32 val batches
-            # drop to uint8.  The full-res protocol composes: its
-            # native-resolution gt caches as padded uint8 id rows,
-            # emitted ragged as ``gt_full``.
-            sem_val_prep = prepared and cfg.data.val_prepared
-            sem_val_tf = None if sem_val_prep else \
-                build_semantic_eval_transform(
-                    crop_size=cfg.data.crop_size,
-                    keep_fullres=cfg.eval_full_res)
-            if cfg.data.source == "packed":
-                self.val_set = self._open_pack(
-                    "voc", [cfg.data.val_split], sem_val_tf)
-            else:
-                self.val_set = VOCSemanticSegmentation(
-                    root, split=cfg.data.val_split,
-                    transform=sem_val_tf)
-            if sem_val_prep:
-                from ..data.pipeline import (
-                    build_prepared_semantic_eval_post_transform,
-                )
-                from ..data.prepared import PreparedSemanticDataset
-                self.val_set = PreparedSemanticDataset(
-                    self.val_set, cfg.data.prepared_cache,
-                    crop_size=cfg.data.crop_size,
-                    uint8_arrays=cfg.data.uint8_transfer,
-                    keep_fullres=cfg.eval_full_res,
-                    max_im_size=cfg.data.val_max_im_size,
-                    post_transform=(
-                        build_prepared_semantic_eval_post_transform(
-                            uint8_wire=cfg.data.uint8_transfer)))
-            if cfg.data.sbd_root:
-                from ..data import CombinedDataset
-                from ..data.sbd import SBDSemanticSegmentation
-                if cfg.data.source == "packed":
-                    sbd = self._open_pack("sbd", ["train", "val"],
-                                          sem_train_tf)
-                else:
-                    sbd = SBDSemanticSegmentation(
-                        cfg.data.sbd_root, split=["train", "val"],
-                        transform=sem_train_tf,
-                        decode_cache=cfg.data.decode_cache)
-                self.train_set = CombinedDataset(
-                    [self.train_set, sbd], excluded=[self.val_set])
-            if prepared:
-                from ..data.pipeline import (
-                    build_prepared_semantic_post_transform,
-                )
-                from ..data.prepared import PreparedSemanticDataset
-                self.train_set = PreparedSemanticDataset(
-                    self.train_set, cfg.data.prepared_cache,
-                    crop_size=cfg.data.crop_size,
-                    uint8_arrays=cfg.data.uint8_transfer,
-                    post_transform=build_prepared_semantic_post_transform(
-                        rots=cfg.data.rots, scales=cfg.data.scales,
-                        flip=not cfg.data.device_augment,
-                        geom=not (cfg.data.device_augment
-                                  and cfg.data.device_augment_geom),
-                        uint8_wire=cfg.data.uint8_transfer))
-        elif self._token_task:
-            self.train_set, self.val_set = self._token_sets()
-        else:
-            raise ValueError(
-                f"unknown task: {cfg.task!r} (instance | semantic | tokens)")
+        self.train_set, self.val_set, val_wire = self.task.datasets(
+            cfg, tasks.DataContext(root, self._open_pack))
+        #: what the prepared val wire ships that the eval step undoes
+        #: (tasks.ValWire): 3-channel batches, the packed 1-bit crop_gt
+        self._val_device_guidance, self._val_packbits = val_wire
         # Batch sizes are GLOBAL (the reference's trainBatch=16 spans its 4
         # GPUs; BASELINE speaks of global batches); each host's loader feeds
         # its 1/process_count share, which shard_batch assembles into the
@@ -716,30 +421,14 @@ class Trainer:
                     "(model axis 1) and a non-ring PAM — its shard_map "
                     "region owns the data axis; nearest supported: "
                     "parallel.strategy=dp (or dp_zero1)")
-        self.model = build_model(
-            name=cfg.model.name, nclass=cfg.model.nclass,
-            backbone=cfg.model.backbone, output_stride=cfg.model.output_stride,
+        self.model = build_from_config(
+            cfg.model,
             dtype=(self.precision.compute_dtype if self.precision
                    else cfg.model.dtype),
-            bn_fp32_stats=cfg.model.bn_fp32_stats,
             bn_cross_replica_axis=(DATA_AXIS if cfg.train.reduce_buckets
                                    else None),
-            pam_block_size=cfg.model.pam_block_size,
-            attention_impl=cfg.model.attention_impl,
-            pam_impl=cfg.model.pam_impl,
-            pam_score_dtype=cfg.model.pam_score_dtype,
             # ring PAM shards the spatial tokens over this mesh's model axis
-            pam_sp_mesh=(self.mesh if cfg.model.pam_impl == "ring" else None),
-            remat=cfg.model.remat,
-            remat_policy=cfg.model.remat_policy or None,
-            moe_experts=cfg.model.moe_experts,
-            moe_hidden=cfg.model.moe_hidden, moe_k=cfg.model.moe_k,
-            moe_capacity_factor=cfg.model.moe_capacity_factor,
-            aux_head=cfg.model.aux_head,
-            encnet_codes=cfg.model.encnet_codes,
-            ccnet_recurrence=cfg.model.ccnet_recurrence,
-            guidance_inject=cfg.model.guidance_inject,
-            lm_config=cfg.model.lm_config)
+            pam_sp_mesh=(self.mesh if cfg.model.pam_impl == "ring" else None))
         steps_per_epoch = len(self.train_loader)  # > 0: guarded above
         # Each loaded batch is stepped data.echo times, so schedules (poly
         # decay, warmup fractions) must span echo x the loader length or
@@ -749,12 +438,9 @@ class Trainer:
         with self.mesh:
             self.state = create_train_state(
                 jax.random.PRNGKey(cfg.seed), self.model, self.tx,
-                **self._init_input(), mesh=self.mesh,
+                **self.task.init_input(cfg), mesh=self.mesh,
                 shard_params=self.plan.shard_params,
                 shard_opt_state=self.plan.shard_opt_state)
-        loss_type = (NEXT_TOKEN if self._token_task else
-                     "multi_softmax" if cfg.task == "semantic"
-                     else "multi_sigmoid")
         loss_weights = cfg.model.loss_weights
         if loss_weights is None:
             # a model may state its own (a token model: the next-token
@@ -764,8 +450,8 @@ class Trainer:
         # into the compiled steps (live shardings — exactly what
         # create_train_state placed); the plan owns the threading rule.
         st_sh = self.plan.state_shardings(self.state, self.mesh)
-        augment = None if self._token_task else self._build_device_stage(
-            cfg.data.device_augment, cfg.data.device_guidance)
+        augment = self.task.device_stage and self.task.device_stage(
+            cfg, cfg.data.device_augment, cfg.data.device_guidance)
         # --- self-healing sentinel (train/sentinel.py; see fit()): built
         # before the steps because monitor_grads changes their outputs
         sc = cfg.sentinel
@@ -795,7 +481,8 @@ class Trainer:
         step_kwargs = dict(
             loss_weights=loss_weights,
             accum_steps=cfg.optim.accum_steps, mesh=self.mesh,
-            loss_type=loss_type, state_shardings=st_sh, augment=augment,
+            loss_type=self.task.loss_type, state_shardings=st_sh,
+            augment=augment,
             aux_loss_weight=(cfg.model.moe_aux_weight
                              if cfg.model.moe_experts else 0.0),
             loss_scale=cfg.optim.loss_scale,
@@ -853,36 +540,21 @@ class Trainer:
             if (cfg.data.governor != "off" and cfg.telemetry
                 and (self.is_main or gov_multi)) else None
         self._feed_last: dict | None = None
-        eval_preprocess = None
-        if self._val_device_guidance:
-            # prepared val ships bare image channels; append the guidance
-            # channel on device with the DETERMINISTIC val semantics
-            # (extreme_points_fixed — bit-exact vs the host at pert=0).
-            # The rng argument is never consumed at is_val.
-            from ..ops.guidance_device import make_device_guidance
-            gstage = make_device_guidance(
-                family=cfg.data.guidance, alpha=cfg.data.guidance_alpha,
-                is_val=True)
-            fixed_key = jax.random.PRNGKey(0)
-
-            def eval_preprocess(b, _g=gstage, _k=fixed_key):
-                return _g(b, _k)
         #: what the last dispatch's model counted, if it sows counters
         self._last_counters: dict | None = None
         self.eval_step = make_eval_step(
             self.model, loss_weights=loss_weights, mesh=self.mesh,
-            loss_type=loss_type, state_shardings=st_sh,
-            preprocess=eval_preprocess,
-            packbits_masks=self._val_packbits)
+            loss_type=self.task.loss_type, state_shardings=st_sh,
+            preprocess=val_wire.preprocess(cfg),
+            packbits_masks=val_wire.packbits)
 
         # --- checkpointing
         self.ckpt = CheckpointManager(
             os.path.join(self.run_dir, "checkpoints"),
             keep_latest=cfg.checkpoint.keep_latest,
-            # a token model is gated on the negated val loss, which never
-            # reaches the Jaccard scale's 0
-            best_metric_init=(-1e30 if self._token_task
-                              else cfg.checkpoint.best_metric_init),
+            best_metric_init=(cfg.checkpoint.best_metric_init
+                              if self.task.best_init is None
+                              else self.task.best_init),
             async_save=cfg.checkpoint.async_save,
             digest=cfg.checkpoint.digest,
             # every save's meta names the plan that laid the state out —
@@ -955,26 +627,24 @@ class Trainer:
         )
 
         cfg = self.cfg
-        path = pack_dir_path(cfg.data.pack_path, dataset_name, cfg.task,
-                             splits)
+        kind = self.task.pack_kind
+        area_thres = self.task.pack_area_thres(cfg)
+        path = pack_dir_path(cfg.data.pack_path, dataset_name, kind, splits)
         root = (cfg.data.sbd_root if dataset_name == "sbd"
                 else self._data_root)
-        cmd = pack_command(root, cfg.data.pack_path, dataset_name,
-                           cfg.task, splits,
-                           cfg.data.area_thres if cfg.task == "instance"
-                           else None)
+        cmd = pack_command(root, cfg.data.pack_path, dataset_name, kind,
+                           splits, area_thres)
         try:
             ds = PackedDataset(path, transform=transform,
-                               quarantine=quarantine,
-                               expect_kind=cfg.task)
+                               quarantine=quarantine, expect_kind=kind)
         except (OSError, PackFormatError) as e:
             raise ValueError(
                 f"data.source=packed but no readable "
-                f"{dataset_name}/{cfg.task} pack at {path} "
+                f"{dataset_name}/{kind} pack at {path} "
                 f"({type(e).__name__}: {e}) — build it once: `{cmd}`"
             ) from e
-        if cfg.task == "instance" \
-                and ds.meta.get("area_thres") != cfg.data.area_thres:
+        if area_thres is not None \
+                and ds.meta.get("area_thres") != area_thres:
             raise ValueError(
                 f"pack {path} was built with area_thres="
                 f"{ds.meta.get('area_thres')} but this run wants "
@@ -1005,77 +675,16 @@ class Trainer:
         batch's byte count.  Built from the config alone, before the
         mesh exists — the plan decides the mesh."""
         cfg = self.cfg
-        h, w = cfg.data.crop_size
-        in_ch = cfg.model.in_channels
-        model = build_model(
-            name=cfg.model.name, nclass=cfg.model.nclass,
-            backbone=cfg.model.backbone,
-            output_stride=cfg.model.output_stride,
-            dtype=(precision_policy(cfg.train.precision).compute_dtype
-                   if precision_policy(cfg.train.precision)
-                   else cfg.model.dtype),
-            moe_experts=cfg.model.moe_experts,
-            moe_hidden=cfg.model.moe_hidden, moe_k=cfg.model.moe_k,
-            moe_capacity_factor=cfg.model.moe_capacity_factor,
-            aux_head=cfg.model.aux_head,
-            encnet_codes=cfg.model.encnet_codes,
-            ccnet_recurrence=cfg.model.ccnet_recurrence,
-            guidance_inject=cfg.model.guidance_inject,
-            remat=cfg.model.remat, lm_config=cfg.model.lm_config)
+        policy = precision_policy(cfg.train.precision)
+        model = build_from_config(
+            cfg.model,
+            dtype=policy.compute_dtype if policy else cfg.model.dtype)
         tx, _ = make_optimizer(cfg.optim, 100)  # shapes don't see steps
         state_struct = jax.eval_shape(
             lambda: create_train_state(
-                jax.random.PRNGKey(0), model, tx, **self._init_input()))
-        if cfg.task == "tokens":
-            # ids are a few KB: the input-bytes rule of the image nets
-            # would cost the activations at nothing.  The model knows its
-            # own (block inputs kept, one block live, the logits); the
-            # batch shards over at most every device.
-            per_device = max(1, cfg.data.train_batch // len(jax.devices()))
-            return (state_struct, cfg.data.train_batch * cfg.data.seq_len * 4,
-                    model.activation_bytes(per_device, cfg.data.seq_len))
-        # device-bound train tensors, f32 on device (the uint8 wire
-        # dequantizes inside the step): concat + crop_gt (+void)
-        batch_bytes = cfg.data.train_batch * h * w * (in_ch + 2) * 4
-        return state_struct, batch_bytes
-
-    def _init_input(self) -> dict:
-        """``create_train_state``'s description of the dummy batch the
-        state is initialised on: one NHWC crop, or one sequence of ids."""
-        cfg = self.cfg
-        if cfg.task == "tokens":
-            return {"input_shape": (1, cfg.data.seq_len),
-                    "input_dtype": jnp.int32}
-        h, w = cfg.data.crop_size
-        return {"input_shape": (1, h, w, cfg.model.in_channels)}
-
-    def _token_sets(self) -> tuple:
-        """(train, val) token sources (data/tokens.py): the packed uint32
-        file when ``data.token_file`` names one — its last
-        ``token_val_samples`` windows are the val split — else the seeded
-        synthetic source."""
-        from ..data.tokens import PackedTokens, SyntheticTokens
-
-        cfg = self.cfg
-        # the ids a source may draw are the model's to say; the module is a
-        # description until it is initialised, so building it here is free
-        vocab = build_model(cfg.model.name,
-                            lm_config=cfg.model.lm_config).vocab_size
-        n_val = cfg.data.token_val_samples
-        if cfg.data.token_file:
-            whole = PackedTokens(cfg.data.token_file, cfg.data.seq_len)
-            if len(whole) <= n_val:
-                raise ValueError(
-                    f"{whole} holds {len(whole)} sequences, not more than "
-                    f"data.token_val_samples={n_val}: nothing to train on")
-            return (PackedTokens(cfg.data.token_file, cfg.data.seq_len,
-                                 vocab, count=len(whole) - n_val),
-                    PackedTokens(cfg.data.token_file, cfg.data.seq_len,
-                                 vocab, first=-n_val))
-        return (SyntheticTokens(cfg.data.token_samples, cfg.data.seq_len,
-                                vocab, seed=cfg.seed),
-                SyntheticTokens(n_val, cfg.data.seq_len, vocab,
-                                seed=cfg.seed + 1))
+                jax.random.PRNGKey(0), model, tx,
+                **self.task.init_input(cfg)))
+        return self.task.memory_inputs(cfg, model, state_struct)
 
     def _warm_start(self, path: str, partial: bool) -> None:
         """Import model weights from a torch ``.pth`` state_dict — the
@@ -1283,7 +892,7 @@ class Trainer:
         the attribute writes are published to the dispatch loop by the
         placement future's ``result()`` (completion happens-before the
         first wire batch is yielded)."""
-        batch, spec = pack_wire(batch, DEVICE_KEYS)
+        batch, spec = pack_wire(batch, self.task.device_keys)
         if self._wire_spec is None:
             self._wire_spec = spec
             self._wire_step, self._wire_multi_step = self._build_steps(spec)
@@ -1373,9 +982,12 @@ class Trainer:
         the reason as a RECOMMENDATION naming the config keys — the
         governor logs it instead of acting."""
         cfg = self.cfg
+        if self.task.device_stage is None:
+            return False, (f"task={self.task.name} has no host "
+                           "augmentation to move")
+        moves_guidance = self.task.guidance_on_device(cfg)
         already = cfg.data.device_augment and (
-            cfg.task == "semantic" or cfg.data.device_guidance
-            or cfg.data.guidance == "none")
+            cfg.data.device_guidance or not moves_guidance)
         if already or self._feed_flipped:
             return False, "on-device augmentation + guidance already active"
         if cfg.data.coalesce_wire:
@@ -1396,8 +1008,7 @@ class Trainer:
             return False, (
                 "grain loader builds its pipeline up front — set "
                 "data.device_augment/data.device_guidance in the config")
-        if cfg.task == "instance" and cfg.data.guidance != "none" \
-                and not cfg.data.device_guidance:
+        if moves_guidance and not cfg.data.device_guidance:
             from ..ops.guidance_device import FAMILIES as _DEV_FAM
             if cfg.data.guidance not in _DEV_FAM:
                 return False, (
@@ -1405,37 +1016,12 @@ class Trainer:
                     f"implementation (supported: {_DEV_FAM}) — "
                     "data.prepared_cache is the remaining lever")
         what = "flip augmentation"
-        if cfg.task == "instance" and cfg.data.guidance != "none":
+        if moves_guidance:
             what += " + guidance synthesis"
         return True, (f"move {what} on device "
                       "(data.device_augment=true"
                       + (", data.device_guidance=true"
-                         if cfg.task == "instance"
-                         and cfg.data.guidance != "none" else "") + ")")
-
-    def _build_device_stage(self, device_augment: bool,
-                            device_guidance: bool):
-        """The fused on-device augmentation (+ guidance synthesis) stage
-        for the compiled step, or None when both are off.  The ONE
-        constructor shared by the config path (build time) and the
-        governor's rung-2 flip — a config-enabled run and a
-        governor-flipped run must train through the identical stage."""
-        if not (device_augment or device_guidance):
-            return None
-        cfg = self.cfg
-        from ..ops.augment import make_device_augment
-
-        guidance_fn = None
-        if device_guidance:  # instance task only (validated at build)
-            from ..ops.guidance_device import make_device_guidance
-            guidance_fn = make_device_guidance(
-                family=cfg.data.guidance, alpha=cfg.data.guidance_alpha)
-        return make_device_augment(  # host flip (+geom) disabled
-            hflip=device_augment,
-            scale_rotate=device_augment and cfg.data.device_augment_geom,
-            rots=cfg.data.rots, scales=cfg.data.scales,
-            semantic=cfg.task == "semantic",
-            guidance_fn=guidance_fn)
+                         if moves_guidance else "") + ")")
 
     def _flip_device_path(self) -> None:
         """Apply the rung-2 flip (epoch boundary — the recompile-safe
@@ -1449,21 +1035,10 @@ class Trainer:
         if not ok:
             raise RuntimeError(f"device-path flip not available: {reason}")
         cfg = self.cfg
-        dev_guidance = (cfg.task == "instance"
-                        and cfg.data.guidance != "none")
-        if cfg.task == "instance":
-            new_tf = build_train_transform(
-                crop_size=cfg.data.crop_size, relax=cfg.data.relax,
-                zero_pad=cfg.data.zero_pad, rots=cfg.data.rots,
-                scales=cfg.data.scales, alpha=cfg.data.guidance_alpha,
-                guidance="none" if dev_guidance else cfg.data.guidance,
-                flip=False, geom=not cfg.data.device_augment_geom,
-                fused_crop_resize=cfg.data.fused_crop_resize)
-        else:
-            new_tf = build_semantic_train_transform(
-                crop_size=cfg.data.crop_size, rots=cfg.data.rots,
-                scales=cfg.data.scales, flip=False,
-                geom=not cfg.data.device_augment_geom)
+        dev_guidance = self.task.guidance_on_device(cfg)
+        new_tf = self.task.train_transform(
+            cfg, flip=False, geom=not cfg.data.device_augment_geom,
+            guidance="none" if dev_guidance else cfg.data.guidance)
 
         def set_transform(ds):
             subs = getattr(ds, "datasets", None)
@@ -1474,8 +1049,8 @@ class Trainer:
                 ds.transform = new_tf
 
         set_transform(self.train_set)
-        self._step_kwargs["augment"] = self._build_device_stage(
-            True, dev_guidance)
+        self._step_kwargs["augment"] = self.task.device_stage(
+            cfg, True, dev_guidance)
         self.train_step, self.multi_train_step = self._build_steps()
         # the rebuilt programs' first dispatch is a fresh trace+XLA —
         # re-book it as 'compile', not a mysteriously slow 'step'
@@ -1625,12 +1200,8 @@ class Trainer:
                 idx = start_batch + i
                 if qset and idx in qset:
                     continue
-                if cfg.debug_asserts and not self._token_task:
-                    if cfg.task == "instance":
-                        batch_debug_asserts(
-                            batch, packed_masks=cfg.data.packbits_masks)
-                    else:
-                        semantic_batch_debug_asserts(batch, cfg.model.nclass)
+                if cfg.debug_asserts:
+                    self.task.batch_asserts(batch, cfg)
                 self._epoch_batch_order.append(idx)
                 yield batch
 
@@ -1759,7 +1330,7 @@ class Trainer:
                 size=lambda: max(self._device_prefetch,
                                  cfg.data.steps_per_dispatch),
                 keys=(WIRE_KEY,) if cfg.data.coalesce_wire
-                else (TOKENS_KEY,) if self._token_task else DEVICE_KEYS,
+                else self.task.device_keys,
                 transform=(self._pack_wire_transform
                            if cfg.data.coalesce_wire else None))
             if echo > 1:
@@ -2156,45 +1727,13 @@ class Trainer:
         """The device/host evaluation half of :meth:`validate` — no writer
         or checkpoint side effects, so it is safe to run on the val-overlap
         thread against a snapshot ``state``."""
+        self.val_loader.set_epoch(0)
         # goodput: validation wall-clock books under 'eval' (per-thread
         # stacks keep the val-overlap thread's books separate)
-        with get_accountant().account("eval"):
-            return self._eval_metrics_inner(state, epoch)
-
-    def _eval_metrics_inner(self, state, epoch: int | None = None
-                            ) -> tuple[dict, dict | None]:
-        self.val_loader.set_epoch(0)
-        with self.mesh:
-            if self._token_task:
-                # mean next-token loss over the val sequences (the loader
-                # wrap-pads its last batch: every sequence is scored)
-                losses = [self.eval_step(state, b)[1] for b in
-                          prefetch_to_device(
-                              iter(self.val_loader), self.mesh,
-                              size=self.cfg.data.device_prefetch,
-                              keys=(TOKENS_KEY,))]
-                loss = float(np.mean(jax.device_get(losses)))
-                metrics = {"loss": loss, "perplexity": float(np.exp(loss))}
-            elif self.cfg.task == "semantic":
-                metrics = evaluate_semantic(
-                    self.eval_step, state, self.val_loader,
-                    nclass=self.cfg.model.nclass, mesh=self.mesh,
-                    tta_scales=self.cfg.eval_tta_scales,
-                    tta_flip=self.cfg.eval_tta_flip,
-                    debug_asserts=self.cfg.debug_asserts,
-                    bf16_probs=self.cfg.eval_bf16_probs,
-                    device_fullres=(
-                        tuple(self.cfg.data.val_max_im_size)
-                        if self.cfg.eval_device_fullres else None))
-            else:
-                metrics = evaluate(
-                    self.eval_step, state, self.val_loader,
-                    thresholds=self.cfg.eval_thresholds,
-                    relax=self.cfg.data.relax,
-                    zero_pad=self.cfg.data.zero_pad, mesh=self.mesh,
-                    debug_asserts=self.cfg.debug_asserts,
-                    packed_masks=self._val_packbits,
-                    bf16_readback=self.cfg.eval_bf16_probs)
+        with get_accountant().account("eval"), self.mesh:
+            metrics = self.task.evaluate(
+                self.eval_step, state, self.val_loader, self.cfg, self.mesh,
+                tasks.ValWire(self._val_device_guidance, self._val_packbits))
         first = metrics.pop("_first_batch", None)
         if self.cfg.debug_asserts and not np.isfinite(metrics["loss"]):
             # Watchdog, val side: a 1-step epoch's train loss is computed
@@ -2325,10 +1864,8 @@ class Trainer:
             # entries of epochs it is about to replay (see
             # _handle_divergence) without positional guesswork
             history["val"].append(dict(metrics, epoch=epoch))
-        # best-gating metric, higher is better: threshold-max Jaccard, or
-        # for a token model the negated val loss
-        name, best = ("neg_loss", -metrics["loss"]) if self._token_task \
-            else ("jaccard", metrics["jaccard"])
+        # best-gating metric, higher is better: the task names it
+        name, best = self.task.best(metrics)
         is_best = self.ckpt.save(step, state, metric=best,
                                  extra={"epoch": epoch})
         if is_best and self.is_main:

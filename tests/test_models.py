@@ -353,6 +353,41 @@ class TestFactory:
         with pytest.raises(ValueError):
             build_model("segformer")
 
+    @pytest.mark.parametrize("overrides", [
+        ["model.backbone=resnet18", "model.pam_score_dtype=bfloat16",
+         "model.pam_block_size=7", "model.bn_fp32_stats=false",
+         "model.remat=true", "model.remat_policy=dots_saveable"],
+        ["model.name=deeplabv3", "model.nclass=21", "model.in_channels=3",
+         "model.backbone=resnet50", "model.aux_head=true"],
+        ["model.name=nemotron_h", "model.lm_config=tiny",
+         "model.remat=true"],
+    ], ids=["danet", "deeplabv3_aux", "nemotron_h"])
+    def test_build_from_config_is_the_hand_spelled_call(self, overrides):
+        """``ModelConfig`` -> model is spelled once: the mapping equals the
+        keyword list the trainer carried by hand before it (Flax modules
+        are dataclasses: equality is field equality)."""
+        from distributedpytorch_tpu.models import build_from_config
+        from distributedpytorch_tpu.train import Config, apply_overrides
+
+        m = apply_overrides(Config(), overrides).model
+        by_hand = build_model(
+            name=m.name, nclass=m.nclass, backbone=m.backbone,
+            output_stride=m.output_stride, dtype="bfloat16",
+            bn_fp32_stats=m.bn_fp32_stats, bn_cross_replica_axis="data",
+            pam_block_size=m.pam_block_size,
+            attention_impl=m.attention_impl, pam_impl=m.pam_impl,
+            pam_score_dtype=m.pam_score_dtype, pam_sp_mesh=None,
+            remat=m.remat, remat_policy=m.remat_policy or None,
+            moe_experts=m.moe_experts, moe_hidden=m.moe_hidden,
+            moe_k=m.moe_k, moe_capacity_factor=m.moe_capacity_factor,
+            aux_head=m.aux_head, encnet_codes=m.encnet_codes,
+            ccnet_recurrence=m.ccnet_recurrence,
+            guidance_inject=m.guidance_inject, lm_config=m.lm_config)
+        built = build_from_config(m, dtype="bfloat16",
+                                  bn_cross_replica_axis="data")
+        assert built == by_hand
+        assert built != build_from_config(m, dtype="float32")
+
 
 class TestRemat:
     """model.remat: jax.checkpoint per residual block — must be a pure

@@ -201,6 +201,47 @@ class TestModelFromConfig:
         assert m.pam_score_dtype == jnp.bfloat16
         assert m.pam_block_size == 7
 
+    def test_a_token_run_is_rebuilt_from_its_own_lm_config(self, tmp_path):
+        """``model.lm_config`` reaches the rebuilt model: a JSON file that
+        is not the ``tiny`` preset is not served as ``tiny``."""
+        import json
+
+        from distributedpytorch_tpu.models import build_model
+        from distributedpytorch_tpu.models.nemotron_h import PRESETS
+        from distributedpytorch_tpu.predict import model_from_config
+        from distributedpytorch_tpu.train import Config, apply_overrides
+        path = tmp_path / "wider.json"
+        path.write_text(json.dumps(dict(PRESETS["tiny"], vocab_size=384)))
+        cfg = apply_overrides(Config(), [
+            "task=tokens", "model.name=nemotron_h",
+            f"model.lm_config={path}"])
+        m = model_from_config(cfg)
+        assert m == build_model("nemotron_h", lm_config=str(path),
+                                remat=False)
+        assert m.vocab_size == 384 != PRESETS["tiny"]["vocab_size"]
+
+    def test_bn_stat_dtype_carries_over(self):
+        from distributedpytorch_tpu.predict import model_from_config
+        from distributedpytorch_tpu.train import Config, apply_overrides
+        cfg = apply_overrides(Config(), ["model.backbone=resnet18",
+                                         "model.bn_fp32_stats=false"])
+        assert model_from_config(cfg).bn_fp32_stats is False
+
+    def test_a_config_saved_before_a_field_existed(self):
+        """A stand-in that carries only the old fields gets the missing
+        ones' own defaults; ring PAM is served by the einsum form."""
+        import types
+
+        from distributedpytorch_tpu.predict import model_from_config
+        old = types.SimpleNamespace(model=types.SimpleNamespace(
+            name="danet", nclass=1, backbone="resnet18",
+            output_stride=None, dtype="float32", pam_block_size=None,
+            pam_impl="ring", remat=False, moe_experts=0, moe_hidden=None,
+            moe_k=1, moe_capacity_factor=1.25, aux_head=False))
+        m = model_from_config(old)
+        assert m.pam_impl == "einsum" and m.bn_fp32_stats is True
+        assert m.guidance_inject == "stem"
+
 
 class TestFromTorch:
     def test_roundtrip_matches_native_predictor(self, tmp_path):

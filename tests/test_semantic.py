@@ -488,7 +488,7 @@ class TestBf16ProbsWire:
         # can't prove it: both wire dtypes produce a valid miou)
         import sys
 
-        import distributedpytorch_tpu.train.trainer as trainer_mod
+        import distributedpytorch_tpu.train.tasks as tasks_mod
         # NOT `from ..train import evaluate`: the package re-exports the
         # evaluate FUNCTION under that name, shadowing the module
         eval_mod = sys.modules["distributedpytorch_tpu.train.evaluate"]
@@ -499,7 +499,7 @@ class TestBf16ProbsWire:
             seen["bf16_probs"] = kw.get("bf16_probs")
             return real(*a, **kw)
 
-        monkeypatch.setattr(trainer_mod, "evaluate_semantic", spy)
+        monkeypatch.setattr(tasks_mod, "evaluate_semantic", spy)
         tr = self._trained(tmp_path, ["eval_full_res=true",
                                       "eval_bf16_probs=false"])
         m = tr.validate(log_panels=False)
