@@ -41,9 +41,13 @@ CROP = 512
 
 def _kernel_parity() -> dict:
     """The Mosaic kernels against the XLA einsum forms on this chip, at the
-    flagship head's tile shapes (N=4096 tokens, C=512, bf16)."""
+    flagship head's tile shapes (N=4096 tokens, C=512, bf16), and the token
+    model's causal grouped-query attention at the benchmark cell's (8,192
+    tokens, 4 query heads to 1 key/value head of 128), forward and reverse."""
     from distributedpytorch_tpu.ops import (
+        causal_attention,
         channel_attention,
+        flash_causal_attention,
         flash_channel_attention,
         flash_position_attention,
         position_attention,
@@ -67,6 +71,21 @@ def _kernel_parity() -> dict:
         "cam": rel_err(jax.jit(flash_channel_attention)(x),
                        jax.jit(channel_attention)(x)),
     }
+    tq = jnp.asarray(r.normal(0, 1.0, (1, 8192, 4, 128)), jnp.bfloat16)
+    tk, tv = (jnp.asarray(r.normal(0, 1.0, (1, 8192, 1, 128)), jnp.bfloat16)
+              for _ in range(2))
+
+    def out_and_grads(attend):
+        def loss(*a):
+            return (attend(*a).astype(jnp.float32) ** 2).sum()
+        return (jax.jit(attend)(tq, tk, tv),
+                *jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(tq, tk, tv))
+
+    for name, got, want in zip(
+            ("causal_attn", "causal_attn_dq", "causal_attn_dk",
+             "causal_attn_dv"), out_and_grads(flash_causal_attention),
+            out_and_grads(causal_attention)):
+        errs[name] = rel_err(got, want)
     for name, e in errs.items():
         # bf16 stores round at 2^-9; a wrong tile or transpose is O(1)
         assert e < 3e-2, f"{name} kernel vs einsum: relative error {e:.3g}"

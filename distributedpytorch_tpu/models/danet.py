@@ -58,11 +58,13 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _auto_wants_flash(dtype) -> bool:
+def auto_wants_flash(dtype) -> bool:
     """'auto' promotes the fused Pallas kernels only on TPU and only for
     bf16 compute — see :data:`AUTO_FLASH_MIN_TOKENS`: the f32 crossover
     sweep still favors XLA's einsum, so an f32 run (reference parity,
-    ``train.precision=float32``) keeps the measured-faster form."""
+    ``train.precision=float32``) keeps the measured-faster form.  The one
+    rule for every model's attention kernels (a token model's causal
+    attention asks it too: ``models/nemotron_h.py``)."""
     return _on_tpu() and jnp.dtype(dtype) == jnp.dtype(jnp.bfloat16)
 
 
@@ -105,7 +107,7 @@ class PositionAttentionModule(nn.Module):
             # AUTO_FLASH_MIN_TOKENS.  Backend, dtype and token count
             # are static at trace time: a compile-time choice, one
             # program per shape.
-            if _auto_wants_flash(self.dtype):
+            if auto_wants_flash(self.dtype):
                 impl = "flash"
             else:
                 impl = "einsum" if h * w < AUTO_FLASH_MIN_TOKENS \
@@ -178,7 +180,7 @@ class ChannelAttentionModule(nn.Module):
         b, h, w, c = x.shape
         impl = self.impl
         if impl == "auto":
-            impl = "flash" if _auto_wants_flash(self.dtype) else "einsum"
+            impl = "flash" if auto_wants_flash(self.dtype) else "einsum"
         tokens = x.reshape(b, h * w, c)
         if impl == "flash":
             from ..ops.pallas_attention import flash_channel_attention

@@ -8,7 +8,11 @@ character of ``hybrid_override_pattern``:
   chunk a masked ``C B^T`` product with the decay matrix, between chunks a
   scan over the (heads, head_dim, state) states;
 * ``*``  :class:`Attention` — causal grouped-query attention, rotary
-  embedding over the whole head;
+  embedding over the whole head; in bfloat16 on a TPU (DANet's rule,
+  ``models/danet.py::auto_wants_flash``) as the Mosaic flash kernels of
+  ``ops/pallas_attention.py``, forward and reverse, otherwise the einsum
+  form ``ops/attention.py::causal_attention``; a rematerialised block keeps
+  the kernels' output and log-sum-exp, so it runs the forward call once;
 * ``E``  :class:`LatentMoE` — sigmoid router over ALL published experts,
   top-k with the score-correction bias, experts in a latent space, one shared
   expert; the layer is told which experts it holds and computes their part
@@ -39,8 +43,11 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from ..ops import pallas_attention
+from ..ops.attention import causal_attention
 from ..parallel import moe as moe_lib
 from ..telemetry import counters, scopes
+from . import danet
 
 F32 = jnp.float32
 
@@ -332,16 +339,13 @@ class Attention(nn.Module):
         q = _dot(x, q_proj, self.dtype).reshape(b, length, qh, hd)
         k = _dot(x, k_proj, self.dtype).reshape(b, length, kvh, hd)
         v = _dot(x, v_proj, self.dtype).reshape(b, length, kvh, hd)
-        q = rope(q, c.rope_theta).reshape(b, length, kvh, qh // kvh, hd)
+        q = rope(q, c.rope_theta)
         k = rope(k, c.rope_theta)
-        sc = jnp.einsum("bqgrd,bkgd->bgrqk", q, k,
-                        preferred_element_type=F32) / math.sqrt(hd)
-        pos = jnp.arange(length)
-        sc = jnp.where(pos[:, None] >= pos[None, :], sc, -jnp.inf)
-        w = jax.nn.softmax(sc, axis=-1).astype(self.dtype)
-        out = jnp.einsum("bgrqk,bkgd->bqgrd", w, v,
-                         preferred_element_type=F32)
-        out = out.astype(self.dtype).reshape(b, length, qh * hd)
+        # bfloat16 on a TPU: the Mosaic flash kernels, forward and reverse,
+        # and no (length, length) array reaches HBM; else the einsum form
+        attend = pallas_attention.flash_causal_attention \
+            if danet.auto_wants_flash(self.dtype) else causal_attention
+        out = attend(q, k, v).reshape(b, length, qh * hd)
         return u + _dot(out, o_proj, self.dtype, out=u.dtype)
 
 
@@ -404,6 +408,8 @@ class LatentMoE(nn.Module):
 
 _BLOCKS = {"M": (scopes.MAMBA, MambaMixer), "*": (scopes.ATTN, Attention),
            "E": (scopes.MOE, LatentMoE)}
+_KEEP_FLASH_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
+    *pallas_attention.KEPT_BY_REVERSE)
 
 
 def _run_blocks(module: nn.Module, pattern: str, x, *, remat: bool):
@@ -417,7 +423,10 @@ def _run_blocks(module: nn.Module, pattern: str, x, *, remat: bool):
                              f"{pattern!r} (M | * | E)")
         layer, cls = _BLOCKS[kind]
         if remat:
-            cls = nn.remat(cls)
+            # an attention block keeps what its flash reverse pass reads of
+            # the forward call (where the kernels run; nothing otherwise)
+            cls = nn.remat(cls, policy=_KEEP_FLASH_RESIDUALS
+                           if kind == "*" else None)
         with jax.named_scope(layer):
             x = cls(c, dtype, name=layer_name(i))(x)
     return x
@@ -513,8 +522,16 @@ class NemotronH(nn.Module):
         # an expert layer's wide arrays are one chunk of the row buffer
         rows = moe_lib.chunk_rows_of(moe_lib.dropless_buffer_rows(
             t, c.experts_per_token, c.experts_held))
+        if danet.auto_wants_flash(self.dtype):
+            # the flash kernels: q, out and their gradients, the reverse
+            # pass's float32 dQ and per-query-head dK, dV, then k, v and
+            # theirs; no (seq_len, seq_len) array
+            attn = t * c.head_dim * (c.q_heads * (4 * item + 3 * 4)
+                                     + 4 * c.kv_heads * item)
+        else:  # the einsum form: float32 scores, probabilities, gradients
+            attn = 3 * batch * c.q_heads * seq_len * seq_len * 4
         per_kind = {
-            "*": 3 * batch * c.q_heads * seq_len * seq_len * 4,
+            "*": attn,
             "E": 2 * (rows * (2 * c.latent_size + c.expert_hidden) * item
                       + t * c.shared_hidden * item
                       + t * c.latent_size * 4),

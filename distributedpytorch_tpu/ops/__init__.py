@@ -13,8 +13,10 @@ from .attention import (
     position_attention,
     blocked_position_attention,
     channel_attention,
+    causal_attention,
 )
 from .pallas_attention import (
+    flash_causal_attention,
     flash_channel_attention,
     flash_position_attention,
 )
@@ -40,6 +42,8 @@ __all__ = [
     "position_attention",
     "blocked_position_attention",
     "channel_attention",
+    "causal_attention",
+    "flash_causal_attention",
     "flash_channel_attention",
     "flash_position_attention",
     "sigmoid_balanced_bce",

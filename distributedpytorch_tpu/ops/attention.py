@@ -14,12 +14,17 @@ they are pure jnp functions the flax modules call, designed for the MXU:
   building block the ring/sequence-parallel path reuses (each ring hop feeds
   one key/value block and carries the same running (max, sum, acc) state).
 
+:func:`causal_attention` is a token model's layer (``models/nemotron_h.py``):
+causal, grouped-query, scaled by 1/sqrt(head_dim), heads kept apart.
+
 Layouts: spatial features are (B, N, C) token-major — N = H*W spatial tokens —
 the natural NHWC flattening.  Scores accumulate in float32 regardless of input
 dtype (bf16-safe softmax).
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -128,3 +133,26 @@ def channel_attention(x: jax.Array) -> jax.Array:
     attn = jax.nn.softmax(energy, axis=-1)
     out = jnp.einsum("bij,bnj->bni", attn, xf)
     return out.astype(x.dtype)
+
+
+def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
+    """Causal grouped-query attention, scores scaled by 1/sqrt(head_dim).
+
+    ``q``: (B, S, Hq, D); ``k``, ``v``: (B, S, Hkv, D), ``Hq`` a multiple of
+    ``Hkv``: query heads ``g·r … g·r + r − 1`` read key/value head ``g`` ->
+    (B, S, Hq, D) in ``q``'s dtype.  The float32 (B, Hq, S, S) scores and
+    their probabilities in ``q``'s dtype are whole arrays here: the form that
+    runs off-TPU and in float32; ``ops/pallas_attention.py::
+    flash_causal_attention`` is the same mathematics tile by tile."""
+    b, length, qh, hd = q.shape
+    kvh = k.shape[2]
+    dtype = q.dtype
+    q = q.reshape(b, length, kvh, qh // kvh, hd)
+    sc = jnp.einsum("bqgrd,bkgd->bgrqk", q, k,
+                    preferred_element_type=jnp.float32) / math.sqrt(hd)
+    pos = jnp.arange(length)
+    sc = jnp.where(pos[:, None] >= pos[None, :], sc, -jnp.inf)
+    w = jax.nn.softmax(sc, axis=-1).astype(dtype)
+    out = jnp.einsum("bgrqk,bkgd->bqgrd", w, v,
+                     preferred_element_type=jnp.float32)
+    return out.astype(dtype).reshape(b, length, qh, hd)

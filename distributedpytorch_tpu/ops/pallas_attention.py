@@ -1,4 +1,5 @@
-"""Pallas TPU kernels for BOTH DANet attention branches — the hot path.
+"""Pallas TPU kernels for BOTH DANet attention branches, and for a token
+model's causal grouped-query attention — the hot path.
 
 The reference's dual-attention head materializes its intermediates in
 external CUDA code (PyTorch-Encoding's DANet head, reference
@@ -15,6 +16,18 @@ the off-TPU fallback:
   K innermost; the running (max, sum, accumulator) state lives in VMEM
   scratch across the K sweep (the canonical flash-attention schedule).
   Blocks default 256×256, aligned to the (8,128) f32 tile.
+* :func:`flash_causal_attention` — the same kernels, forward and reverse,
+  as a token model's ``*`` layer asks for them (``models/nemotron_h.py``):
+  ``scale`` 1/√head_dim; ``causal`` — a query sees the keys at or before
+  it, a tile wholly above the diagonal is neither computed (``pl.when``)
+  nor fetched (its block index stays on the last tile that ran), and only
+  a tile that crosses the diagonal pays for the mask; ``group`` — heads lie
+  on the grid's first axis beside the batch, and the query heads of a group
+  read their one key/value head through the index map (nothing is repeated
+  in HBM; the reverse pass writes one float32 dK, dV per query head, summed
+  over the group in XLA).  What differs from DANet's calls is static at
+  trace time; with ``causal=False`` and ``group=1`` the kernels' Mosaic
+  modules are DANet's, op for op.
 * :func:`flash_channel_attention` — the gram branch: one kernel streams
   the (N, C) tokens through VMEM in row blocks, accumulates the C×C
   gram on the MXU in VMEM scratch and finishes with DANet's
@@ -50,9 +63,11 @@ see :func:`_on_local_batch`.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -86,10 +101,14 @@ def _on_local_batch(kernel, *operands):
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *refs,
-                  n_real: int, block_k: int, scale: float | None):
+                  n_real: int, block_k: int, scale: float | None,
+                  causal: bool = False):
     """One (q-block, k-block) tile of online-softmax attention.  ``refs``:
     the running (max, sum, accumulator) scratch, after the log-sum-exp
-    output where the call was built with one (the differentiated forward)."""
+    output where the call was built with one (the differentiated forward).
+    ``causal``: a query sees the keys at or before its own position; a tile
+    wholly above the diagonal is not computed, and only a tile that crosses
+    it pays for the mask."""
     *lse_ref, m_ref, s_ref, acc_ref = refs
     j = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -100,27 +119,47 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *refs,
         s_ref[:] = jnp.zeros_like(s_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0]          # (bq, ck)
-    k = k_ref[0]          # (bk, ck)
-    v = v_ref[0]          # (bk, cv)
-    scores = jax.lax.dot_general(
-        q, k, _NT, preferred_element_type=jnp.float32)   # (bq, bk)
-    if scale is not None:
-        scores = scores * scale
-    # Mask keys past the true token count (N was padded to a block multiple).
-    key_idx = j * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, scores.shape, 1)
-    scores = jnp.where(key_idx < n_real, scores, _NEG_INF)
+    def tile(q_lo=None):
+        q = q_ref[0]          # (bq, ck)
+        k = k_ref[0]          # (bk, ck)
+        v = v_ref[0]          # (bk, cv)
+        scores = jax.lax.dot_general(
+            q, k, _NT, preferred_element_type=jnp.float32)   # (bq, bk)
+        if scale is not None:
+            scores = scores * scale
+        key_idx = j * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, scores.shape, 1)
+        if not causal:
+            # Mask keys past the true token count (N was padded to a block
+            # multiple).
+            scores = jnp.where(key_idx < n_real, scores, _NEG_INF)
+        elif q_lo is not None:
+            # the tile crosses the diagonal.  Padded keys lie past every
+            # real query, so this mask is theirs too
+            query_idx = q_lo + jax.lax.broadcasted_iota(
+                jnp.int32, scores.shape, 0)
+            scores = jnp.where(key_idx <= query_idx, scores, _NEG_INF)
 
-    m_prev = m_ref[:, :1]                            # (bq, 1)
-    m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new)                      # (bq, bk)
-    s_new = s_ref[:, :1] * corr + p.sum(axis=-1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-        p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32)
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    s_ref[:] = jnp.broadcast_to(s_new, s_ref.shape)
+        m_prev = m_ref[:, :1]                            # (bq, 1)
+        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(scores - m_new)                      # (bq, bk)
+        s_new = s_ref[:, :1] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32)
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        s_ref[:] = jnp.broadcast_to(s_new, s_ref.shape)
+
+    if causal:
+        block_q = q_ref.shape[1]
+        q_lo = pl.program_id(1) * block_q
+        k_lo = j * block_k
+        crosses = k_lo + block_k - 1 > q_lo
+        pl.when(jnp.logical_and(k_lo < q_lo + block_q, crosses))(
+            functools.partial(tile, q_lo))
+        pl.when(jnp.logical_not(crosses))(tile)
+    else:
+        tile()
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -139,7 +178,8 @@ def _pad_tokens(x, n_padded: int):
 
 
 def _flash_local(q, k, v, *, block_q: int, block_k: int,
-                 scale: float | None, interpret: bool, with_lse: bool):
+                 scale: float | None, interpret: bool, with_lse: bool,
+                 causal: bool = False, group: int = 1):
     b, n, ck = q.shape
     cv = v.shape[-1]
     nq = pl.cdiv(n, block_q)
@@ -149,7 +189,21 @@ def _flash_local(q, k, v, *, block_q: int, block_k: int,
     v = _pad_tokens(v, nk * block_k)
 
     kernel = functools.partial(_flash_kernel, n_real=n, block_k=block_k,
-                               scale=scale)
+                               scale=scale, causal=causal)
+    # a causal call's tiles (``_CAUSAL_TILE``) pass Mosaic's default scope
+    extra = dict(compiler_params=pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_BWD_VMEM_LIMIT)) if causal else {}
+
+    def kv_map(b_, i, j):
+        if group > 1:  # row ``b_`` is a query head of key/value head:
+            b_ = b_ // group
+        if causal:
+            # past the query block's last tile the index stays where it is,
+            # so a tile that is stepped over starts no copy
+            j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+        return (b_, j, 0)
+
     out_specs = [pl.BlockSpec((1, block_q, cv), lambda b_, i, j: (b_, i, 0))]
     out_shape = [jax.ShapeDtypeStruct((b, nq * block_q, cv), v.dtype)]
     if with_lse:
@@ -164,8 +218,8 @@ def _flash_local(q, k, v, *, block_q: int, block_k: int,
         grid=(b, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, ck), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_k, ck), lambda b_, i, j: (b_, j, 0)),
-            pl.BlockSpec((1, block_k, cv), lambda b_, i, j: (b_, j, 0)),
+            pl.BlockSpec((1, block_k, ck), kv_map),
+            pl.BlockSpec((1, block_k, cv), kv_map),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -178,7 +232,8 @@ def _flash_local(q, k, v, *, block_q: int, block_k: int,
         # the call's name is its innermost scope, and the TPU compiler names
         # the custom-call after that: the trace shows ``%pam`` whatever
         # encloses the call (a module, a shard_map)
-        name=scopes.PAM_KERNEL,
+        name=scopes.CAUSAL_ATTN if causal else scopes.PAM_KERNEL,
+        **extra,
     )(q, k, v)
     out = res[0][:, :n, :]
     if with_lse:
@@ -188,11 +243,13 @@ def _flash_local(q, k, v, *, block_q: int, block_k: int,
 
 def _flash_forward(q, k, v, block_q: int, block_k: int,
                    scale: float | None, interpret: bool,
-                   with_lse: bool = False):
+                   with_lse: bool = False, causal: bool = False,
+                   group: int = 1):
     return _on_local_batch(
         functools.partial(_flash_local, block_q=block_q, block_k=block_k,
                           scale=scale, interpret=interpret,
-                          with_lse=with_lse), q, k, v)
+                          with_lse=with_lse, causal=causal, group=group),
+        q, k, v)
 
 
 # ------------------------------------------------- position reverse pass
@@ -220,17 +277,24 @@ def _bwd_plan(n: int, ck: int) -> tuple[int, bool]:
 
 
 def _bwd_tile(q, k, v, do, lse, delta, *, key_block, n_real: int,
-              scale: float | None):
+              scale: float | None, diagonal: bool | None = None):
     """``(Pᵀ, dSᵀ)`` of one tile, keys on sublanes and queries on lanes —
     the orientation in which ``lse`` and ``delta`` (per query) are lane-
-    dense rows and dV, dK need no transpose.  float32 throughout."""
+    dense rows and dV, dK need no transpose.  float32 throughout.
+    ``diagonal``: of a causal call, whether the tile's key block is its
+    query block, where a key counts for the queries at or after it."""
     st = jax.lax.dot_general(k, q, _NT,
                              preferred_element_type=jnp.float32)  # (bk, bq)
     if scale is not None:
         st = st * scale
     pt = jnp.exp(st - lse)
     block = k.shape[0]
-    if n_real % block:  # keys past the true token count (zero-padded)
+    if diagonal:  # padded keys lie past every real query: masked here too
+        pt = jnp.where(
+            jax.lax.broadcasted_iota(jnp.int32, pt.shape, 0)
+            <= jax.lax.broadcasted_iota(jnp.int32, pt.shape, 1), pt, 0.0)
+    elif diagonal is None and n_real % block:
+        # keys past the true token count (zero-padded)
         key_idx = key_block * block + jax.lax.broadcasted_iota(
             jnp.int32, pt.shape, 0)
         pt = jnp.where(key_idx < n_real, pt, 0.0)
@@ -242,9 +306,16 @@ def _bwd_tile(q, k, v, do, lse, delta, *, key_block, n_real: int,
     return pt, dst
 
 
+def _causal_tiles(tile, key_block, query_block):
+    """Run ``tile(diagonal)`` where a causal call has work: below the
+    diagonal as it is, on it masked, above it not at all."""
+    pl.when(query_block > key_block)(functools.partial(tile, False))
+    pl.when(query_block == key_block)(functools.partial(tile, True))
+
+
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *refs, n_real: int,
-                    scale: float | None):
+                    scale: float | None, causal: bool = False):
     """The key-block sweep, queries innermost: dK and dV of the block
     accumulate in float32 scratch.  Built with a dQ output (the fused
     schedule) it also adds the tile's ``dS·k`` into the image's dQ, which
@@ -257,27 +328,36 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    q, k, do = q_ref[0], k_ref[0], do_ref[0]
-    pt, dst = _bwd_tile(q, k, v_ref[0], do, lse_ref[0], delta_ref[0],
-                        key_block=j, n_real=n_real, scale=scale)
-    dst = dst.astype(q.dtype)
-    dv_acc[:] += jax.lax.dot_general(pt.astype(do.dtype), do, _NN,
+    def tile(diagonal=None):
+        q, k, do = q_ref[0], k_ref[0], do_ref[0]
+        pt, dst = _bwd_tile(q, k, v_ref[0], do, lse_ref[0], delta_ref[0],
+                            key_block=j, n_real=n_real, scale=scale,
+                            diagonal=diagonal)
+        dst = dst.astype(q.dtype)
+        dv_acc[:] += jax.lax.dot_general(pt.astype(do.dtype), do, _NN,
+                                         preferred_element_type=jnp.float32)
+        dk_acc[:] += jax.lax.dot_general(dst, q, _NN,
+                                         preferred_element_type=jnp.float32)
+        if dq_ref:
+            dq = jax.lax.dot_general(dst, k, _TN,
                                      preferred_element_type=jnp.float32)
-    dk_acc[:] += jax.lax.dot_general(dst, q, _NN,
-                                     preferred_element_type=jnp.float32)
-    if dq_ref:
-        dq = jax.lax.dot_general(dst, k, _TN,
-                                 preferred_element_type=jnp.float32)
-        block = q.shape[0]
-        rows = pl.ds(pl.multiple_of(i * block, block), block)
+            block = q.shape[0]
+            rows = pl.ds(pl.multiple_of(i * block, block), block)
 
-        @pl.when(j == 0)
-        def _first():
-            dq_ref[0][0, rows, :] = dq
+            # causal: key block 0 counts for every query block, so each
+            # row of dQ is still written before it is added to
+            @pl.when(j == 0)
+            def _first():
+                dq_ref[0][0, rows, :] = dq
 
-        @pl.when(j > 0)
-        def _add():
-            dq_ref[0][0, rows, :] += dq
+            @pl.when(j > 0)
+            def _add():
+                dq_ref[0][0, rows, :] += dq
+
+    if causal:
+        _causal_tiles(tile, j, i)
+    else:
+        tile()
 
     @pl.when(i == pl.num_programs(2) - 1)
     def _finalize():
@@ -286,7 +366,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_acc, *, n_real: int, scale: float | None):
+                   dq_ref, dq_acc, *, n_real: int, scale: float | None,
+                   causal: bool = False):
     """The query-block sweep of the two-sweep schedule, keys innermost."""
     j = pl.program_id(2)
 
@@ -294,12 +375,18 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    k = k_ref[0]
-    _, dst = _bwd_tile(q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0],
-                       delta_ref[0], key_block=j, n_real=n_real,
-                       scale=scale)
-    dq_acc[:] += jax.lax.dot_general(dst.astype(k.dtype), k, _TN,
-                                     preferred_element_type=jnp.float32)
+    def tile(diagonal=None):
+        k = k_ref[0]
+        _, dst = _bwd_tile(q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0],
+                           delta_ref[0], key_block=j, n_real=n_real,
+                           scale=scale, diagonal=diagonal)
+        dq_acc[:] += jax.lax.dot_general(dst.astype(k.dtype), k, _TN,
+                                         preferred_element_type=jnp.float32)
+
+    if causal:
+        _causal_tiles(tile, j, pl.program_id(1))
+    else:
+        tile()
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
@@ -307,7 +394,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_backward_local(q, k, v, out, lse, do, *, scale: float | None,
-                          interpret: bool):
+                          interpret: bool, causal: bool = False,
+                          group: int = 1):
     """dq, dk, dv of flash position attention from the saved output and
     log-sum-exp.  Per tile: ``S = q·kᵀ`` (× ``scale``), ``P = exp(S − lse)``
     with padded keys at 0, ``dP = dO·vᵀ``, ``dS = P ∘ (dP − δ)`` with ``δ =
@@ -319,7 +407,14 @@ def _flash_backward_local(q, k, v, out, lse, do, *, scale: float | None,
     whole float32 dQ of an image is the call's resident output block, so S
     and dP are computed once.  *Two sweeps* (``pam_bwd_dkv`` without the dQ
     output, then ``pam_bwd_dq`` on grid ``(batch, q_blocks, k_blocks)``):
-    O(block) VMEM at any N, at the price of computing S and dP twice."""
+    O(block) VMEM at any N, at the price of computing S and dP twice.
+
+    ``causal`` (the calls are then named ``causal_attn_bwd_…``): tiles above
+    the diagonal are stepped over in both schedules, and the inner axis'
+    block index stays on the diagonal's while they are, so they copy
+    nothing.  ``group`` > 1: a row of ``q`` is a query head and ``group`` of
+    them read one row of ``k``, ``v``; each writes its own float32 dK, dV,
+    summed over the group here."""
     b, n, ck = q.shape
     cv = v.shape[-1]
     block, fused = _bwd_plan(n, ck)
@@ -332,26 +427,46 @@ def _flash_backward_local(q, k, v, out, lse, do, *, scale: float | None,
     lse, delta = (_pad_tokens(x, nb * block)[:, None, :]
                   for x in (lse, delta))
 
-    static = dict(n_real=n, scale=scale)
+    static = dict(n_real=n, scale=scale, causal=causal)
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         vmem_limit_bytes=_BWD_VMEM_LIMIT)
+    names = (scopes.CAUSAL_ATTN_BWD_FUSED, scopes.CAUSAL_ATTN_BWD_DKV,
+             scopes.CAUSAL_ATTN_BWD_DQ) if causal else (
+        scopes.PAM_BWD_FUSED, scopes.PAM_BWD_DKV, scopes.PAM_BWD_DQ)
 
     def specs(at_q, at_k):
         """In-specs of (q, k, v, dO, lse, δ); ``at_q`` / ``at_k``: which
         grid axis walks the query blocks / the key blocks."""
-        def tokens(axis, c):
+        def block_at(axis):
+            if not causal or axis != 2:
+                return lambda g: g[axis]
+            # the inner axis does not leave the tiles that run: the queries'
+            # index not below the key block's, the keys' not above the
+            # query block's
+            bound = jnp.maximum if axis == at_q else jnp.minimum
+            return lambda g: bound(g[2], g[1])
+        qi, ki = block_at(at_q), block_at(at_k)
+        kv_row = (lambda g: g[0] // group) if group > 1 else (lambda g: g[0])
+
+        def tokens(c, row, at):
             return pl.BlockSpec((1, block, c),
-                                lambda *g: (g[0], g[axis], 0))
-        row = pl.BlockSpec((1, 1, block), lambda *g: (g[0], 0, g[at_q]))
-        return [tokens(at_q, ck), tokens(at_k, ck), tokens(at_k, cv),
-                tokens(at_q, cv), row, row]
+                                lambda *g: (row(g), at(g), 0))
+        q_row = lambda g: g[0]
+        row = pl.BlockSpec((1, 1, block), lambda *g: (g[0], 0, qi(g)))
+        return [tokens(ck, q_row, qi), tokens(ck, kv_row, ki),
+                tokens(cv, kv_row, ki), tokens(cv, q_row, qi), row, row]
 
     # the key-block sweep: grid (batch, k_blocks, q_blocks)
     dkv_specs = specs(2, 1)
     out_specs = [dkv_specs[1], dkv_specs[2]]
     out_shape = [jax.ShapeDtypeStruct(k.shape, k.dtype),
                  jax.ShapeDtypeStruct(v.shape, v.dtype)]
+    if group > 1:  # one dK, dV per query head
+        out_specs = [pl.BlockSpec((1, block, c), lambda b_, j, i: (b_, j, 0))
+                     for c in (ck, cv)]
+        out_shape = [jax.ShapeDtypeStruct((b,) + x.shape[1:], jnp.float32)
+                     for x in (k, v)]
     if fused:
         out_specs.append(
             pl.BlockSpec((1,) + q.shape[1:], lambda b_, j, i: (b_, 0, 0)))
@@ -366,9 +481,12 @@ def _flash_backward_local(q, k, v, out, lse, do, *, scale: float | None,
                         pltpu.VMEM((block, cv), jnp.float32)],
         compiler_params=params,
         interpret=interpret,
-        name=scopes.PAM_BWD_FUSED if fused else scopes.PAM_BWD_DKV,
+        name=names[0] if fused else names[1],
     )(q, k, v, do, lse, delta)
     dk, dv = res[0], res[1]
+    if group > 1:
+        dk, dv = (x.reshape((b // group, group) + x.shape[1:]).sum(1)
+                  .astype(like.dtype) for x, like in ((dk, k), (dv, v)))
     if fused:
         dq = res[2].astype(q.dtype)
     else:
@@ -382,41 +500,91 @@ def _flash_backward_local(q, k, v, out, lse, do, *, scale: float | None,
             scratch_shapes=[pltpu.VMEM((block, ck), jnp.float32)],
             compiler_params=params,
             interpret=interpret,
-            name=scopes.PAM_BWD_DQ,
+            name=names[2],
         )(q, k, v, do, lse, delta)
     return dq[:, :n], dk[:, :n], dv[:, :n]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_position_attention(q, k, v, block_q: int = 256, block_k: int = 256,
                              scale: float | None = None,
-                             interpret: bool = False):
+                             interpret: bool = False, causal: bool = False,
+                             group: int = 1):
     """Flash position attention: same math as
     :func:`ops.attention.position_attention` (unscaled DANet energies unless
     ``scale``), O(N·block) memory, MXU-scheduled.  ``block_q`` / ``block_k``
     tile the forward; the reverse pass sizes its own tiles from the shapes.
 
-    ``q``/``k``: (B, N, Ck); ``v``: (B, N, Cv) -> (B, N, Cv).
+    ``q``/``k``: (B, N, Ck); ``v``: (B, N, Cv) -> (B, N, Cv).  ``causal``:
+    row ``t`` attends to rows ``<= t``.  ``group``: ``q`` has ``group``
+    times the rows of ``k`` and ``v``, and rows ``g·group … g·group + group
+    − 1`` of it read row ``g`` of theirs (grouped-query heads laid on the
+    batch axis: :func:`flash_causal_attention`).
     """
-    return _flash_forward(q, k, v, block_q, block_k, scale, interpret)
+    return _flash_forward(q, k, v, block_q, block_k, scale, interpret,
+                          causal=causal, group=group)
 
 
-def _fwd(q, k, v, block_q, block_k, scale, interpret):
+#: ``checkpoint_name``s of what the causal reverse pass keeps of its forward
+#: call: a block that recomputes itself under a policy that saves these
+#: names (8 MB + 131 KB at the token cell's shape) runs no second forward
+#: call.  A policy that names nothing (``nn.remat``'s default) is unmoved
+KEPT_BY_REVERSE = ("causal_attn_out", "causal_attn_lse")
+
+
+def _fwd(q, k, v, block_q, block_k, scale, interpret, causal, group):
     out, lse = _flash_forward(q, k, v, block_q, block_k, scale, interpret,
-                              with_lse=True)
+                              with_lse=True, causal=causal, group=group)
+    if causal:
+        out, lse = map(checkpoint_name, (out, lse), KEPT_BY_REVERSE)
     return out, (q, k, v, out, lse)
 
 
-def _bwd(block_q, block_k, scale, interpret, res, g):
+def _bwd(block_q, block_k, scale, interpret, causal, group, res, g):
     # the flash backward as Mosaic calls: no recompute of the forward's
     # recurrence, no N×N array in HBM (see _flash_backward_local)
-    with jax.named_scope(scopes.PAM_BWD):
+    with jax.named_scope(scopes.CAUSAL_ATTN_BWD if causal
+                         else scopes.PAM_BWD):
         return _on_local_batch(
             functools.partial(_flash_backward_local, scale=scale,
-                              interpret=interpret), *res, g)
+                              interpret=interpret, causal=causal,
+                              group=group), *res, g)
 
 
 flash_position_attention.defvjp(_fwd, _bwd)
+
+
+#: (queries, keys) of a causal forward tile.  On the v5e at 8,192 tokens, 4
+#: query heads of 128 (PERF.md, PR 34): 256 / 512 / 1,024 a side take 2.65 /
+#: 1.29 / 0.68 ms, the last 57% of the MXU's peak for the tiles it runs
+_CAUSAL_TILE = (1024, 1024)
+
+
+def flash_causal_attention(q, k, v, interpret: bool = False):
+    """:func:`ops.attention.causal_attention` through the flash kernels,
+    forward and reverse: no (S, S) array of scores, probabilities or their
+    gradients reaches HBM, and the half of the tiles above the diagonal is
+    neither computed nor fetched.  Softmax statistics in float32, MXU
+    operands in the inputs' dtype.
+
+    ``q``: (B, S, Hq, D); ``k``, ``v``: (B, S, Hkv, D) with ``Hq`` a
+    multiple of ``Hkv``: query heads ``g·r … g·r + r − 1`` read key/value
+    head ``g`` through the kernels' index maps, nothing is repeated in HBM.
+    Returns (B, S, Hq, D)."""
+    b, s, hq, d = q.shape
+    hkv = k.shape[2]
+    if hq % hkv:
+        raise ValueError(f"{hq} query heads do not share {hkv} key/value "
+                         f"heads evenly")
+
+    def heads_first(x):   # heads beside the batch on the grid's first axis
+        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], s, d)
+
+    block_q, block_k = (min(t, 128 * pl.cdiv(s, 128)) for t in _CAUSAL_TILE)
+    out = flash_position_attention(
+        heads_first(q), heads_first(k), heads_first(v), block_q, block_k,
+        1 / math.sqrt(d), interpret=interpret, causal=True, group=hq // hkv)
+    return out.reshape(b, hq, s, d).transpose(0, 2, 1, 3)
 
 
 # ---------------------------------------------------- channel (gram) branch

@@ -45,6 +45,16 @@ PAM_BWD = "pam_bwd"
 PAM_BWD_FUSED = "pam_bwd_fused"
 PAM_BWD_DKV = "pam_bwd_dkv"
 PAM_BWD_DQ = "pam_bwd_dq"
+#: causal grouped-query attention's Mosaic calls (a token model's ``*``
+#: layers).  Never ``pam…``: the DANet cells' roofline patterns hold on to
+#: that.  No entry in :data:`KERNEL_SCOPE_LAYER` either: the trunk's layer
+#: and the prediction module's run the same calls, and each call's layer is
+#: the block that its ``op_name`` passes through
+CAUSAL_ATTN = "causal_attn"
+CAUSAL_ATTN_BWD = "causal_attn_bwd"
+CAUSAL_ATTN_BWD_FUSED = "causal_attn_bwd_fused"
+CAUSAL_ATTN_BWD_DKV = "causal_attn_bwd_dkv"
+CAUSAL_ATTN_BWD_DQ = "causal_attn_bwd_dq"
 CAM_BWD = "cam_bwd"
 CAM_ENERGY = "cam_energy"
 CAM_APPLY = "cam_apply"

@@ -231,6 +231,53 @@ def test_model_logits_loss_and_gradients_equal_the_reference(whole,
     assert float(jnp.abs(got_grads["l01"]["router_bias"]).max()) == 0.0
 
 
+@pytest.fixture()
+def forced_kernels(monkeypatch, interpreted_kernels):
+    """The causal flash kernels in the einsum form's place whatever the
+    backend and the compute dtype (the rule, ``danet.auto_wants_flash``,
+    wants a TPU and bfloat16), through the pallas interpreter."""
+    from distributedpytorch_tpu.models import danet
+
+    monkeypatch.setattr(danet, "auto_wants_flash", lambda dtype: True)
+
+
+def test_flash_kernels_give_the_einsum_models_logits_loss_and_gradients(
+        whole, share_cfg, monkeypatch, request):
+    """The ``tiny`` model (trunk ``*`` and the prediction module's) with the
+    kernels forced against itself in the einsum form, float32: the same
+    sums tile by tile, within the file's tolerances."""
+    from distributedpytorch_tpu.ops import pallas_attention
+    from distributedpytorch_tpu.parallel.step import _loss_and_updates
+
+    model, params, tokens = whole
+
+    def logits_loss_grads():
+        logits = model.apply({"params": params}, tokens, train=True,
+                             mutable=["counters"])[0]
+
+        def loss(p):
+            return _loss_and_updates(
+                model, p, {}, {"tokens": tokens}, jax.random.PRNGKey(0),
+                (1.0, share_cfg["mtp_loss_weight"]), True, NEXT_TOKEN)[0]
+
+        return logits, *jax.value_and_grad(loss)(params)
+
+    want = logits_loss_grads()
+    calls = []
+    flash = pallas_attention.flash_causal_attention
+    monkeypatch.setattr(
+        pallas_attention, "flash_causal_attention",
+        lambda *a, **kw: calls.append(a[0].shape) or flash(*a, **kw))
+    request.getfixturevalue("forced_kernels")
+    got = logits_loss_grads()
+    # both attention layers, forward and under value_and_grad
+    assert len(calls) >= 4 and set(calls) == {(2, 21, 4, 16)}
+    for g, w in zip(got[0], want[0]):
+        assert rel_gap(g, w) <= OUT_RTOL
+    assert abs(float(got[1]) - float(want[1])) <= 1e-5 * float(want[1])
+    assert_trees_close(got[2], want[2], GRAD_RTOL)
+
+
 def test_reference_blocks_change_memory_not_arithmetic(share_cfg,
                                                        monkeypatch):
     """The reference's time, query and loss blocks (what makes 8,192
@@ -411,6 +458,46 @@ def test_scope_table_holds_every_new_layer(token_step):
                    or f"({part})" in n for n in in_loop), part
 
 
+def test_causal_attention_scopes_stay_in_their_block(whole, share_cfg,
+                                                      forced_kernels):
+    """The step with the kernels forced (interpreter: each call is a loop of
+    plain ops that carry its name stack): everything the forward call and
+    the reverse pass leave in the scope table resolves to ``attn`` for the trunk layer and to ``mtp`` for the
+    prediction module's, forward and reverse, none to ``other``."""
+    model, _, tokens = whole
+    tx = optax.sgd(1e-2, momentum=0.9)
+    state = jax.eval_shape(lambda: create_train_state(
+        jax.random.PRNGKey(11), model, tx, tokens.shape,
+        input_dtype=jnp.int32))
+    step = make_train_step(
+        model, tx, loss_type=NEXT_TOKEN, donate=False,
+        loss_weights=(1.0, share_cfg["mtp_loss_weight"]))
+    text = step.lower(state, {"tokens": tokens}).compile().as_text()
+    table = scopes.scope_table(text)
+    named = {i.name: i.op_name or "" for instrs in
+             scopes.parse_hlo(text).values() for i in instrs}
+    mine = {k: s for k, s in table.items()
+            if scopes.CAUSAL_ATTN in named.get(k, "")
+            and ";" not in named[k]}
+    seen = set()
+    for k, s in mine.items():
+        parts = s.path.split("/")
+        layer = scopes.MTP if "/mtp/" in named[k] else scopes.ATTN
+        assert s.layer == layer, (k, s, named[k])
+        assert parts[:2] == ([scopes.MTP, scopes.ATTN] if layer == scopes.MTP
+                             else [scopes.ATTN, "l00"]), (k, s)
+        kernel = next(p for p in parts if p.startswith(scopes.CAUSAL_ATTN))
+        seen.add((layer, kernel, s.phase))
+    for layer in (scopes.ATTN, scopes.MTP):
+        assert {(layer, scopes.CAUSAL_ATTN, "fwd"),
+                (layer, scopes.CAUSAL_ATTN_BWD, "bwd")} <= seen, seen
+        # the block keeps the call's output and log-sum-exp across its
+        # recomputation (``_KEEP_FLASH_RESIDUALS``): no second forward call
+        assert (layer, scopes.CAUSAL_ATTN, "bwd") not in seen
+    assert not any(s.phase == "fwd" and scopes.CAUSAL_ATTN_BWD in s.path
+                   for s in mine.values())
+
+
 @pytest.mark.parametrize("op_name,want", [
     ("jit(step_fn)/jvp(NemotronH)/mamba/l02/ssd_scan/mul",
      ("mamba", "mamba/l02/ssd_scan", "fwd")),
@@ -443,6 +530,29 @@ def test_scope_table_holds_every_new_layer(token_step):
     ("jit(step_fn)/transpose(jvp(NemotronH))/mtp/moe/jvp(NemotronH)/mtp/moe/"
      "checkpoint/l01/while/body/dispatch/scatter-add",
      ("mtp", "mtp/moe/l01/dispatch", "bwd")),
+    # the causal attention kernels (the executable's own op_names, compiled
+    # for a described v5e): the forward call, a recomputed forward call
+    # (a block rematerialised under a policy that does not keep the call's
+    # results) and the custom-VJP reverse pass keep the block's prefix, so
+    # the trunk layer's calls are ``attn`` and the prediction module's
+    # ``mtp``
+    ("jit(step_fn)/jvp(NemotronH)/attn/l00/causal_attn/pallas_call",
+     ("attn", "attn/l00/causal_attn", "fwd")),
+    ("jit(step_fn)/jvp(NemotronH)/mtp/attn/l00/shard_map/causal_attn/"
+     "pallas_call", ("mtp", "mtp/attn/l00/causal_attn", "fwd")),
+    ("jit(step_fn)/transpose(jvp(NemotronH))/attn/jvp(NemotronH)/attn/"
+     "checkpoint/rematted_computation/l00/causal_attn/pallas_call",
+     ("attn", "attn/l00/causal_attn", "bwd")),
+    ("jit(step_fn)/transpose(jvp(NemotronH))/attn/jvp(NemotronH)/attn/"
+     "checkpoint/l00/causal_attn_bwd/causal_attn_bwd_fused/pallas_call",
+     ("attn", "attn/l00/causal_attn_bwd/causal_attn_bwd_fused", "bwd")),
+    ("jit(step_fn)/transpose(jvp(NemotronH))/mtp/attn/jvp(NemotronH)/mtp/"
+     "attn/checkpoint/l00/causal_attn_bwd/shard_map/causal_attn_bwd_dq/"
+     "pallas_call",
+     ("mtp", "mtp/attn/l00/causal_attn_bwd/causal_attn_bwd_dq", "bwd")),
+    ("jit(step_fn)/transpose(jvp(NemotronH))/mtp/attn/jvp(NemotronH)/mtp/"
+     "attn/checkpoint/l00/causal_attn_bwd/reduce_sum",
+     ("mtp", "mtp/attn/l00/causal_attn_bwd", "bwd")),
 ])
 def test_token_op_names_resolve_to_their_layer(op_name, want):
     s = scopes.scope_of(op_name)
@@ -566,6 +676,25 @@ def test_auto_plan_costs_a_token_model_by_its_own_activations(tmp_path):
 
 
 # ------------------------------------------------- the benchmark's config
+def test_activation_bytes_follow_the_attention_form_that_runs(monkeypatch):
+    """The planner's memory model charges the einsum form its three float32
+    (q_heads, 8192, 8192) arrays and the flash kernels none of them."""
+    from distributedpytorch_tpu.models import danet
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           "nemotron3_super_stage_tp8_ep64.json")) as f:
+        cfg = json.load(f)
+    model = nh.build_nemotron_h(cfg, dtype=jnp.bfloat16)
+    float32 = nh.build_nemotron_h(cfg, dtype=jnp.float32)
+    scores = 3 * 4 * 8192 * 8192 * 4
+    einsum = model.activation_bytes(1, 8192)
+    einsum32 = float32.activation_bytes(1, 8192)
+    monkeypatch.setattr(danet, "_on_tpu", lambda: True)
+    assert einsum - model.activation_bytes(1, 8192) > 0.7 * scores
+    # float32 keeps the einsum form on a TPU too
+    assert float32.activation_bytes(1, 8192) == einsum32
+
+
 def test_benchmark_configuration_keeps_every_published_width():
     with open(os.path.join(REPO, "benchmarks", "configs",
                            "nemotron3_super_stage_tp8_ep64.json")) as f:

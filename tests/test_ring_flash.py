@@ -16,6 +16,7 @@ import pytest
 from distributedpytorch_tpu.models import DANet, build_model
 from distributedpytorch_tpu.ops import (
     blocked_position_attention,
+    causal_attention,
     channel_attention,
     pallas_attention,
     position_attention,
@@ -26,6 +27,8 @@ flash_position_attention = functools.partial(
     pallas_attention.flash_position_attention, interpret=True)
 flash_channel_attention = functools.partial(
     pallas_attention.flash_channel_attention, interpret=True)
+flash_causal_attention = functools.partial(
+    pallas_attention.flash_causal_attention, interpret=True)
 
 
 from conftest import assert_grads_close as _assert_grads_close
@@ -294,6 +297,110 @@ class TestFlashBackwardKernels:
         assert all(g.sharding.spec == P("data") for g in got)
         # the same tiles on other batch shapes: float32 reassociation only
         _assert_grads_close(one, got)
+
+
+def heads(s=64, q_heads=4, kv_heads=1, hd=16, b=2, seed=0):
+    r = np.random.RandomState(seed)
+    return (jnp.asarray(r.randn(b, s, q_heads, hd).astype(np.float32)),
+            jnp.asarray(r.randn(b, s, kv_heads, hd).astype(np.float32)),
+            jnp.asarray(r.randn(b, s, kv_heads, hd).astype(np.float32)))
+
+
+class TestCausalFlashKernels:
+    """Causal grouped-query attention through the same kernels (the token
+    model's ``*`` layer): forward and ``jax.grad`` against the einsum form,
+    ``ops.attention.causal_attention``, in the pallas interpreter."""
+
+    @pytest.fixture()
+    def tiles(self, monkeypatch, request):
+        """Tiles of 128 a side, forward and reverse, in the named reverse
+        schedule: 64 tokens are one padded tile, 256 are 2 x 2 tiles, 300
+        are 3 x 3 with 84 padded keys and queries in the last."""
+        schedule = request.param
+        monkeypatch.setattr(pallas_attention, "_CAUSAL_TILE", (128, 128))
+        monkeypatch.setattr(pallas_attention, "_bwd_plan",
+                            lambda n, ck: (128, schedule == "fused"))
+        return schedule
+
+    @pytest.mark.parametrize("tiles", ["fused", "two_sweeps"], indirect=True)
+    @pytest.mark.parametrize("s", [64, 256, 300])
+    @pytest.mark.parametrize("q_heads,kv_heads", [(2, 2), (4, 1)])
+    def test_forward_and_grads_match_the_einsum_form(self, tiles, s, q_heads,
+                                                     kv_heads):
+        q, k, v = heads(s, q_heads, kv_heads, seed=21)
+        np.testing.assert_allclose(flash_causal_attention(q, k, v),
+                                   causal_attention(q, k, v),
+                                   rtol=2e-5, atol=2e-6)
+        _assert_grads_close(_GRAD(_sq_loss(causal_attention))(q, k, v),
+                            _GRAD(_sq_loss(flash_causal_attention))(q, k, v))
+
+    @pytest.mark.parametrize("tiles", ["fused", "two_sweeps"], indirect=True)
+    @pytest.mark.parametrize("q_heads,kv_heads", [(2, 2), (4, 1)])
+    def test_bfloat16_inputs(self, tiles, q_heads, kv_heads):
+        """As PAM's bfloat16 case: kernel and einsum form round P and dS to
+        bfloat16 at different places, each within a few 2^-8 of the float32
+        result, so within 4 * 2^-8 of its norm of each other."""
+        q, k, v = (x.astype(jnp.bfloat16)
+                   for x in heads(300, q_heads, kv_heads, seed=22))
+        exact = (causal_attention(*(x.astype(jnp.float32)
+                                    for x in (q, k, v))),
+                 *_GRAD(_sq_loss(causal_attention))(
+                     *(x.astype(jnp.float32) for x in (q, k, v))))
+        got = (flash_causal_attention(q, k, v),
+               *_GRAD(_sq_loss(flash_causal_attention))(q, k, v))
+        ref = (causal_attention(q, k, v),
+               *_GRAD(_sq_loss(causal_attention))(q, k, v))
+        for g, r, want in zip(got, ref, exact):
+            assert g.dtype == jnp.bfloat16 and g.shape == want.shape
+            norm = float(jnp.linalg.norm(want))
+            assert float(jnp.linalg.norm(
+                g.astype(jnp.float32) - want)) <= 2 * 2.0 ** -8 * norm
+            assert float(jnp.linalg.norm(
+                g.astype(jnp.float32) - r.astype(jnp.float32))
+            ) <= 4 * 2.0 ** -8 * norm
+
+    @pytest.mark.parametrize("tiles", ["fused", "two_sweeps"], indirect=True)
+    def test_calls_are_named_and_read_one_key_value_head(self, tiles):
+        q, k, v = heads(300, 4, 1)
+        jaxpr = jax.make_jaxpr(_GRAD(_sq_loss(flash_causal_attention)))(
+            q, k, v)
+        calls = _pallas_calls(jaxpr.jaxpr)
+        assert [e.params["name"] for e in calls] == {
+            "fused": ["causal_attn", "causal_attn_bwd_fused"],
+            "two_sweeps": ["causal_attn", "causal_attn_bwd_dkv",
+                           "causal_attn_bwd_dq"]}[tiles]
+        for e in calls:  # q by query head, k and v by key/value head
+            rows = [x.aval.shape[0] for x in e.invars[:3]]
+            assert rows == [2 * 4, 2 * 1, 2 * 1], (e.params["name"], rows)
+            assert not any(x.aval.shape[-2:] == (384, 384)
+                           for x in e.invars + e.outvars)
+
+    @pytest.mark.parametrize("tiles", ["fused", "two_sweeps"], indirect=True)
+    def test_tiles_above_the_diagonal_are_not_computed(self, tiles):
+        """A NaN among the later keys and values poisons a masked product
+        (0 x NaN) but not a tile that never runs: the queries of the first
+        block, and their own gradient, stay what they are without it."""
+        q, k, v = heads(256, 4, 1, seed=23)
+        late = jnp.arange(256)[None, :, None, None] >= 128
+        k_bad, v_bad = (jnp.where(late, jnp.nan, x) for x in (k, v))
+        np.testing.assert_array_equal(
+            flash_causal_attention(q, k_bad, v_bad)[:, :128],
+            flash_causal_attention(q, k, v)[:, :128])
+
+        def first_block_loss(q_, k_, v_):
+            out = flash_causal_attention(q_, k_, v_)[:, :128]
+            return (out.astype(jnp.float32) ** 2).sum()
+
+        # (dK, dV of the first keys do hear from the later queries, whose
+        # own rows the NaN keys have rightly poisoned)
+        dq_bad = jax.grad(first_block_loss)(q, k_bad, v_bad)
+        dq = jax.grad(first_block_loss)(q, k, v)
+        np.testing.assert_array_equal(dq_bad[:, :128], dq[:, :128])
+
+    def test_uneven_groups_are_refused(self):
+        q, k, v = heads(64, 3, 2)
+        with pytest.raises(ValueError, match="3 query heads"):
+            flash_causal_attention(q, k, v)
 
 
 class TestFlashChannelAttention:

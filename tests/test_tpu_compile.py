@@ -248,3 +248,110 @@ def test_latent_moe_block_compiles_at_published_widths(v5e_mesh):
     assert not re.search(
         rf"\[{rows},({cfg.latent_size}|{cfg.expert_hidden})\]", hlo)
     assert not re.search(r"\[8192,512,\d+\]", hlo)
+
+
+@pytest.mark.parametrize("q_heads", [4, 16])
+def test_causal_attention_block_compiles_at_the_cells_shape(
+        v5e_mesh, monkeypatch, q_heads):
+    """The token model's attention block with the causal flash kernels live,
+    forward and reverse, in bfloat16 over 1 x 8,192 tokens: the benchmark
+    cell's share (4 query heads to 1 key/value head of 128) and the
+    published group (16 query heads to one key/value head).  One forward
+    call and one fused reverse call; no token-pair array of any dtype, and
+    no key/value head repeated for its group."""
+    import json
+    import os
+
+    from distributedpytorch_tpu.models import nemotron_h as nh
+
+    monkeypatch.setattr(danet_mod, "_on_tpu", lambda: True)
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "nemotron3_super_stage_tp8_ep64.json")) as f:
+        cfg = json.load(f)
+    assert (cfg["num_attention_heads"], cfg["num_key_value_heads"],
+            cfg["head_dim"]) == (4, 1, 128)
+    cfg = nh.LMConfig.from_dict(dict(cfg, num_attention_heads=q_heads))
+    one = _one_chip(v5e_mesh)
+    layer = nh.Attention(cfg, jnp.bfloat16)
+    seq = 8192
+    u = jax.ShapeDtypeStruct((1, seq, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, cfg.hidden_size),
+                                             jnp.bfloat16)))["params"])
+
+    def loss(p, v):
+        return layer.apply({"params": p}, v).astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, u).compile().as_text()
+    names = sorted(ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
+                   for ln in _custom_calls(hlo))
+    assert names == [scopes.CAUSAL_ATTN, scopes.CAUSAL_ATTN_BWD_FUSED]
+    assert not re.search(rf"\[(\d+,)*{seq},{seq}\]", hlo)
+    # the group reads its one key/value head through the index map: the
+    # calls take the queries' heads and ONE key/value row
+    for ln in _custom_calls(hlo):
+        operands = ln.split("operand_layout_constraints=", 1)[1]
+        assert f"bf16[{q_heads},{seq},128]" in operands, ln[:300]
+        assert operands.count(f"bf16[1,{seq},128]") == 2, ln[:300]
+
+
+def test_causal_attention_calls_resolve_to_their_block(v5e_mesh, monkeypatch):
+    """A small token model's differentiated forward with the kernels live,
+    compiled for the chip: four Mosaic calls — per attention layer the
+    forward call and the fused reverse call; the block's recomputation runs
+    no second forward call, it keeps the first one's output and
+    log-sum-exp — and the executable's scope table puts the trunk layer's
+    two under ``attn`` and the prediction module's under ``mtp``, none
+    under ``other``.  The benchmark's ``attn_kernel_roofline`` pattern reads
+    all four by their event names and DANet's ``pam`` patterns read none."""
+    import json
+    import os
+
+    from distributedpytorch_tpu.models import nemotron_h as nh
+
+    monkeypatch.setattr(danet_mod, "_on_tpu", lambda: True)
+    one = _one_chip(v5e_mesh)
+    model = nh.build_nemotron_h(
+        dict(nh.PRESETS["tiny"], head_dim=128, hidden_size=128),
+        dtype=jnp.bfloat16, remat=True)
+    tokens = jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 16), jnp.int32)))["params"])
+
+    def loss(p, t):
+        (logits, mtp), _ = model.apply({"params": p}, t, train=True,
+                                       mutable=["counters"])
+        return logits.sum() + mtp.sum()
+
+    hlo = jax.jit(jax.grad(loss)).lower(params, tokens).compile().as_text()
+    table = scopes.scope_table(hlo)
+    calls = {ln.split(" = ")[0].strip().lstrip("%") for ln in
+             _custom_calls(hlo)}
+    calls = {c for c in calls if c.startswith(scopes.CAUSAL_ATTN)}
+    by_layer = {}
+    for c in calls:
+        s = table[c]
+        by_layer.setdefault(s.layer, []).append(
+            (c.split(".")[0], s.phase))
+    want = [(scopes.CAUSAL_ATTN, "fwd"),
+            (scopes.CAUSAL_ATTN_BWD_FUSED, "bwd")]
+    assert {k: sorted(v) for k, v in by_layer.items()} == {
+        scopes.ATTN: want, scopes.MTP: want}
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def pattern(metric):
+        with open(os.path.join(here, "benchmarks", "metrics",
+                               metric + ".json")) as f:
+            return re.compile(json.load(f)["args"]["event_pattern"])
+
+    events = [f"%{c} custom-call" for c in calls]
+    assert all(pattern("attn_kernel_roofline").search(e) for e in events)
+    for metric in ("pam_kernel_roofline", "pam_backward_kernel_roofline"):
+        assert not any(pattern(metric).search(e) for e in events)
