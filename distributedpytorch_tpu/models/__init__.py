@@ -18,6 +18,7 @@ from .ccnet import CCNet, CrissCrossAttention, RCCAHead
 from .danet import DANet, DANetHead
 from .deeplab import ASPP, DeepLabV3, FCN, FCNHead
 from .encnet import EncNet, EncNetHead, Encoding
+from .keye_lm import KeyeLM, build_keye_lm
 from .nemotron_h import NemotronH, build_nemotron_h
 from .pspnet import PSPNet, PyramidPooling
 from .resnet import ResNet, resnet50, resnet101
@@ -26,7 +27,12 @@ from .resnet import ResNet, resnet50, resnet101
 #: name; a name not listed is a segmentation net.  What the trainer checks a
 #: configuration against: it knows tasks, not models.
 SEGMENTATION_TASKS = ("instance", "semantic")
-MODEL_TASKS = {"nemotron_h": ("tokens",)}
+#: the token models: ``name -> (builder(lm_config, dtype=, remat=), tasks)``.
+#: ``build_model``, :data:`MODEL_TASKS` and the ``lm_config`` error read
+#: this table; a new token model is one entry
+TOKEN_MODELS = {"nemotron_h": (build_nemotron_h, ("tokens",)),
+                "keye_lm": (build_keye_lm, ("tokens",))}
+MODEL_TASKS = {name: tasks for name, (_, tasks) in TOKEN_MODELS.items()}
 
 
 def model_tasks(name: str) -> tuple:
@@ -47,23 +53,24 @@ def build_model(
     bn_fp32_stats: bool = True,
     **kw,
 ):
-    """Construct a segmentation model by name — or, by ``nemotron_h``, the
-    token model of the ``tokens`` task (``lm_config``: a preset's name, a
-    JSON file of the published keys, or that dict; ``remat``; the image
-    options do not apply to it).
+    """Construct a segmentation model by name — or, by a name of
+    :data:`TOKEN_MODELS` (``nemotron_h``, ``keye_lm``), a token model of the
+    ``tokens`` task (``lm_config``: a preset's name, a JSON file of the
+    published keys, or that dict; ``remat``; the image options do not apply
+    to it).
 
     ``dtype`` may be a string ('float32' / 'bfloat16') for config-file use.
     """
     if isinstance(dtype, str):
         dtype = jnp.dtype(dtype)
-    if name == "nemotron_h":
-        return build_nemotron_h(
+    if name in TOKEN_MODELS:
+        return TOKEN_MODELS[name][0](
             kw.get("lm_config", ""), dtype=dtype,
             remat=kw.get("remat", True))
     if kw.pop("lm_config", ""):
         raise ValueError(
-            f"lm_config is nemotron_h-only; model {name!r} does not "
-            "support it")
+            f"lm_config is for the token models ({' | '.join(TOKEN_MODELS)}"
+            f"); model {name!r} does not support it")
     if isinstance(kw.get("pam_score_dtype"), str):
         kw["pam_score_dtype"] = jnp.dtype(kw["pam_score_dtype"])
     depth = _BACKBONE_DEPTH[backbone]
@@ -186,7 +193,7 @@ def build_model(
         )
     raise ValueError(
         f"unknown model: {name!r} (danet | deeplabv3 | deeplabv3plus | fcn "
-        "| pspnet | encnet | ccnet | nemotron_h)")
+        f"| pspnet | encnet | ccnet | {' | '.join(TOKEN_MODELS)})")
 
 
 def build_from_config(mcfg, *, dtype, bn_cross_replica_axis=None,
@@ -226,6 +233,7 @@ __all__ = [
     "RCCAHead",
     "FCN",
     "FCNHead",
+    "KeyeLM",
     "NemotronH",
     "PSPNet",
     "PyramidPooling",

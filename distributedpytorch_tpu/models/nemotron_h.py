@@ -141,21 +141,21 @@ class LMConfig:
         return self.mamba_inner + 2 * self.mamba_groups * self.state_size
 
 
-def load_lm_config(spec: str | dict) -> dict:
+def load_preset(spec: str | dict, presets: dict) -> dict:
     """A preset's name (``""`` = ``tiny``), a path to a JSON file of the
     published keys (the benchmark's ``configs/*.json``), or the dict itself."""
     if isinstance(spec, dict):
         return spec
     spec = spec or "tiny"
-    if spec in PRESETS:
-        return PRESETS[spec]
+    if spec in presets:
+        return presets[spec]
     try:
         with open(spec) as f:
             return json.load(f)
     except OSError as e:
         raise ValueError(
             f"model.lm_config={spec!r} is neither a preset "
-            f"({sorted(PRESETS)}) nor a readable JSON file: {e}") from e
+            f"({sorted(presets)}) nor a readable JSON file: {e}") from e
 
 
 def layer_name(i: int) -> str:
@@ -545,5 +545,5 @@ class NemotronH(nn.Module):
 
 def build_nemotron_h(lm_config: str | dict = "", dtype=F32,
                      remat: bool = True) -> NemotronH:
-    return NemotronH(LMConfig.from_dict(load_lm_config(lm_config)),
+    return NemotronH(LMConfig.from_dict(load_preset(lm_config, PRESETS)),
                      dtype=dtype, remat=remat)

@@ -16,6 +16,10 @@ they are pure jnp functions the flax modules call, designed for the MXU:
 
 :func:`causal_attention` is a token model's layer (``models/nemotron_h.py``):
 causal, grouped-query, scaled by 1/sqrt(head_dim), heads kept apart.
+:func:`causal_attention` with ``keep`` is the same over a per-query key set
+(:func:`head_mean_probs` its probabilities, heads averaged), and
+:func:`indexer_scores` with :func:`topk_keep` / :func:`threshold_keep` are
+the learned selector that picks the set (``models/keye_lm.py``).
 
 Layouts: spatial features are (B, N, C) token-major — N = H*W spatial tokens —
 the natural NHWC flattening.  Scores accumulate in float32 regardless of input
@@ -135,24 +139,140 @@ def channel_attention(x: jax.Array) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
+def _causal_scores(q, k, keep=None):
+    """float32 (B, G, R, S, S) scaled scores of each query's causal keys, or
+    of those that ``keep`` (B, S, S) marks, ``-inf`` elsewhere; ``q`` grouped
+    by key/value head."""
+    length, hd = q.shape[1], q.shape[-1]
+    sc = jnp.einsum("bqgrd,bkgd->bgrqk", q, k,
+                    preferred_element_type=jnp.float32) / math.sqrt(hd)
+    pos = jnp.arange(length)
+    seen = pos[:, None] >= pos[None, :]
+    if keep is not None:
+        seen = (seen & keep)[:, None, None]
+    return jnp.where(seen, sc, -jnp.inf)
+
+
+def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     keep: jax.Array | None = None) -> jax.Array:
     """Causal grouped-query attention, scores scaled by 1/sqrt(head_dim).
 
     ``q``: (B, S, Hq, D); ``k``, ``v``: (B, S, Hkv, D), ``Hq`` a multiple of
     ``Hkv``: query heads ``g·r … g·r + r − 1`` read key/value head ``g`` ->
-    (B, S, Hq, D) in ``q``'s dtype.  The float32 (B, Hq, S, S) scores and
-    their probabilities in ``q``'s dtype are whole arrays here: the form that
-    runs off-TPU and in float32; ``ops/pallas_attention.py::
-    flash_causal_attention`` is the same mathematics tile by tile."""
+    (B, S, Hq, D) in ``q``'s dtype.  ``keep`` (bool, (B, S, S)): query ``t``
+    of every head attends only to the keys ``s <= t`` with ``keep[b, t, s]``
+    (a learned sparse attention's key set).  The float32 (B, Hq, S, S) scores
+    and their probabilities in ``q``'s dtype are whole arrays here: the form
+    that runs off-TPU and in float32; ``ops/pallas_attention.py::
+    flash_causal_attention`` / ``flash_sparse_attention`` are the same
+    mathematics tile by tile."""
     b, length, qh, hd = q.shape
     kvh = k.shape[2]
     dtype = q.dtype
-    q = q.reshape(b, length, kvh, qh // kvh, hd)
-    sc = jnp.einsum("bqgrd,bkgd->bgrqk", q, k,
-                    preferred_element_type=jnp.float32) / math.sqrt(hd)
-    pos = jnp.arange(length)
-    sc = jnp.where(pos[:, None] >= pos[None, :], sc, -jnp.inf)
+    sc = _causal_scores(q.reshape(b, length, kvh, qh // kvh, hd), k, keep)
     w = jax.nn.softmax(sc, axis=-1).astype(dtype)
     out = jnp.einsum("bgrqk,bkgd->bqgrd", w, v,
                      preferred_element_type=jnp.float32)
     return out.astype(dtype).reshape(b, length, qh, hd)
+
+
+def head_mean_probs(q: jax.Array, k: jax.Array,
+                    keep: jax.Array | None = None) -> jax.Array:
+    """The mean over the query heads of :func:`causal_attention`'s
+    probabilities: float32 (B, S, S), zero outside the key set, each row
+    summing to one — what a learned selector is aligned with."""
+    b, length, qh, hd = q.shape
+    kvh = k.shape[2]
+    sc = _causal_scores(q.reshape(b, length, kvh, qh // kvh, hd), k, keep)
+    return jax.nn.softmax(sc, axis=-1).sum(axis=(1, 2)) / qh
+
+
+def indexer_scores(qi: jax.Array, ki: jax.Array, w: jax.Array) -> jax.Array:
+    """A learned sparse attention's index scores ``I[b, t, s] = (J·Di)^-½ ·
+    Σ_j w[b, t, j] · ReLU(qi[b, t, j] · ki[b, s])``, float32 at full
+    precision (a pick that differs is another key set, not a rounding).
+
+    ``qi``: (B, S, J, Di); ``ki``: (B, S, Di), one key head; ``w``: (B, S, J)
+    -> (B, S, S), every pair, causal or not.  The (B, J, S, S) products are a
+    whole array here; ``ops/pallas_attention.py::flash_indexer_scores`` is
+    the same sum tile by tile."""
+    j, di = qi.shape[2:]
+    s = jnp.einsum("bqjd,bkd->bjqk", qi.astype(jnp.float32),
+                   ki.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
+    w = jnp.moveaxis(w.astype(jnp.float32), -1, 1)[..., None]
+    return (w * jnp.maximum(s, 0.0)).sum(axis=1) / math.sqrt(j * di)
+
+
+def keys_wanted(length: int, topk: int) -> jax.Array:
+    """``min(t + 1, topk)`` for every query position ``t``: (S,) int32."""
+    return jnp.minimum(jnp.arange(length, dtype=jnp.int32) + 1, topk)
+
+
+def topk_keep(scores: jax.Array, topk: int) -> jax.Array:
+    """The key set of every query by ``jax.lax.top_k``: bool (B, S, S),
+    ``keep[b, t, s]`` where ``s`` is among the ``min(t + 1, topk)`` causal
+    keys of largest ``scores[b, t, ·]``, equal scores to the lower index
+    (``top_k``'s rule).  The form that sorts; :func:`threshold_keep` gives
+    the same set without a sort."""
+    b, length, _ = scores.shape
+    pos = jnp.arange(length)
+    causal = pos[:, None] >= pos[None, :]
+    if topk >= length:
+        return jnp.broadcast_to(causal, scores.shape)
+    _, idx = jax.lax.top_k(jnp.where(causal, scores, -jnp.inf), topk)
+    rows = jnp.arange(b * length)[:, None]
+    keep = jnp.zeros((b * length, length), bool).at[
+        rows, idx.reshape(b * length, topk)].set(True)
+    return keep.reshape(scores.shape) & causal
+
+
+def _sortable(x: jax.Array) -> jax.Array:
+    """float32 -> int32 whose signed order is the floats' total order (-0
+    below +0, as ``top_k`` has it)."""
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    return bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+
+
+def threshold_keep(scores: jax.Array, topk: int) -> jax.Array:
+    """:func:`topk_keep`'s set, exactly, by a threshold per row and no sort:
+    the ``n``-th largest score of the row's causal keys is found bit by bit
+    (32 counts of the row; ``n = min(t + 1, topk)``), then, among the keys
+    that equal it, the index up to which they are kept (14 more counts at
+    8,192 keys), so that exactly ``n`` keys stay and equal scores go to the
+    lower index."""
+    b, length, _ = scores.shape
+    pos = jnp.arange(length, dtype=jnp.int32)
+    causal = pos[:, None] >= pos[None, :]
+    if topk >= length:
+        return jnp.broadcast_to(causal, scores.shape)
+    lowest = jnp.iinfo(jnp.int32).min
+    # outside the causal row a key sorts below every score
+    key = jnp.where(causal, _sortable(scores), lowest)
+    want = keys_wanted(length, topk)[None, :, None]
+
+    def count(hit):
+        return hit.sum(axis=-1, keepdims=True, dtype=jnp.int32)
+
+    def value_bit(i, t):
+        # the candidate sets one more bit of the threshold's offset-binary
+        # form, from the top; it stands if enough keys reach it
+        bit = jnp.left_shift(jnp.int32(1), 31 - i)
+        cand = jnp.where(i == 0, jnp.zeros_like(t), t + bit)
+        return jnp.where(count(key >= cand) >= want, cand, t)
+
+    t = jax.lax.fori_loop(0, 32, value_bit,
+                          jnp.full((b, length, 1), lowest, jnp.int32))
+    above = key > t
+    tied = (key == t) & causal
+    spare = want - count(above)          # >= 1: ties to keep, lowest first
+
+    def index_bit(i, j):
+        cand = j + jnp.left_shift(jnp.int32(1), n_bits - 1 - i)
+        return jnp.where(count(tied & (pos < cand)) < spare, cand, j)
+
+    n_bits = max(1, (length - 1).bit_length())
+    # the largest j with fewer than ``spare`` ties below it: the last kept
+    last = jax.lax.fori_loop(0, n_bits, index_bit,
+                             jnp.zeros((b, length, 1), jnp.int32))
+    return (above | (tied & (pos <= last))) & causal

@@ -102,13 +102,15 @@ def _on_local_batch(kernel, *operands):
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *refs,
                   n_real: int, block_k: int, scale: float | None,
-                  causal: bool = False):
+                  causal: bool = False, keep_ref=None):
     """One (q-block, k-block) tile of online-softmax attention.  ``refs``:
     the running (max, sum, accumulator) scratch, after the log-sum-exp
     output where the call was built with one (the differentiated forward).
     ``causal``: a query sees the keys at or before its own position; a tile
     wholly above the diagonal is not computed, and only a tile that crosses
-    it pays for the mask."""
+    it pays for the mask.  ``keep_ref`` (a causal call's): the tile of each
+    query's key set, int8; every tile that runs is masked by it, and it
+    holds the causal mask already."""
     *lse_ref, m_ref, s_ref, acc_ref = refs
     j = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -127,18 +129,24 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *refs,
             q, k, _NT, preferred_element_type=jnp.float32)   # (bq, bk)
         if scale is not None:
             scores = scores * scale
-        key_idx = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 1)
-        if not causal:
-            # Mask keys past the true token count (N was padded to a block
-            # multiple).
-            scores = jnp.where(key_idx < n_real, scores, _NEG_INF)
-        elif q_lo is not None:
-            # the tile crosses the diagonal.  Padded keys lie past every
-            # real query, so this mask is theirs too
-            query_idx = q_lo + jax.lax.broadcasted_iota(
-                jnp.int32, scores.shape, 0)
-            scores = jnp.where(key_idx <= query_idx, scores, _NEG_INF)
+        if keep_ref is not None:
+            # a row none of whose keys has been kept yet sums rubbish at the
+            # running max -1e30; its first kept key (at the latest its own
+            # position's tile) rescales that to nothing
+            scores = jnp.where(_kept(keep_ref[0]), scores, _NEG_INF)
+        else:
+            key_idx = j * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, scores.shape, 1)
+            if not causal:
+                # Mask keys past the true token count (N was padded to a
+                # block multiple).
+                scores = jnp.where(key_idx < n_real, scores, _NEG_INF)
+            elif q_lo is not None:
+                # the tile crosses the diagonal.  Padded keys lie past every
+                # real query, so this mask is theirs too
+                query_idx = q_lo + jax.lax.broadcasted_iota(
+                    jnp.int32, scores.shape, 0)
+                scores = jnp.where(key_idx <= query_idx, scores, _NEG_INF)
 
         m_prev = m_ref[:, :1]                            # (bq, 1)
         m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
@@ -155,9 +163,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *refs,
         q_lo = pl.program_id(1) * block_q
         k_lo = j * block_k
         crosses = k_lo + block_k - 1 > q_lo
-        pl.when(jnp.logical_and(k_lo < q_lo + block_q, crosses))(
-            functools.partial(tile, q_lo))
-        pl.when(jnp.logical_not(crosses))(tile)
+        if keep_ref is not None:
+            pl.when(k_lo < q_lo + block_q)(tile)
+        else:
+            pl.when(jnp.logical_and(k_lo < q_lo + block_q, crosses))(
+                functools.partial(tile, q_lo))
+            pl.when(jnp.logical_not(crosses))(tile)
     else:
         tile()
 
@@ -169,6 +180,23 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *refs,
             lse_ref[0][0] = m_ref[:] + jnp.log(s_ref[:])
 
 
+def _kept(tile):
+    """Where an int8 tile of a key set holds a key."""
+    return tile.astype(jnp.int32) != 0
+
+
+def _sparse_flash_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, *refs, **kw):
+    _flash_kernel(q_ref, k_ref, v_ref, o_ref, *refs, keep_ref=keep_ref, **kw)
+
+
+def _pad_keep(keep, rows: int, cols: int):
+    """Zero-pad a (B, S, S) array of token pairs (an int8 key set: a padded
+    query keeps nothing, a padded key is kept by no query) to (B, rows,
+    cols)."""
+    return jnp.pad(keep, ((0, 0), (0, rows - keep.shape[1]),
+                          (0, cols - keep.shape[2])))
+
+
 def _pad_tokens(x, n_padded: int):
     """Zero-pad the token dim (1) of a (B, N, ...) array to ``n_padded``."""
     pad = n_padded - x.shape[1]
@@ -177,9 +205,11 @@ def _pad_tokens(x, n_padded: int):
     return jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
 
 
-def _flash_local(q, k, v, *, block_q: int, block_k: int,
+def _flash_local(q, k, v, keep=None, *, block_q: int, block_k: int,
                  scale: float | None, interpret: bool, with_lse: bool,
-                 causal: bool = False, group: int = 1):
+                 causal: bool = False, group: int = 1, heads: int = 1):
+    """``keep``: int8 (B / heads, N, N), each query's key set, shared by the
+    ``heads`` rows of ``q`` that are one sequence's query heads."""
     b, n, ck = q.shape
     cv = v.shape[-1]
     nq = pl.cdiv(n, block_q)
@@ -188,8 +218,9 @@ def _flash_local(q, k, v, *, block_q: int, block_k: int,
     k = _pad_tokens(k, nk * block_k)
     v = _pad_tokens(v, nk * block_k)
 
-    kernel = functools.partial(_flash_kernel, n_real=n, block_k=block_k,
-                               scale=scale, causal=causal)
+    kernel = functools.partial(
+        _flash_kernel if keep is None else _sparse_flash_kernel,
+        n_real=n, block_k=block_k, scale=scale, causal=causal)
     # a causal call's tiles (``_CAUSAL_TILE``) pass Mosaic's default scope
     extra = dict(compiler_params=pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -204,6 +235,19 @@ def _flash_local(q, k, v, *, block_q: int, block_k: int,
             j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
         return (b_, j, 0)
 
+    in_specs = [
+        pl.BlockSpec((1, block_q, ck), lambda b_, i, j: (b_, i, 0)),
+        pl.BlockSpec((1, block_k, ck), kv_map),
+        pl.BlockSpec((1, block_k, cv), kv_map),
+    ]
+    operands = (q, k, v)
+    name = scopes.CAUSAL_ATTN if causal else scopes.PAM_KERNEL
+    if keep is not None:
+        def keep_map(b_, i, j):
+            return (b_ // heads, i, kv_map(b_, i, j)[1])
+        in_specs.append(pl.BlockSpec((1, block_q, block_k), keep_map))
+        operands += (_pad_keep(keep, nq * block_q, nk * block_k),)
+        name = scopes.SPARSE_ATTN
     out_specs = [pl.BlockSpec((1, block_q, cv), lambda b_, i, j: (b_, i, 0))]
     out_shape = [jax.ShapeDtypeStruct((b, nq * block_q, cv), v.dtype)]
     if with_lse:
@@ -216,11 +260,7 @@ def _flash_local(q, k, v, *, block_q: int, block_k: int,
     res = pl.pallas_call(
         kernel,
         grid=(b, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, ck), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_k, ck), kv_map),
-            pl.BlockSpec((1, block_k, cv), kv_map),
-        ],
+        in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
@@ -232,9 +272,9 @@ def _flash_local(q, k, v, *, block_q: int, block_k: int,
         # the call's name is its innermost scope, and the TPU compiler names
         # the custom-call after that: the trace shows ``%pam`` whatever
         # encloses the call (a module, a shard_map)
-        name=scopes.CAUSAL_ATTN if causal else scopes.PAM_KERNEL,
+        name=name,
         **extra,
-    )(q, k, v)
+    )(*operands)
     out = res[0][:, :n, :]
     if with_lse:
         return out, res[1][:, :n, 0]
@@ -244,12 +284,13 @@ def _flash_local(q, k, v, *, block_q: int, block_k: int,
 def _flash_forward(q, k, v, block_q: int, block_k: int,
                    scale: float | None, interpret: bool,
                    with_lse: bool = False, causal: bool = False,
-                   group: int = 1):
+                   group: int = 1, keep=None, heads: int = 1):
     return _on_local_batch(
         functools.partial(_flash_local, block_q=block_q, block_k=block_k,
                           scale=scale, interpret=interpret,
-                          with_lse=with_lse, causal=causal, group=group),
-        q, k, v)
+                          with_lse=with_lse, causal=causal, group=group,
+                          heads=heads),
+        q, k, v, *(() if keep is None else (keep,)))
 
 
 # ------------------------------------------------- position reverse pass
@@ -277,19 +318,23 @@ def _bwd_plan(n: int, ck: int) -> tuple[int, bool]:
 
 
 def _bwd_tile(q, k, v, do, lse, delta, *, key_block, n_real: int,
-              scale: float | None, diagonal: bool | None = None):
+              scale: float | None, diagonal: bool | None = None, keep=None):
     """``(Pᵀ, dSᵀ)`` of one tile, keys on sublanes and queries on lanes —
     the orientation in which ``lse`` and ``delta`` (per query) are lane-
     dense rows and dV, dK need no transpose.  float32 throughout.
     ``diagonal``: of a causal call, whether the tile's key block is its
-    query block, where a key counts for the queries at or after it."""
+    query block, where a key counts for the queries at or after it.
+    ``keep``: the tile of the queries' key sets, keys on sublanes, int8; it
+    holds the causal mask and the padding's already."""
     st = jax.lax.dot_general(k, q, _NT,
                              preferred_element_type=jnp.float32)  # (bk, bq)
     if scale is not None:
         st = st * scale
     pt = jnp.exp(st - lse)
     block = k.shape[0]
-    if diagonal:  # padded keys lie past every real query: masked here too
+    if keep is not None:
+        pt = jnp.where(_kept(keep), pt, 0.0)
+    elif diagonal:  # padded keys lie past every real query: masked here too
         pt = jnp.where(
             jax.lax.broadcasted_iota(jnp.int32, pt.shape, 0)
             <= jax.lax.broadcasted_iota(jnp.int32, pt.shape, 1), pt, 0.0)
@@ -306,16 +351,21 @@ def _bwd_tile(q, k, v, do, lse, delta, *, key_block, n_real: int,
     return pt, dst
 
 
-def _causal_tiles(tile, key_block, query_block):
+def _causal_tiles(tile, key_block, query_block, sparse: bool = False):
     """Run ``tile(diagonal)`` where a causal call has work: below the
-    diagonal as it is, on it masked, above it not at all."""
+    diagonal as it is, on it masked, above it not at all.  ``sparse``: the
+    tile masks itself by its key set, on the diagonal as below it."""
+    if sparse:
+        pl.when(query_block >= key_block)(functools.partial(tile, False))
+        return
     pl.when(query_block > key_block)(functools.partial(tile, False))
     pl.when(query_block == key_block)(functools.partial(tile, True))
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *refs, n_real: int,
-                    scale: float | None, causal: bool = False):
+                    scale: float | None, causal: bool = False,
+                    keep_ref=None):
     """The key-block sweep, queries innermost: dK and dV of the block
     accumulate in float32 scratch.  Built with a dQ output (the fused
     schedule) it also adds the tile's ``dS·k`` into the image's dQ, which
@@ -332,7 +382,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         q, k, do = q_ref[0], k_ref[0], do_ref[0]
         pt, dst = _bwd_tile(q, k, v_ref[0], do, lse_ref[0], delta_ref[0],
                             key_block=j, n_real=n_real, scale=scale,
-                            diagonal=diagonal)
+                            diagonal=diagonal,
+                            keep=None if keep_ref is None else keep_ref[0])
         dst = dst.astype(q.dtype)
         dv_acc[:] += jax.lax.dot_general(pt.astype(do.dtype), do, _NN,
                                          preferred_element_type=jnp.float32)
@@ -355,7 +406,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_ref[0][0, rows, :] += dq
 
     if causal:
-        _causal_tiles(tile, j, i)
+        _causal_tiles(tile, j, i, sparse=keep_ref is not None)
     else:
         tile()
 
@@ -365,9 +416,21 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _sparse_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                           keep_ref, dk_ref, dv_ref, *refs, **kw):
+    _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
+                    dv_ref, *refs, keep_ref=keep_ref, **kw)
+
+
+def _sparse_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                          keep_ref, dq_ref, dq_acc, **kw):
+    _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                   dq_acc, keep_ref=keep_ref, **kw)
+
+
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_acc, *, n_real: int, scale: float | None,
-                   causal: bool = False):
+                   causal: bool = False, keep_ref=None):
     """The query-block sweep of the two-sweep schedule, keys innermost."""
     j = pl.program_id(2)
 
@@ -379,12 +442,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0]
         _, dst = _bwd_tile(q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0],
                            delta_ref[0], key_block=j, n_real=n_real,
-                           scale=scale, diagonal=diagonal)
+                           scale=scale, diagonal=diagonal,
+                           keep=None if keep_ref is None else keep_ref[0])
         dq_acc[:] += jax.lax.dot_general(dst.astype(k.dtype), k, _TN,
                                          preferred_element_type=jnp.float32)
 
     if causal:
-        _causal_tiles(tile, j, pl.program_id(1))
+        _causal_tiles(tile, j, pl.program_id(1),
+                      sparse=keep_ref is not None)
     else:
         tile()
 
@@ -393,9 +458,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _flash_backward_local(q, k, v, out, lse, do, *, scale: float | None,
-                          interpret: bool, causal: bool = False,
-                          group: int = 1):
+def _flash_backward_local(q, k, v, out, lse, do, keep_t=None, *,
+                          scale: float | None, interpret: bool,
+                          causal: bool = False, group: int = 1,
+                          heads: int = 1):
     """dq, dk, dv of flash position attention from the saved output and
     log-sum-exp.  Per tile: ``S = q·kᵀ`` (× ``scale``), ``P = exp(S − lse)``
     with padded keys at 0, ``dP = dO·vᵀ``, ``dS = P ∘ (dP − δ)`` with ``δ =
@@ -414,7 +480,9 @@ def _flash_backward_local(q, k, v, out, lse, do, *, scale: float | None,
     block index stays on the diagonal's while they are, so they copy
     nothing.  ``group`` > 1: a row of ``q`` is a query head and ``group`` of
     them read one row of ``k``, ``v``; each writes its own float32 dK, dV,
-    summed over the group here."""
+    summed over the group here.  ``keep_t`` (the calls are then named
+    ``sparse_attn_bwd_…``): int8 (B / heads, N keys, N queries), the queries'
+    key sets transposed to the tiles' orientation."""
     b, n, ck = q.shape
     cv = v.shape[-1]
     block, fused = _bwd_plan(n, ck)
@@ -434,6 +502,12 @@ def _flash_backward_local(q, k, v, out, lse, do, *, scale: float | None,
     names = (scopes.CAUSAL_ATTN_BWD_FUSED, scopes.CAUSAL_ATTN_BWD_DKV,
              scopes.CAUSAL_ATTN_BWD_DQ) if causal else (
         scopes.PAM_BWD_FUSED, scopes.PAM_BWD_DKV, scopes.PAM_BWD_DQ)
+    dkv_kernel, dq_kernel, operands = _bwd_dkv_kernel, _bwd_dq_kernel, ()
+    if keep_t is not None:
+        names = (scopes.SPARSE_ATTN_BWD_FUSED, scopes.SPARSE_ATTN_BWD_DKV,
+                 scopes.SPARSE_ATTN_BWD_DQ)
+        dkv_kernel, dq_kernel = _sparse_bwd_dkv_kernel, _sparse_bwd_dq_kernel
+        operands = (_pad_keep(keep_t, nb * block, nb * block),)
 
     def specs(at_q, at_k):
         """In-specs of (q, k, v, dO, lse, δ); ``at_q`` / ``at_k``: which
@@ -454,8 +528,11 @@ def _flash_backward_local(q, k, v, out, lse, do, *, scale: float | None,
                                 lambda *g: (row(g), at(g), 0))
         q_row = lambda g: g[0]
         row = pl.BlockSpec((1, 1, block), lambda *g: (g[0], 0, qi(g)))
+        sparse = [] if keep_t is None else [pl.BlockSpec(
+            (1, block, block), lambda *g: (g[0] // heads, ki(g), qi(g)))]
         return [tokens(ck, q_row, qi), tokens(ck, kv_row, ki),
-                tokens(cv, kv_row, ki), tokens(cv, q_row, qi), row, row]
+                tokens(cv, kv_row, ki), tokens(cv, q_row, qi), row, row
+                ] + sparse
 
     # the key-block sweep: grid (batch, k_blocks, q_blocks)
     dkv_specs = specs(2, 1)
@@ -472,7 +549,7 @@ def _flash_backward_local(q, k, v, out, lse, do, *, scale: float | None,
             pl.BlockSpec((1,) + q.shape[1:], lambda b_, j, i: (b_, 0, 0)))
         out_shape.append(jax.ShapeDtypeStruct(q.shape, jnp.float32))
     res = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **static),
+        functools.partial(dkv_kernel, **static),
         grid=(b, nb, nb),
         in_specs=dkv_specs,
         out_specs=out_specs,
@@ -482,7 +559,7 @@ def _flash_backward_local(q, k, v, out, lse, do, *, scale: float | None,
         compiler_params=params,
         interpret=interpret,
         name=names[0] if fused else names[1],
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, lse, delta, *operands)
     dk, dv = res[0], res[1]
     if group > 1:
         dk, dv = (x.reshape((b // group, group) + x.shape[1:]).sum(1)
@@ -491,7 +568,7 @@ def _flash_backward_local(q, k, v, out, lse, do, *, scale: float | None,
         dq = res[2].astype(q.dtype)
     else:
         dq = pl.pallas_call(
-            functools.partial(_bwd_dq_kernel, **static),
+            functools.partial(dq_kernel, **static),
             grid=(b, nb, nb),
             in_specs=specs(1, 2),
             out_specs=pl.BlockSpec((1, block, ck),
@@ -501,7 +578,7 @@ def _flash_backward_local(q, k, v, out, lse, do, *, scale: float | None,
             compiler_params=params,
             interpret=interpret,
             name=names[2],
-        )(q, k, v, do, lse, delta)
+        )(q, k, v, do, lse, delta, *operands)
     return dq[:, :n], dk[:, :n], dv[:, :n]
 
 
@@ -577,14 +654,468 @@ def flash_causal_attention(q, k, v, interpret: bool = False):
         raise ValueError(f"{hq} query heads do not share {hkv} key/value "
                          f"heads evenly")
 
-    def heads_first(x):   # heads beside the batch on the grid's first axis
-        return x.transpose(0, 2, 1, 3).reshape(b * x.shape[2], s, d)
-
-    block_q, block_k = (min(t, 128 * pl.cdiv(s, 128)) for t in _CAUSAL_TILE)
     out = flash_position_attention(
-        heads_first(q), heads_first(k), heads_first(v), block_q, block_k,
+        _heads_first(q), _heads_first(k), _heads_first(v), *_causal_blocks(s),
         1 / math.sqrt(d), interpret=interpret, causal=True, group=hq // hkv)
     return out.reshape(b, hq, s, d).transpose(0, 2, 1, 3)
+
+
+# --------------------------------------------- learned sparse attention
+#: ``checkpoint_name``s of what a sparse attention block keeps across its
+#: recomputation: the forward call's output and log-sum-exp (as
+#: :data:`KEPT_BY_REVERSE`) and the key set, so that the reverse pass runs
+#: neither a second forward call nor a second selection
+SPARSE_KEPT_BY_REVERSE = ("sparse_attn_out", "sparse_attn_lse",
+                          "sparse_attn_keep")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _sparse_attention(q, k, v, keep, block_q, block_k, scale, interpret,
+                      group, heads):
+    return _flash_forward(q, k, v, block_q, block_k, scale, interpret,
+                          with_lse=True, causal=True, group=group, keep=keep,
+                          heads=heads)
+
+
+def _sparse_fwd(q, k, v, keep, block_q, block_k, scale, interpret, group,
+                heads):
+    out, lse = _flash_forward(q, k, v, block_q, block_k, scale, interpret,
+                              with_lse=True, causal=True, group=group,
+                              keep=keep, heads=heads)
+    out, lse = map(checkpoint_name, (out, lse), SPARSE_KEPT_BY_REVERSE[:2])
+    return (out, lse), (q, k, v, keep, out, lse)
+
+
+def _sparse_bwd(block_q, block_k, scale, interpret, group, heads, res, g):
+    q, k, v, keep, out, lse = res
+    with jax.named_scope(scopes.SPARSE_ATTN_BWD):
+        # the reverse tiles hold keys on sublanes and queries on lanes
+        grads = _on_local_batch(
+            functools.partial(_flash_backward_local, scale=scale,
+                              interpret=interpret, causal=True, group=group,
+                              heads=heads),
+            q, k, v, out, lse, g[0], keep.swapaxes(1, 2))
+    return (*grads, None)
+
+
+_sparse_attention.defvjp(_sparse_fwd, _sparse_bwd)
+
+
+def _heads_first(x):
+    """(B, S, H, D) -> (B·H, S, D): heads beside the batch on the grid's
+    first axis."""
+    b, s, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+
+def _causal_blocks(s: int) -> tuple[int, int]:
+    return tuple(min(t, 128 * pl.cdiv(s, 128)) for t in _CAUSAL_TILE)
+
+
+def flash_sparse_attention(q, k, v, keep, interpret: bool = False):
+    """:func:`flash_causal_attention` over a per-query key set
+    (``ops/attention.py::causal_attention`` with ``keep``): every tile at or
+    below the diagonal runs, masked by its tile of ``keep``; the calls are
+    ``sparse_attn`` and ``sparse_attn_bwd_…``.
+
+    ``keep``: int8 (B, S, S), ``keep[b, t, s] != 0`` where query ``t`` of
+    every head attends to key ``s``; it holds the causal mask (``s <= t``)
+    and at least one key a row.  Read once per query head and pass.
+    Returns ``(out (B, S, Hq, D), lse (B·Hq, S) float32)``; no gradient
+    reaches ``keep`` or comes from ``lse``."""
+    b, s, hq, d = q.shape
+    hkv = k.shape[2]
+    if hq % hkv:
+        raise ValueError(f"{hq} query heads do not share {hkv} key/value "
+                         f"heads evenly")
+    out, lse = _sparse_attention(
+        _heads_first(q), _heads_first(k), _heads_first(v), keep,
+        *_causal_blocks(s), 1 / math.sqrt(d), interpret, hq // hkv, hq)
+    return out.reshape(b, hq, s, d).transpose(0, 2, 1, 3), lse
+
+
+def _probs_kernel(q_ref, k_ref, lse_ref, keep_ref, o_ref, *, scale: float,
+                  heads: int):
+    """One (q-block, k-block) tile of the head-averaged probabilities, the
+    query heads on the grid's last axis: the tile stays in VMEM while they
+    add ``exp(S − lse)`` of their own scores to it."""
+    i, j, h = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    block_q, block_k = o_ref.shape[1:]
+
+    @pl.when(h == 0)
+    def _init():
+        o_ref[0] = jnp.zeros_like(o_ref[0])
+
+    @pl.when(j * block_k < (i + 1) * block_q)
+    def _tile():
+        scores = jax.lax.dot_general(
+            q_ref[0], k_ref[0], _NT,
+            preferred_element_type=jnp.float32) * scale
+        p = jnp.exp(scores - lse_ref[0])
+        o_ref[0] += jnp.where(_kept(keep_ref[0]), p, 0.0) * (1.0 / heads)
+
+
+def _probs_local(q, k, lse, keep, *, block_q: int, block_k: int,
+                 scale: float, interpret: bool, group: int, heads: int):
+    bh, n, d = q.shape
+    b = bh // heads
+    nq, nk = pl.cdiv(n, block_q), pl.cdiv(n, block_k)
+    q = _pad_tokens(q, nq * block_q)
+    k = _pad_tokens(k, nk * block_k)
+    lse = _pad_tokens(lse, nq * block_q)[:, :, None]
+
+    def last(i, j):  # a tile above the diagonal fetches nothing new
+        return jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+
+    out = pl.pallas_call(
+        functools.partial(_probs_kernel, scale=scale, heads=heads),
+        grid=(b, nq, nk, heads),
+        in_specs=[
+            pl.BlockSpec((1, block_q, d),
+                         lambda b_, i, j, h: (b_ * heads + h, i, 0)),
+            pl.BlockSpec((1, block_k, d),
+                         lambda b_, i, j, h: ((b_ * heads + h) // group,
+                                              last(i, j), 0)),
+            pl.BlockSpec((1, block_q, 1),
+                         lambda b_, i, j, h: (b_ * heads + h, i, 0)),
+            pl.BlockSpec((1, block_q, block_k),
+                         lambda b_, i, j, h: (b_, i, last(i, j))),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, block_k),
+                               lambda b_, i, j, h: (b_, i, j)),
+        out_shape=jax.ShapeDtypeStruct((b, nq * block_q, nk * block_k),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
+        interpret=interpret,
+        name=scopes.SPARSE_ATTN_PROBS,
+    )(q, k, lse, _pad_keep(keep, nq * block_q, nk * block_k))
+    return out[:, :n, :n]
+
+
+def flash_head_mean_probs(q, k, keep, lse, interpret: bool = False):
+    """``ops/attention.py::head_mean_probs`` from the log-sum-exp that
+    :func:`flash_sparse_attention` handed back: float32 (B, S, S), the mean
+    over the query heads of each head's probabilities over the key set.
+    One Mosaic call (``sparse_probs``), heads innermost, so the only (S, S)
+    array written is the result; a tile above the diagonal is written as
+    zeros.  No gradient (a selector's alignment target has none)."""
+    b, s, hq, d = q.shape
+    hkv = k.shape[2]
+    block_q, block_k = _causal_blocks(s)
+    q, k, keep, lse = map(jax.lax.stop_gradient, (q, k, keep, lse))
+    return _on_local_batch(
+        functools.partial(_probs_local, block_q=block_q, block_k=block_k,
+                          scale=1 / math.sqrt(d), interpret=interpret,
+                          group=hq // hkv, heads=hq),
+        _heads_first(q), _heads_first(k), lse, keep)
+
+
+#: query rows of a selection block (each holds its whole row of keys in
+#: VMEM: 4 MB of float32 scores and as much of sortable keys at 8,192), and
+#: the keys of one counting pass's chunk
+_SELECT_ROWS = 128
+_SELECT_CHUNK = 1024
+_INT_MIN = -2 ** 31
+
+
+def _topk_keep_kernel(s_ref, o_ref, key_ref, *, topk: int, chunk: int):
+    """The key sets of a block of queries, exactly and with no sort
+    (``ops/attention.py::threshold_keep``): the scores become int32 keys of
+    the same order, the ``n``-th largest key of each row is built bit by bit
+    from counts of the row (``n = min(t + 1, topk)``), then the index up to
+    which the keys that equal it are kept.  A pass counts only the chunks
+    that hold a causal key of the block."""
+    rows, length = key_ref.shape
+    q_lo = pl.program_id(1) * rows
+    t = q_lo + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    n_chunks = length // chunk
+    live = (q_lo + rows + chunk - 1) // chunk    # chunks with a causal key
+
+    def cols_of(c):
+        return pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+
+    def col_index(c):
+        return c * chunk + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, chunk), 1)
+
+    def write(keep_of):
+        def body(c, carry):
+            keep = jnp.logical_and(keep_of(c), col_index(c) <= t)
+            o_ref[0, :, cols_of(c)] = keep.astype(jnp.int32).astype(jnp.int8)
+            return carry
+        jax.lax.fori_loop(0, n_chunks, body, 0)
+
+    @pl.when(q_lo + rows <= topk)
+    def _every_causal_key():
+        write(lambda c: jnp.full((rows, chunk), True))
+
+    @pl.when(q_lo + rows > topk)
+    def _select():
+        want = jnp.minimum(t + 1, topk).astype(jnp.float32)
+
+        def fill(c, carry):
+            bits = jax.lax.bitcast_convert_type(s_ref[0, :, cols_of(c)],
+                                                jnp.int32)
+            key = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+            key_ref[:, cols_of(c)] = jnp.where(col_index(c) <= t, key,
+                                               jnp.int32(_INT_MIN))
+            return carry
+        jax.lax.fori_loop(0, live, fill, 0)
+
+        def count(hit):
+            """(rows, 1) float32: the row's keys that ``hit(key, col)``."""
+            def body(c, acc):
+                return acc + jnp.sum(
+                    jnp.where(hit(key_ref[:, cols_of(c)], col_index(c)),
+                              1.0, 0.0), axis=-1, keepdims=True)
+            return jax.lax.fori_loop(0, live, body,
+                                     jnp.zeros((rows, 1), jnp.float32))
+
+        def value_bit(i, thr):
+            # offset-binary: INT_MIN + 2^31 wraps to 0, the top bit's turn
+            cand = thr + jnp.left_shift(jnp.int32(1), 31 - i)
+            enough = count(lambda key, col: key >= cand) >= want
+            return jnp.where(enough, cand, thr)
+
+        thr = jax.lax.fori_loop(
+            0, 32, value_bit, jnp.full((rows, 1), _INT_MIN, jnp.int32))
+        spare = want - count(lambda key, col: key > thr)
+
+        def index_bit(i, last):
+            cand = last + jnp.left_shift(jnp.int32(1), n_bits - 1 - i)
+            below = count(lambda key, col: jnp.logical_and(key == thr,
+                                                           col < cand))
+            return jnp.where(below < spare, cand, last)
+
+        n_bits = max(1, (length - 1).bit_length())
+        last = jax.lax.fori_loop(0, n_bits, index_bit,
+                                 jnp.zeros((rows, 1), jnp.int32))
+
+        def keep_of(c):
+            key = key_ref[:, cols_of(c)]
+            return jnp.logical_or(key > thr, jnp.logical_and(
+                key == thr, col_index(c) <= last))
+        write(keep_of)
+
+
+def _topk_keep_local(scores, *, topk: int, interpret: bool):
+    b, n, _ = scores.shape
+    rows = min(_SELECT_ROWS, 8 * pl.cdiv(n, 8))
+    chunk = min(_SELECT_CHUNK, 128 * pl.cdiv(n, 128))
+    nq = pl.cdiv(n, rows)
+    length = chunk * pl.cdiv(n, chunk)
+    # padded keys lie past every query; padded queries are cut off
+    scores = _pad_keep(scores.astype(jnp.float32), nq * rows, length)
+    keep = pl.pallas_call(
+        functools.partial(_topk_keep_kernel, topk=topk, chunk=chunk),
+        grid=(b, nq),
+        in_specs=[pl.BlockSpec((1, rows, length), lambda b_, i: (b_, i, 0))],
+        out_specs=pl.BlockSpec((1, rows, length), lambda b_, i: (b_, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, nq * rows, length), jnp.int8),
+        scratch_shapes=[pltpu.VMEM((rows, length), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
+        interpret=interpret,
+        name=scopes.TOPK_KEEP,
+    )(scores)
+    return keep[:, :n, :n]
+
+
+def flash_topk_keep(scores, topk: int, interpret: bool = False):
+    """``ops/attention.py::threshold_keep`` as one Mosaic call
+    (``topk_keep``), a block of queries with their whole rows of keys in
+    VMEM: the int8 (B, S, S) key set that :func:`flash_sparse_attention`
+    reads — query ``t`` keeps exactly the ``min(t + 1, topk)`` causal keys
+    of largest score, equal scores to the lower index.  The scores are read
+    once and the set is written once."""
+    n = scores.shape[1]
+    if topk >= n:
+        pos = jnp.arange(n)
+        return jnp.broadcast_to(pos[:, None] >= pos[None, :],
+                                scores.shape).astype(jnp.int8)
+    return _on_local_batch(
+        functools.partial(_topk_keep_local, topk=topk, interpret=interpret),
+        jax.lax.stop_gradient(scores))
+
+
+#: (queries, keys) of an index-score tile, forward and reverse
+_INDEXER_TILE = (512, 512)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _indexer_kernel(q_ref, k_ref, w_ref, o_ref, *, scale: float):
+    """One tile of ``I = scale · Σ_j w_j ∘ ReLU(q_j · kᵀ)``, float32 at
+    full precision; a tile above the diagonal is left as it is (no causal
+    pair lies there)."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    block_q, block_k = o_ref.shape[1:]
+
+    @pl.when(j * block_k < (i + 1) * block_q)
+    def _tile():
+        k = k_ref[0]
+        acc = jnp.zeros((block_q, block_k), jnp.float32)
+        for h in range(q_ref.shape[1]):
+            s = jax.lax.dot_general(q_ref[0, h], k, _NT, precision=_HIGHEST,
+                                    preferred_element_type=jnp.float32)
+            acc += w_ref[0][:, h:h + 1] * jnp.maximum(s, 0.0)
+        o_ref[0] = acc * scale
+
+
+def _indexer_bwd_kernel(q_ref, k_ref, w_ref, g_ref, dq_ref, dk_ref, dw_ref,
+                        *, scale: float):
+    """One tile of the reverse pass: the products again (full precision, so
+    that a ReLU is open here where it was forward), then ``dw_j += Σ_s g ∘
+    ReLU(s_j)``, ``dS_j = g ∘ w_j ∘ [s_j > 0]``, ``dq_j += dS_j · k`` and
+    ``dk += dS_jᵀ · q_j`` in bfloat16 with float32 sums.  Keys innermost:
+    the query block's dq and dw stay in VMEM over the sweep, and the whole
+    sequence's dk over the call."""
+    i, j = pl.program_id(1), pl.program_id(2)
+    block_q, block_k = g_ref.shape[1:]
+
+    @pl.when(jnp.logical_and(i == 0, j == 0))
+    def _init_keys():
+        dk_ref[0] = jnp.zeros_like(dk_ref[0])
+
+    @pl.when(j == 0)
+    def _init_queries():
+        dq_ref[0] = jnp.zeros_like(dq_ref[0])
+        dw_ref[0] = jnp.zeros_like(dw_ref[0])
+
+    @pl.when(j * block_k < (i + 1) * block_q)
+    def _tile():
+        k = k_ref[0]
+        g = g_ref[0] * scale
+        k16 = k.astype(jnp.bfloat16)
+        rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        dk = jnp.zeros(k.shape, jnp.float32)
+        dw = jnp.zeros(dw_ref.shape[1:], jnp.float32)
+        head = jax.lax.broadcasted_iota(jnp.int32, dw.shape, 1)
+        for h in range(q_ref.shape[1]):
+            q = q_ref[0, h]
+            s = jax.lax.dot_general(q, k, _NT, precision=_HIGHEST,
+                                    preferred_element_type=jnp.float32)
+            dw = jnp.where(head == h, jnp.sum(
+                g * jnp.maximum(s, 0.0), axis=-1, keepdims=True), dw)
+            ds = jnp.where(s > 0.0, g * w_ref[0][:, h:h + 1], 0.0
+                           ).astype(jnp.bfloat16)
+            dq_ref[0, h] += jax.lax.dot_general(
+                ds, k16, _NN, preferred_element_type=jnp.float32)
+            dk += jax.lax.dot_general(
+                ds, q.astype(jnp.bfloat16), _TN,
+                preferred_element_type=jnp.float32)
+        dk_ref[0, rows, :] += dk
+        dw_ref[0] += dw
+
+
+def _indexer_operands(qi, ki, w, block_q: int, block_k: int):
+    """(B, S, J, Di), (B, S, Di), (B, S, J) -> float32, heads first, padded
+    to the tiles."""
+    n = qi.shape[1]
+    nq, nk = pl.cdiv(n, block_q), pl.cdiv(n, block_k)
+    q = _pad_tokens(qi.astype(jnp.float32), nq * block_q).transpose(0, 2, 1, 3)
+    k = _pad_tokens(ki.astype(jnp.float32), nk * block_k)
+    w = _pad_tokens(w.astype(jnp.float32), nq * block_q)
+    return q, k, w, nq, nk
+
+
+def _indexer_specs(q, k, w, block_q: int, block_k: int):
+    _, heads, _, di = q.shape
+
+    def last(i, j):
+        return jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+
+    return [pl.BlockSpec((1, heads, block_q, di),
+                         lambda b_, i, j: (b_, 0, i, 0)),
+            pl.BlockSpec((1, block_k, di),
+                         lambda b_, i, j: (b_, last(i, j), 0)),
+            pl.BlockSpec((1, block_q, heads), lambda b_, i, j: (b_, i, 0))
+            ], last
+
+
+def _indexer_scale(qi) -> float:
+    return 1 / math.sqrt(qi.shape[2] * qi.shape[3])
+
+
+def _indexer_fwd_local(qi, ki, w, *, interpret: bool):
+    n = qi.shape[1]
+    block_q, block_k = (min(t, 128 * pl.cdiv(n, 128)) for t in _INDEXER_TILE)
+    q, k, w, nq, nk = _indexer_operands(qi, ki, w, block_q, block_k)
+    in_specs, _ = _indexer_specs(q, k, w, block_q, block_k)
+    out = pl.pallas_call(
+        functools.partial(_indexer_kernel, scale=_indexer_scale(qi)),
+        grid=(q.shape[0], nq, nk),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, block_q, block_k),
+                               lambda b_, i, j: (b_, i, j)),
+        out_shape=jax.ShapeDtypeStruct((q.shape[0], nq * block_q,
+                                        nk * block_k), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
+        interpret=interpret,
+        name=scopes.INDEXER_SCORES,
+    )(q, k, w)
+    return out[:, :n, :n]
+
+
+def _indexer_bwd_local(qi, ki, w, g, *, interpret: bool):
+    n = qi.shape[1]
+    block_q, block_k = (min(t, 128 * pl.cdiv(n, 128)) for t in _INDEXER_TILE)
+    q, k, wp, nq, nk = _indexer_operands(qi, ki, w, block_q, block_k)
+    in_specs, last = _indexer_specs(q, k, wp, block_q, block_k)
+    g = _pad_keep(g.astype(jnp.float32), nq * block_q, nk * block_k)
+    in_specs.append(pl.BlockSpec((1, block_q, block_k),
+                                 lambda b_, i, j: (b_, i, last(i, j))))
+    dq, dk, dw = pl.pallas_call(
+        functools.partial(_indexer_bwd_kernel, scale=_indexer_scale(qi)),
+        grid=(q.shape[0], nq, nk),
+        in_specs=in_specs,
+        out_specs=[in_specs[0],
+                   pl.BlockSpec((1,) + k.shape[1:], lambda b_, i, j: (b_, 0, 0)),
+                   in_specs[2]],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, jnp.float32)
+                   for x in (q, k, wp)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT),
+        interpret=interpret,
+        name=scopes.INDEXER_SCORES_BWD,
+    )(q, k, wp, g)
+    return (dq.transpose(0, 2, 1, 3)[:, :n].astype(qi.dtype),
+            dk[:, :n].astype(ki.dtype), dw[:, :n].astype(w.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def flash_indexer_scores(qi, ki, w, interpret: bool = False):
+    """``ops/attention.py::indexer_scores`` tile by tile, forward and
+    reverse (``indexer_scores``, ``indexer_scores_bwd``): no (J, S, S) array
+    reaches HBM.  The scores of the pairs above the diagonal are not
+    computed and hold anything (a selector reads the causal pairs), and the
+    reverse pass reads no cotangent there: tile by tile, what the forward
+    call did not write has no gradient."""
+    return _indexer_forward(qi, ki, w, interpret)
+
+
+def _indexer_forward(qi, ki, w, interpret):
+    return _on_local_batch(
+        functools.partial(_indexer_fwd_local, interpret=interpret),
+        qi, ki, w)
+
+
+def _indexer_fwd(qi, ki, w, interpret):
+    return _indexer_forward(qi, ki, w, interpret), (qi, ki, w)
+
+
+def _indexer_bwd(interpret, res, g):
+    return _on_local_batch(
+        functools.partial(_indexer_bwd_local, interpret=interpret), *res, g)
+
+
+flash_indexer_scores.defvjp(_indexer_fwd, _indexer_bwd)
 
 
 # ---------------------------------------------------- channel (gram) branch

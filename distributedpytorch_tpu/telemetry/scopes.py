@@ -55,6 +55,20 @@ CAUSAL_ATTN_BWD = "causal_attn_bwd"
 CAUSAL_ATTN_BWD_FUSED = "causal_attn_bwd_fused"
 CAUSAL_ATTN_BWD_DKV = "causal_attn_bwd_dkv"
 CAUSAL_ATTN_BWD_DQ = "causal_attn_bwd_dq"
+#: a learned sparse attention's Mosaic calls (``models/keye_lm.py``): the
+#: causal kernels given a per-query key set, the head-averaged probabilities
+#: its selector is aligned with, the selector's index scores forward and
+#: reverse, and the exact top-k selection.  Never ``causal_attn…`` nor
+#: ``pam…``: other cells' roofline patterns hold on to those
+SPARSE_ATTN = "sparse_attn"
+SPARSE_ATTN_BWD = "sparse_attn_bwd"
+SPARSE_ATTN_BWD_FUSED = "sparse_attn_bwd_fused"
+SPARSE_ATTN_BWD_DKV = "sparse_attn_bwd_dkv"
+SPARSE_ATTN_BWD_DQ = "sparse_attn_bwd_dq"
+SPARSE_ATTN_PROBS = "sparse_probs"
+INDEXER_SCORES = "indexer_scores"
+INDEXER_SCORES_BWD = "indexer_scores_bwd"
+TOPK_KEEP = "topk_keep"
 CAM_BWD = "cam_bwd"
 CAM_ENERGY = "cam_energy"
 CAM_APPLY = "cam_apply"
@@ -78,6 +92,12 @@ MOE_ROUTED_EXPERTS = "routed_experts"
 MOE_COMBINE = "combine"
 MOE_SHARED_EXPERT = "shared_expert"
 MOE_LATENT = "latent"
+#: the parts of a learned sparse attention block under ``attn/l<i>/``: the
+#: index scores (three products, the ReLU, the sum over heads), the exact
+#: top-k selection (threshold and keep set), the alignment target and its KL
+ATTN_INDEXER = "indexer"
+ATTN_TOPK_SELECT = "topk_select"
+ATTN_INDEX_ALIGN = "index_align"
 TOKEN_LAYERS = (EMBED, MAMBA, ATTN, MOE, MTP, LM_HEAD)
 #: ops of the model that sit in no sub-module (the logits' final upsample)
 MODEL = "model"

@@ -483,8 +483,12 @@ class Trainer:
             accum_steps=cfg.optim.accum_steps, mesh=self.mesh,
             loss_type=self.task.loss_type, state_shardings=st_sh,
             augment=augment,
+            # the image models' capacity MoE takes its weight from the
+            # configuration; any other model states its own beside its
+            # ``loss_weights`` (a token model's alignment loss)
             aux_loss_weight=(cfg.model.moe_aux_weight
-                             if cfg.model.moe_experts else 0.0),
+                             if cfg.model.moe_experts else
+                             getattr(self.model, "aux_loss_weight", 0.0)),
             loss_scale=cfg.optim.loss_scale,
             packbits_masks=cfg.data.packbits_masks,
             sentinel_metrics=sc.enabled and sc.monitor_grads,

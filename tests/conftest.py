@@ -116,14 +116,16 @@ def interpreted_kernels(monkeypatch):
     """Run the Pallas attention kernels in the pallas interpreter for
     tests that reach them through a model: CPU has no Mosaic compiler and
     the kernels take ``interpret`` only as an explicit argument.  The
-    DANet modules and the token model's ``Attention`` look the entry points
-    up at call time, so patching the module attributes is enough."""
+    DANet modules and the token models' attention blocks look the entry
+    points up at call time, so patching the module attributes is enough."""
     import functools
 
     from distributedpytorch_tpu.ops import pallas_attention as pa
 
     for name in ("flash_position_attention", "flash_channel_attention",
-                 "flash_causal_attention"):
+                 "flash_causal_attention", "flash_sparse_attention",
+                 "flash_head_mean_probs", "flash_indexer_scores",
+                 "flash_topk_keep"):
         monkeypatch.setattr(
             pa, name, functools.partial(getattr(pa, name), interpret=True))
 

@@ -355,3 +355,101 @@ def test_causal_attention_calls_resolve_to_their_block(v5e_mesh, monkeypatch):
     assert all(pattern("attn_kernel_roofline").search(e) for e in events)
     for metric in ("pam_kernel_roofline", "pam_backward_kernel_roofline"):
         assert not any(pattern(metric).search(e) for e in events)
+
+
+def test_sparse_attention_block_compiles_at_the_cells_shape(v5e_mesh,
+                                                            monkeypatch):
+    """``keye_lm``'s attention block with the learned sparse attention's
+    Mosaic calls live, forward and reverse under ``nn.remat``'s policy, in
+    bfloat16 over 1 x 8,192 tokens at the published widths (32 query heads
+    to 4 key/value heads of 128, an indexer of 16 heads of 64, top-2,048):
+    the index scores and the head-averaged probabilities at most twice (the
+    replay recomputes them for the loss's reverse pass), the scores' reverse,
+    ONE selection and ONE forward call (the key set, the output and the
+    log-sum-exp are kept), the fused reverse call; no array of (heads, S, S)
+    of any dtype; the scope table puts every call under ``attn`` and the
+    part that a metric reads alone."""
+    import json
+    import os
+
+    from flax import linen as nn
+
+    from distributedpytorch_tpu.models import keye_lm as kl
+
+    monkeypatch.setattr(danet_mod, "_on_tpu", lambda: True)
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "keye_vl2_30b_a3b_lm_stage_ep8.json")) as f:
+        cfg = kl.LMConfig.from_dict(json.load(f))
+    assert (cfg.q_heads, cfg.kv_heads, cfg.head_dim, cfg.index_heads,
+            cfg.index_head_dim, cfg.topk) == (32, 4, 128, 16, 64, 2048)
+    one = _one_chip(v5e_mesh)
+    seq = 8192
+
+    class Block(nn.Module):
+        @nn.compact
+        def __call__(self, u):
+            with jax.named_scope(scopes.ATTN):
+                return nn.remat(
+                    kl.SparseAttention, policy=kl._KEEP_SPARSE_RESIDUALS)(
+                        cfg, jnp.bfloat16, name="l00")(u)
+
+    layer = Block()
+    u = jax.ShapeDtypeStruct((1, seq, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, cfg.hidden_size),
+                                             jnp.bfloat16)))["params"])
+
+    def loss(p, v):
+        out, sown = layer.apply({"params": p}, v,
+                                mutable=["losses", "counters"])
+        return out.astype(jnp.float32).sum() + sum(
+            x.sum() for x in jax.tree.leaves(sown["losses"]))
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, u).compile().as_text()
+    names = sorted(ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
+                   for ln in _custom_calls(hlo))
+    assert set(names) == {
+        scopes.INDEXER_SCORES, scopes.INDEXER_SCORES_BWD, scopes.TOPK_KEEP,
+        scopes.SPARSE_ATTN, scopes.SPARSE_ATTN_PROBS,
+        scopes.SPARSE_ATTN_BWD_FUSED}, names
+    for once in (scopes.TOPK_KEEP, scopes.SPARSE_ATTN,
+                 scopes.SPARSE_ATTN_BWD_FUSED, scopes.INDEXER_SCORES_BWD):
+        assert names.count(once) == 1, names
+    # (the compiler may merge the replay's second call of either into the
+    # first: same operands)
+    assert names.count(scopes.INDEXER_SCORES) <= 2
+    assert names.count(scopes.SPARSE_ATTN_PROBS) <= 2
+    assert not re.search(rf"\[(\d+,)*\d+,{seq},{seq}\]", hlo.replace(
+        f"[1,{seq},{seq}]", "[pairs]"))
+    table = scopes.scope_table(hlo)
+    parts = {}
+    for ln in _custom_calls(hlo):
+        call = ln.split(" = ")[0].strip().lstrip("%")
+        s = table[call]
+        assert s.layer == scopes.ATTN and s.path.startswith("attn/l00"), s
+        parts.setdefault(call.split(".")[0], set()).update(
+            s.path.split("/")[2:-1] or [""])
+    assert scopes.ATTN_INDEXER in parts[scopes.INDEXER_SCORES]
+    assert scopes.ATTN_TOPK_SELECT in parts[scopes.TOPK_KEEP]
+    assert scopes.ATTN_INDEX_ALIGN in parts[scopes.SPARSE_ATTN_PROBS]
+    # the benchmark's patterns: each roofline metric reads its own calls
+    for metric, n in (
+            ("sparse_attn_kernel_roofline", 2),
+            ("sparse_probs_kernel_roofline",
+             names.count(scopes.SPARSE_ATTN_PROBS)),
+            ("indexer_scores_kernel_roofline",
+             names.count(scopes.INDEXER_SCORES)),
+            ("indexer_scores_bwd_kernel_roofline", 1),
+            ("topk_keep_kernel_roofline", 1),
+            ("attn_kernel_roofline", 0), ("pam_kernel_roofline", 0)):
+        with open(os.path.join(here, "benchmarks", "metrics",
+                               metric + ".json")) as f:
+            rx = re.compile(json.load(f)["args"]["event_pattern"])
+        events = [ln.split(" = ")[0].strip() + " custom-call"
+                  for ln in _custom_calls(hlo)]
+        assert sum(bool(rx.search(e)) for e in events) == n, metric
