@@ -32,7 +32,11 @@ selection, the causal flash kernels masked by the key set, and the
 head-averaged probabilities — no array of (heads, S, S) reaches HBM.
 Otherwise the einsum / ``top_k`` forms of ``ops/attention.py`` run.  A
 rematerialised block keeps the forward call's output and log-sum-exp and
-the key set, so its reverse pass runs no second forward call or selection.
+the key set, so its reverse pass runs no second forward call or selection;
+and it keeps the alignment loss's gradient with respect to the indexer's
+three operands (:func:`index_align_loss` takes it in the forward pass,
+where the scores and the target are alive: 36 MB a layer at 8,192
+positions), so its reverse pass recomputes neither of those (S, S) arrays.
 
 The counts of experts and vocabulary rows in the configuration are what
 this chip HOLDS of a stated deployment; widths are never cut.  Parameters
@@ -40,7 +44,8 @@ are float32 masters; the indexer, the router and every softmax are float32
 whatever the compute dtype.
 
 Scopes: blocks ``l00, l01, …`` alternate ``attn`` / ``moe`` (block ``2i`` is
-layer ``i``'s attention); inside ``attn/l<k>/``: ``indexer``,
+layer ``i``'s attention); inside ``attn/l<k>/``: ``indexer`` (the index
+scores' reverse call too, which runs in the forward phase),
 ``topk_select``, ``index_align``; inside ``moe/l<k>/``: ``router``,
 ``dispatch``, ``routed_experts``, ``combine``.
 """
@@ -48,6 +53,7 @@ layer ``i``'s attention); inside ``attn/l<k>/``: ``indexer``,
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -203,14 +209,72 @@ def _dot32(x, w):
     return jnp.dot(x.astype(F32), w, precision=HIGHEST)
 
 
+def _log_softmax_over(scores, keep):
+    return jax.nn.log_softmax(jnp.where(keep, scores, -jnp.inf), axis=-1)
+
+
 def index_align_kl(p, scores, keep):
     """Mean over the queries of ``KL(p[t, ·] ‖ softmax over the key set of
     scores[t, ·])``; ``p`` is zero outside the set and sums to one on it."""
-    logq = jax.nn.log_softmax(jnp.where(keep, scores, -jnp.inf), axis=-1)
+    return _kl_to(p, _log_softmax_over(scores, keep), keep)
+
+
+def _kl_to(p, logq, keep):
     live = keep & (p > 0)
     terms = jnp.where(live, p * (jnp.log(jnp.where(live, p, 1.0))
                                  - jnp.where(live, logq, 0.0)), 0.0)
     return terms.sum(-1).mean()
+
+
+#: ``checkpoint_name``s of the alignment loss's gradient with respect to the
+#: indexer's operands (float32: S x 16 x 64, S x 64 and S x 16 a sequence).
+#: They are all that the loss's reverse pass reads, so a block that keeps
+#: them recomputes neither the index scores nor the target
+INDEX_GRADS_KEPT = ("index_align_dq", "index_align_dk", "index_align_dw")
+
+
+def _einsum_scores_grads(qi, ki, wi, g):
+    """``ops/attention.py::indexer_scores``'s reverse pass at cotangent
+    ``g``: what ``flash_indexer_scores_grads`` is to the kernel."""
+    return jax.vjp(attention_ops.indexer_scores, qi, ki, wi)[1](g)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def index_align_loss(scores_grads, qi, ki, wi, scores, p, keep):
+    """:func:`index_align_kl` of the indexer's ``scores`` of ``qi, ki, wi``,
+    differentiable in those three alone: ``scores`` (computed for the
+    selection), the target ``p`` and the key set ``keep`` are constants.
+
+    A loss is a scalar, so its gradient is known, up to the scalar that the
+    reverse pass brings, when the loss is: differentiated, this takes the
+    gradient through the scores in the **forward** pass (``scores_grads(qi,
+    ki, wi, g) -> (dqi, dki, dwi)``, the scores' reverse pass: ``ops/
+    pallas_attention.py::flash_indexer_scores_grads`` or the einsum form's)
+    and keeps that, named :data:`INDEX_GRADS_KEPT`; the reverse pass scales
+    three small arrays.  Not differentiated, it computes the loss only."""
+    with jax.named_scope(scopes.ATTN_INDEX_ALIGN):
+        return index_align_kl(p, scores, keep)
+
+
+def _index_align_fwd(scores_grads, qi, ki, wi, scores, p, keep):
+    with jax.named_scope(scopes.ATTN_INDEX_ALIGN):
+        logq = _log_softmax_over(scores, keep)
+        loss = _kl_to(p, logq, keep)
+        # d(sum of the rows' KL) / d(scores): the mean's 1 / rows waits for
+        # the reverse pass, so that the reverse call's bfloat16 products see
+        # values of a probability's size
+        d = jnp.where(keep, jnp.exp(logq) - p, 0.0)
+    with jax.named_scope(scopes.ATTN_INDEXER):
+        grads = scores_grads(qi, ki, wi, d)
+    return loss, tuple(map(checkpoint_name, grads, INDEX_GRADS_KEPT))
+
+
+def _index_align_bwd(scores_grads, grads, g):
+    g = g / math.prod(grads[2].shape[:2])   # the mean over B x S queries
+    return (*(g * x for x in grads), None, None, None)
+
+
+index_align_loss.defvjp(_index_align_fwd, _index_align_bwd)
 
 
 # ------------------------------------------------------------------ blocks
@@ -261,12 +325,14 @@ class SparseAttention(nn.Module):
             ki = rotate((ki * index_k_scale + index_k_bias)[:, :, None, :],
                         ang_i)[:, :, 0]
             wi = _dot32(xi, index_w)
-            scores = (pallas_attention.flash_indexer_scores if flash
-                      else attention_ops.indexer_scores)(qi, ki, wi)
+            # a constant: no gradient passes the selection, and the
+            # alignment loss takes its own through the scores
+            scores = jax.lax.stop_gradient(
+                (pallas_attention.flash_indexer_scores if flash
+                 else attention_ops.indexer_scores)(qi, ki, wi))
         with jax.named_scope(scopes.ATTN_TOPK_SELECT):
-            picked = jax.lax.stop_gradient(scores)
-            keep = (pallas_attention.flash_topk_keep(picked, c.topk) if flash
-                    else attention_ops.topk_keep(picked, c.topk))
+            keep = (pallas_attention.flash_topk_keep(scores, c.topk) if flash
+                    else attention_ops.topk_keep(scores, c.topk))
             keep = checkpoint_name(
                 keep, pallas_attention.SPARSE_KEPT_BY_REVERSE[2])
         if flash:
@@ -280,9 +346,6 @@ class SparseAttention(nn.Module):
             else:
                 p = jax.lax.stop_gradient(
                     attention_ops.head_mean_probs(q, k, keep))
-            align = index_align_kl(p, scores, keep)
-            # the mean over layers: each layer sows its share
-            self.sow("losses", INDEX_ALIGN_LOSS, align / c.layers)
             kept = keep.sum(-1, dtype=jnp.int32)              # (B, S)
             want = attention_ops.keys_wanted(length, c.topk)
             self.sow(counters.COLLECTION, COUNTER_KEPT_SHARE,
@@ -290,6 +353,13 @@ class SparseAttention(nn.Module):
                      / (b * length * (length + 1) / 2))
             self.sow(counters.COLLECTION, COUNTER_OVER_TOPK,
                      jnp.maximum(kept - want, 0).sum().astype(F32))
+        # names its own parts: index_align, and indexer around the scores'
+        # reverse call
+        align = index_align_loss(
+            pallas_attention.flash_indexer_scores_grads if flash
+            else _einsum_scores_grads, qi, ki, wi, scores, p, keep)
+        # the mean over layers: each layer sows its share
+        self.sow("losses", INDEX_ALIGN_LOSS, align / c.layers)
         out = out.reshape(b, length, qh * hd)
         return u + _dot(out, o_proj, self.dtype, out=u.dtype)
 
@@ -331,7 +401,7 @@ class GatedMoE(nn.Module):
 
 
 _KEEP_SPARSE_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
-    *pallas_attention.SPARSE_KEPT_BY_REVERSE)
+    *pallas_attention.SPARSE_KEPT_BY_REVERSE, *INDEX_GRADS_KEPT)
 
 
 class KeyeLM(nn.Module):
@@ -368,7 +438,8 @@ class KeyeLM(nn.Module):
         attn, moe = SparseAttention, GatedMoE
         if self.remat:
             # per block; an attention block keeps what its reverse pass
-            # reads of the forward call, and the key set
+            # reads of the forward call, the key set, and the alignment
+            # loss's gradient with respect to the indexer's operands
             attn = nn.remat(attn, policy=_KEEP_SPARSE_RESIDUALS)
             moe = nn.remat(moe)
         for i in range(c.layers):
@@ -386,7 +457,8 @@ class KeyeLM(nn.Module):
         """A bound on the step's live activations on one device, for the
         planner's memory model (``parallel/plan.py``): what per-block
         recomputation keeps (every block's input; an attention block's
-        output, log-sum-exp and key set), the largest single block while it
+        output, log-sum-exp and key set, and the index scores' float32
+        gradients), the largest single block while it
         is recomputed and differentiated, and the head's float32 logits
         with their gradient."""
         c = self.cfg
@@ -396,6 +468,9 @@ class KeyeLM(nn.Module):
         kept = (2 * c.layers + 2) * t * c.hidden_size * item
         if not self.remat:
             kept *= 8
+        kept += c.layers * t * 4 * (
+            c.index_heads * c.index_head_dim + c.index_head_dim
+            + c.index_heads)
         if danet.auto_wants_flash(self.dtype):
             kept += c.layers * (t * c.q_heads * c.head_dim * item + pairs)
             # index scores, their gradient and the target (float32), the

@@ -664,7 +664,10 @@ def flash_causal_attention(q, k, v, interpret: bool = False):
 #: ``checkpoint_name``s of what a sparse attention block keeps across its
 #: recomputation: the forward call's output and log-sum-exp (as
 #: :data:`KEPT_BY_REVERSE`) and the key set, so that the reverse pass runs
-#: neither a second forward call nor a second selection
+#: neither a second forward call nor a second selection.  The block's model
+#: keeps the index scores' gradients beside them, under names of its own
+#: (``models/keye_lm.py::INDEX_GRADS_KEPT``): nothing in the reverse pass
+#: then reads an (S, S) array but the key set
 SPARSE_KEPT_BY_REVERSE = ("sparse_attn_out", "sparse_attn_lse",
                           "sparse_attn_keep")
 
@@ -1116,6 +1119,13 @@ def _indexer_bwd(interpret, res, g):
 
 
 flash_indexer_scores.defvjp(_indexer_fwd, _indexer_bwd)
+
+
+def flash_indexer_scores_grads(qi, ki, w, g, interpret: bool = False):
+    """:func:`flash_indexer_scores`'s reverse call (``indexer_scores_bwd``)
+    for a caller that holds the scores' cotangent ``g`` (B, S, S) already:
+    ``(dqi, dki, dw)``, no forward call."""
+    return _indexer_bwd(interpret, (qi, ki, w), g)
 
 
 # ---------------------------------------------------- channel (gram) branch

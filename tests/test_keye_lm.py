@@ -485,6 +485,154 @@ def test_kernel_path_gives_the_einsum_models_loss_and_gradients(
                        indexer_rtol=BF16_GRAD_RTOL)
 
 
+# ------------- the alignment loss takes its gradient in the forward pass
+ALIGN_FORMS = {
+    "einsum": kl._einsum_scores_grads,
+    "kernel": functools.partial(pa.flash_indexer_scores_grads,
+                                interpret=True)}
+
+
+def align_case(s, topk, seed=0):
+    """The indexer's operands, its scores, a key set and a target that sums
+    to one on the set and is zero on a third of it."""
+    r = np.random.RandomState(seed)
+    qi = jnp.asarray(r.randn(2, s, 4, 8).astype(np.float32))
+    ki = jnp.asarray(r.randn(2, s, 8).astype(np.float32))
+    wi = jnp.asarray(r.randn(2, s, 4).astype(np.float32))
+    scores = attention_ops.indexer_scores(qi, ki, wi)
+    keep = attention_ops.topk_keep(scores, topk)
+    p = jnp.where(keep & jnp.asarray(r.rand(2, s, s) > 1 / 3)
+                  | jnp.eye(s, dtype=bool), jnp.asarray(r.rand(2, s, s)), 0)
+    p = jnp.where(keep, p, 0).astype(jnp.float32)
+    return qi, ki, wi, scores, p / p.sum(-1, keepdims=True), keep
+
+
+@pytest.mark.parametrize("form", sorted(ALIGN_FORMS))
+@pytest.mark.parametrize("s,topk", [(64, 9), (300, 77)])
+def test_alignment_loss_is_the_kl_and_its_gradient(small_tiles, form, s,
+                                                   topk):
+    """``index_align_loss`` is ``index_align_kl`` of the scores, to the bit,
+    and its gradient that of ``index_align_kl ∘ indexer_scores``; the
+    cotangent only scales it."""
+    qi, ki, wi, scores, p, keep = align_case(s, topk, seed=s)
+    grads_of = ALIGN_FORMS[form]
+
+    def loss(qi, ki, wi, weight=1.0):
+        return weight * kl.index_align_loss(grads_of, qi, ki, wi, scores, p,
+                                            keep)
+
+    def plain(qi, ki, wi):
+        return kl.index_align_kl(
+            p, attention_ops.indexer_scores(qi, ki, wi), keep)
+
+    want = kl.index_align_kl(p, scores, keep)
+    assert float(want) > 0.1
+    np.testing.assert_array_equal(loss(qi, ki, wi), want)
+    value, got = jax.value_and_grad(loss, (0, 1, 2))(qi, ki, wi)
+    np.testing.assert_array_equal(value, want)
+    wants = jax.grad(plain, (0, 1, 2))(qi, ki, wi)
+    tols = (GRAD_RTOL,) * 3 if form == "einsum" else (
+        BF16_GRAD_RTOL, BF16_GRAD_RTOL, GRAD_RTOL)
+    for a, b, tol in zip(got, wants, tols):
+        assert float(jnp.abs(b).max()) > 0 and rel_gap(a, b) <= tol
+    none = jax.grad(loss, (0, 1, 2))(qi, ki, wi, 0.0)
+    thrice = jax.grad(loss, (0, 1, 2))(qi, ki, wi, 3.0)
+    for g0, g1, g3 in zip(none, got, thrice):
+        assert float(jnp.abs(g0).max()) == 0
+        assert rel_gap(g3, 3 * g1) <= 1e-5
+
+
+def eqns_named(jaxpr, primitive):
+    """Every equation of ``primitive`` in ``jaxpr``, sub-jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == primitive:
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += eqns_named(sub, primitive)
+    return found
+
+
+def calls_named(text, name):
+    return text.count(f"name={name}\n") + text.count(f"name={name} ")
+
+
+@pytest.fixture(params=["einsum", "kernel"])
+def remat_block(request, monkeypatch):
+    """A rematerialised attention block as ``KeyeLM`` wraps it, its
+    parameters and input, a loss over its output and what it sowed, and the
+    calls of the index scores' reverse pass that tracing it made."""
+    from flax import linen as nn
+
+    if request.param == "kernel":
+        request.getfixturevalue("small_tiles")
+        request.getfixturevalue("sparse_kernels")
+        owner, name = pa, "flash_indexer_scores_grads"
+    else:
+        owner, name = kl, "_einsum_scores_grads"
+    calls, fn = [], getattr(owner, name)
+    monkeypatch.setattr(owner, name, lambda *a, **kw: (
+        calls.append(name), fn(*a, **kw))[1])
+    cfg = kl.LMConfig.from_dict(tiny())
+    block = nn.remat(kl.SparseAttention, policy=kl._KEEP_SPARSE_RESIDUALS)(
+        cfg, jnp.float32)
+    # 48 positions: no width of the preset, so a shape names its array
+    u = jax.random.normal(jax.random.PRNGKey(2), (2, 48, 64))
+    params = block.init(jax.random.PRNGKey(3), u)["params"]
+    del calls[:]
+
+    def loss(params, u):
+        out, sown = block.apply({"params": params}, u,
+                                mutable=["losses", "counters"])
+        return (out ** 2).sum() + sum(
+            x.sum() for x in jax.tree.leaves(sown["losses"]))
+
+    return request.param, loss, params, u, calls
+
+
+def test_replay_recomputes_neither_scores_nor_target(remat_block):
+    """The gradient of a rematerialised block holds each Mosaic call once,
+    and the replay inside it no index score, no target and no softmax over
+    an (S, S) array: what the alignment loss's reverse pass reads was kept."""
+    form, loss, params, u, calls = remat_block
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(params, u)
+    assert len(calls) == 1
+    replay, = eqns_named(jaxpr.jaxpr, "remat2")
+    assert replay.params["differentiated"]
+    inside, whole = str(replay.params["jaxpr"]), str(jaxpr)
+    if form == "kernel":
+        for name in (scopes.INDEXER_SCORES, scopes.SPARSE_ATTN_PROBS,
+                     scopes.INDEXER_SCORES_BWD, scopes.TOPK_KEEP,
+                     scopes.SPARSE_ATTN, scopes.SPARSE_ATTN_BWD_FUSED):
+            assert calls_named(whole, name) == 1, name
+            assert calls_named(inside, name) == (
+                name == scopes.SPARSE_ATTN_BWD_FUSED), name
+        assert "f32[2,48,48]" in whole and "f32[2,48,48]" not in inside
+    else:   # the indexer's (B, J, S, S) products, and the selection's sort
+        assert "f32[2,4,48,48]" in whole and "top_k" in whole
+        assert "f32[2,4,48,48]" not in inside and "top_k" not in inside
+    assert "log_softmax" in whole and "log_softmax" not in inside
+    for name in kl.INDEX_GRADS_KEPT:
+        assert whole.count(f"name[name={name}]") == 1
+        assert f"name[name={name}]" not in inside
+
+
+def test_a_call_that_is_not_differentiated_runs_no_reverse_pass(
+        remat_block, whole):
+    form, loss, params, u, calls = remat_block
+    text = str(jax.make_jaxpr(loss)(params, u))
+    assert calls == [] and scopes.INDEXER_SCORES_BWD not in text
+    if form == "kernel":
+        assert calls_named(text, scopes.INDEXER_SCORES) == 1
+    cfg, model, weights, tokens = whole
+    text = str(jax.make_jaxpr(lambda p: model.apply(
+        {"params": p}, tokens, mutable=["losses", "counters"]))(weights))
+    assert calls == [] and scopes.INDEXER_SCORES_BWD not in text
+    jax.make_jaxpr(jax.grad(lambda p: program_loss(model, p, tokens)))(
+        weights)
+    assert len(calls) == cfg["num_hidden_layers"]
+
+
 def test_existing_calls_keep_their_kernels():
     """DANet's and the causal calls trace to the kernels they had: no key
     set among their operands, their names unchanged."""
@@ -511,8 +659,10 @@ def test_scope_table_holds_the_new_parts(whole):
     for part in (scopes.ATTN_INDEXER, scopes.ATTN_TOPK_SELECT,
                  scopes.ATTN_INDEX_ALIGN):
         assert (scopes.ATTN, part, "fwd") in parts, part
-    for part in (scopes.ATTN_INDEXER, scopes.ATTN_INDEX_ALIGN):
-        assert (scopes.ATTN, part, "bwd") in parts, part
+    # the alignment loss's reverse pass scales three kept arrays, inside
+    # the fusions of the indexer's: no instruction of its own
+    assert (scopes.ATTN, scopes.ATTN_INDEXER, "bwd") in parts
+    assert (scopes.ATTN, scopes.ATTN_INDEX_ALIGN, "bwd") not in parts
     for part in (scopes.MOE_ROUTER, scopes.MOE_DISPATCH,
                  scopes.MOE_ROUTED_EXPERTS, scopes.MOE_COMBINE):
         assert (scopes.MOE, part, "fwd") in parts, part
@@ -675,6 +825,15 @@ def test_activation_bytes_follow_the_attention_form_that_runs(monkeypatch):
     # the einsum forms hold 48 heads' float32 (8192, 8192) arrays
     assert einsum > 3 * 48 * 8192 * 8192 * 4 > 8 * flash
     assert 1e9 < flash < 6e9
+    # what a block keeps of the alignment loss: the float32 gradients of the
+    # indexer's queries, key and head weights, every layer's
+    wider = dict(_cell_config())
+    wider["sa_config"] = dict(wider["sa_config"], indexer_num_heads=24)
+    more = kl.build_keye_lm(wider, dtype=jnp.bfloat16).activation_bytes(
+        1, 8192)
+    layers = model.cfg.layers
+    assert more - flash == layers * 8192 * 8 * (64 + 1) * 4
+    assert flash > layers * 8192 * (16 * 64 + 64 + 16) * 4 > 0.25e9
 
 
 @pytest.mark.parametrize("expected,want", [(8192, 5120), (4096, 5120),
