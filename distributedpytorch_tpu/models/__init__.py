@@ -22,6 +22,7 @@ from .keye_lm import KeyeLM, build_keye_lm
 from .nemotron_h import NemotronH, build_nemotron_h
 from .pspnet import PSPNet, PyramidPooling
 from .resnet import ResNet, resnet50, resnet101
+from .sdar_lm import SdarLM, build_sdar_lm
 
 #: the tasks (``Config.task``) a model trains under, by its ``build_model``
 #: name; a name not listed is a segmentation net.  What the trainer checks a
@@ -31,7 +32,8 @@ SEGMENTATION_TASKS = ("instance", "semantic")
 #: ``build_model``, :data:`MODEL_TASKS` and the ``lm_config`` error read
 #: this table; a new token model is one entry
 TOKEN_MODELS = {"nemotron_h": (build_nemotron_h, ("tokens",)),
-                "keye_lm": (build_keye_lm, ("tokens",))}
+                "keye_lm": (build_keye_lm, ("tokens",)),
+                "sdar_lm": (build_sdar_lm, ("tokens",))}
 MODEL_TASKS = {name: tasks for name, (_, tasks) in TOKEN_MODELS.items()}
 
 
@@ -54,7 +56,7 @@ def build_model(
     **kw,
 ):
     """Construct a segmentation model by name — or, by a name of
-    :data:`TOKEN_MODELS` (``nemotron_h``, ``keye_lm``), a token model of the
+    :data:`TOKEN_MODELS` (``nemotron_h``, ``keye_lm``, ``sdar_lm``), a token model of the
     ``tokens`` task (``lm_config``: a preset's name, a JSON file of the
     published keys, or that dict; ``remat``; the image options do not apply
     to it).
@@ -238,6 +240,7 @@ __all__ = [
     "PSPNet",
     "PyramidPooling",
     "ResNet",
+    "SdarLM",
     "build_from_config",
     "build_model",
     "resnet50",

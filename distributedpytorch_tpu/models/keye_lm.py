@@ -391,7 +391,8 @@ class GatedMoE(nn.Module):
         # names its own parts: dispatch / routed_experts / combine
         routed, chunks_run = moe_lib.dropless_routed(
             x, weights, w1, w2, disp, swiglu,
-            chunk_rows=expert_chunk_rows(
+            chunk_rows=getattr(c, "expert_chunk_rows", None)
+            or expert_chunk_rows(
                 x.shape[0] * c.experts_per_token * held / c.experts_total))
         self.sow(counters.COLLECTION, moe_lib.COUNTER_DROPPED, disp.dropped)
         self.sow(counters.COLLECTION, moe_lib.COUNTER_LOAD,

@@ -8,14 +8,17 @@ path).
 """
 
 from . import augment
+from . import diffusion
 from . import guidance_device
 from .attention import (
     position_attention,
     blocked_position_attention,
     channel_attention,
     causal_attention,
+    block_diffusion_attention,
 )
 from .pallas_attention import (
+    flash_block_diffusion_attention,
     flash_causal_attention,
     flash_channel_attention,
     flash_position_attention,
@@ -26,6 +29,7 @@ from .losses import (
     next_token_xent,
     se_presence_loss,
     softmax_xent_ignore,
+    weighted_token_xent,
 )
 from .metrics import (
     batched_jaccard,
@@ -38,11 +42,14 @@ from .warp import fullres_argmax, resize_bilinear_ragged
 
 __all__ = [
     "augment",
+    "diffusion",
     "guidance_device",
     "position_attention",
     "blocked_position_attention",
     "channel_attention",
     "causal_attention",
+    "block_diffusion_attention",
+    "flash_block_diffusion_attention",
     "flash_causal_attention",
     "flash_channel_attention",
     "flash_position_attention",
@@ -51,6 +58,7 @@ __all__ = [
     "next_token_xent",
     "se_presence_loss",
     "softmax_xent_ignore",
+    "weighted_token_xent",
     "jaccard",
     "batched_jaccard",
     "confusion_matrix",

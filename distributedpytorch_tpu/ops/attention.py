@@ -21,6 +21,15 @@ causal, grouped-query, scaled by 1/sqrt(head_dim), heads kept apart.
 :func:`indexer_scores` with :func:`topk_keep` / :func:`threshold_keep` are
 the learned selector that picks the set (``models/keye_lm.py``).
 
+The token layers differ by a **mask rule** — which (query, key) pairs
+attend, from positions alone — and this module holds each rule's whole-array
+form, the kernels' oracle and the path off the TPU: none
+(:func:`position_attention`: every pair), causal (:func:`causal_attention`:
+``s <= t``, optionally cut to a key set), block diffusion
+(:func:`block_diffusion_mask`, :func:`block_diffusion_attention`: a doubled
+sequence ``[clean ‖ noised]``, ``models/sdar_lm.py``).
+``ops/pallas_attention.py::MaskRule`` is the same three as tile rules.
+
 Layouts: spatial features are (B, N, C) token-major — N = H*W spatial tokens —
 the natural NHWC flattening.  Scores accumulate in float32 regardless of input
 dtype (bf16-safe softmax).
@@ -153,6 +162,52 @@ def _causal_scores(q, k, keep=None):
     return jnp.where(seen, sc, -jnp.inf)
 
 
+def _attend(sc, v, b, length, dtype):
+    """Softmax over the keys of grouped scores (B, G, R, S, S), float32, and
+    the probabilities' product with the values: (B, S, Hq, D)."""
+    w = jax.nn.softmax(sc, axis=-1).astype(dtype)
+    out = jnp.einsum("bgrqk,bkgd->bqgrd", w, v,
+                     preferred_element_type=jnp.float32)
+    return out.astype(dtype).reshape(b, length, -1, v.shape[-1])
+
+
+def block_diffusion_mask(length: int, block: int) -> jax.Array:
+    """bool (2·length, 2·length): which key ``s`` (columns) a query ``t``
+    (rows) of a doubled sequence ``[clean ‖ noised]`` attends under a
+    block-diffusion objective.  With ``c(p) = p < length`` and ``B(p) = (p
+    mod length) // block``: ``c(s) & c(t) & B(s) <= B(t)`` (the clean copy,
+    block-causal), or ``c(s) & ~c(t) & B(s) < B(t)`` (a noised block sees
+    the clean blocks before its own), or ``~c(s) & ~c(t) & B(s) = B(t)`` (a
+    noised block sees itself, both directions): ``length² + length·block``
+    pairs."""
+    if length % block:
+        raise ValueError(f"blocks of {block} do not tile a sequence of "
+                         f"{length}")
+    pos = jnp.arange(2 * length)
+    clean = pos < length
+    blk = (pos % length) // block
+    ct, cs = clean[:, None], clean[None, :]
+    bt, bs = blk[:, None], blk[None, :]
+    return (cs & ct & (bs <= bt)) | (cs & ~ct & (bs < bt)) \
+        | (~cs & ~ct & (bs == bt))
+
+
+def block_diffusion_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                              length: int, block: int) -> jax.Array:
+    """Grouped-query attention over ``[clean ‖ noised]`` (2·``length``
+    positions) under :func:`block_diffusion_mask`, scores scaled by
+    1/sqrt(head_dim), softmax in float32; shapes as
+    :func:`causal_attention`.  The (B, Hq, 2L, 2L) scores are a whole array
+    here; ``ops/pallas_attention.py::flash_block_diffusion_attention`` is the
+    same mathematics tile by tile."""
+    b, s, qh, hd = q.shape
+    kvh = k.shape[2]
+    sc = jnp.einsum("bqgrd,bkgd->bgrqk", q.reshape(b, s, kvh, qh // kvh, hd),
+                    k, preferred_element_type=jnp.float32) / math.sqrt(hd)
+    sc = jnp.where(block_diffusion_mask(length, block), sc, -jnp.inf)
+    return _attend(sc, v, b, s, q.dtype)
+
+
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      keep: jax.Array | None = None) -> jax.Array:
     """Causal grouped-query attention, scores scaled by 1/sqrt(head_dim).
@@ -170,10 +225,7 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     kvh = k.shape[2]
     dtype = q.dtype
     sc = _causal_scores(q.reshape(b, length, kvh, qh // kvh, hd), k, keep)
-    w = jax.nn.softmax(sc, axis=-1).astype(dtype)
-    out = jnp.einsum("bgrqk,bkgd->bqgrd", w, v,
-                     preferred_element_type=jnp.float32)
-    return out.astype(dtype).reshape(b, length, qh, hd)
+    return _attend(sc, v, b, length, dtype)
 
 
 def head_mean_probs(q: jax.Array, k: jax.Array,

@@ -154,3 +154,15 @@ def next_token_xent(logits: jax.Array, tokens: jax.Array,
     valid = (jnp.arange(length) < length - shift)[None, :]
     return jnp.sum(jnp.where(valid, nll, 0.0)) / (
         tokens.shape[0] * (length - shift))
+
+
+def weighted_token_xent(logits: jax.Array, tokens: jax.Array,
+                        weights: jax.Array) -> jax.Array:
+    """``(1 / (B·L)) Σ_i weights_i · (−log softmax(logits_i)[tokens_i])`` in
+    float32, no shift: position ``i`` is scored against token ``i`` (a
+    masked-diffusion objective: the weight is 0 on a token left clean and
+    the inverse of its block's noise level on a masked one,
+    ``ops/diffusion.py``)."""
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    nll = -jnp.take_along_axis(logp, tokens[..., None], axis=-1)[..., 0]
+    return jnp.sum(weights.astype(jnp.float32) * nll) / nll.size

@@ -18,7 +18,9 @@ the off-TPU fallback:
   Blocks default 256×256, aligned to the (8,128) f32 tile.
 * :func:`flash_causal_attention` — the same kernels, forward and reverse,
   as a token model's ``*`` layer asks for them (``models/nemotron_h.py``):
-  ``scale`` 1/√head_dim; ``causal`` — a query sees the keys at or before
+  ``scale`` 1/√head_dim; a **mask rule** (:class:`MaskRule`: which tiles
+  run, and the mask inside a tile, from positions alone) — under
+  :data:`CAUSAL` a query sees the keys at or before
   it, a tile wholly above the diagonal is neither computed (``pl.when``)
   nor fetched (its block index stays on the last tile that ran), and only
   a tile that crosses the diagonal pays for the mask; ``group`` — heads lie
@@ -26,8 +28,12 @@ the off-TPU fallback:
   read their one key/value head through the index map (nothing is repeated
   in HBM; the reverse pass writes one float32 dK, dV per query head, summed
   over the group in XLA).  What differs from DANet's calls is static at
-  trace time; with ``causal=False`` and ``group=1`` the kernels' Mosaic
+  trace time; with :data:`NO_MASK` and ``group=1`` the kernels' Mosaic
   modules are DANet's, op for op.
+* :func:`flash_block_diffusion_attention` — the same kernels under the rule
+  :class:`BlockDiffusion` (``models/sdar_lm.py``): a doubled sequence
+  ``[clean ‖ noised]``, block-causal in the clean copy, a noised block
+  seeing the clean blocks before it and itself in both directions.
 * :func:`flash_channel_attention` — the gram branch: one kernel streams
   the (N, C) tokens through VMEM in row blocks, accumulates the C×C
   gram on the MXU in VMEM scratch and finishes with DANet's
@@ -62,6 +68,7 @@ see :func:`_on_local_batch`.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
@@ -100,16 +107,299 @@ def _on_local_batch(kernel, *operands):
                          out_specs=batch_spec(), check_vma=False)(*operands)
 
 
+# ------------------------------------------------------------- mask rules
+def _value(x):
+    """A grid index given as it is, or as the call that reads it (a kernel
+    that never needs a ``program_id`` does not emit one)."""
+    return x() if callable(x) else x
+
+
+@dataclasses.dataclass(frozen=True)
+class MaskRule:
+    """Which (query, key) pairs a flash call attends, from positions alone:
+    which tiles of the grid run, and the mask inside a tile that runs.
+    Static and hashable (a ``custom_vjp``'s non-differentiated argument, a
+    kernel's ``functools.partial``).  This one is no mask: every tile runs
+    and only the keys past the true token count (padding) are masked —
+    DANet's position attention.
+
+    ``forward``, ``reverse_scope``, ``reverse_calls`` name the Mosaic calls
+    (forward; the reverse pass's scope and its fused / dK-dV / dQ calls);
+    ``kept``: the ``checkpoint_name``s of the forward call's output and
+    log-sum-exp, for a block that keeps them across its recomputation.
+
+    Tiles are addressed by block index (``i`` queries, ``j`` keys) and size;
+    ``reverse``: a reverse-pass tile, square, keys on sublanes and queries on
+    lanes.  With :data:`NO_MASK` and :data:`CAUSAL` the kernels trace to what
+    they were when ``causal`` was a flag, equation for equation."""
+
+    forward: str = scopes.PAM_KERNEL
+    reverse_scope: str = scopes.PAM_BWD
+    reverse_calls: tuple = (scopes.PAM_BWD_FUSED, scopes.PAM_BWD_DKV,
+                            scopes.PAM_BWD_DQ)
+    kept: tuple = ()
+    #: the first key tile counts for every query tile, so the fused reverse
+    #: call writes each row of its resident dQ before it adds to it
+    first_key_tile_meets_all = True
+
+    def tiles(self, i, j, block_q: int, block_k: int, *, n_real: int,
+              reverse: bool = False, merged: bool = False):
+        """Does tile ``(i, j)`` run: yields ``(condition, mask)`` for each
+        way it may — ``condition`` a traced bool (``None``: always), ``mask``
+        the boolean mask of the tile from its row and column offsets, called
+        inside the tile as ``mask(shape)`` -> bool array, True where the
+        pair attends (``shape``: (queries, keys), or (keys, queries) of a
+        ``reverse`` tile), or ``None`` (every pair attends).  ``merged``: the
+        caller masks every tile by a key set that holds this rule already,
+        and wants one condition."""
+        if reverse and not n_real % block_k:
+            yield None, None
+        elif reverse:
+            yield None, lambda shape: j * block_k \
+                + jax.lax.broadcasted_iota(jnp.int32, shape, 0) < n_real
+        else:
+            yield None, lambda shape: j * block_k \
+                + jax.lax.broadcasted_iota(jnp.int32, shape, 1) < n_real
+
+    def hold_key(self, i, j, block_q: int, block_k: int,
+                 reverse: bool = False):
+        """The key tile a sweep over the keys holds at step ``j`` of query
+        tile ``i``: ``j`` where that tile runs, else a tile that does (one
+        that is stepped over starts no copy)."""
+        return j
+
+    def hold_query(self, j, i, block_q: int, block_k: int):
+        """The query tile the reverse pass's sweep over the queries holds at
+        step ``i`` of key tile ``j``."""
+        return i
+
+
+NO_MASK = MaskRule()
+
+
+@dataclasses.dataclass(frozen=True)
+class Causal(MaskRule):
+    """A query sees the keys at or before its own position.  A tile wholly
+    above the diagonal is not computed, and only a tile that crosses it pays
+    for the mask; padded keys lie past every real query, so the mask is
+    theirs too."""
+
+    forward: str = scopes.CAUSAL_ATTN
+    reverse_scope: str = scopes.CAUSAL_ATTN_BWD
+    reverse_calls: tuple = (scopes.CAUSAL_ATTN_BWD_FUSED,
+                            scopes.CAUSAL_ATTN_BWD_DKV,
+                            scopes.CAUSAL_ATTN_BWD_DQ)
+    kept: tuple = ("causal_attn_out", "causal_attn_lse")
+
+    def tiles(self, i, j, block_q, block_k, *, n_real, reverse=False,
+              merged=False):
+        if reverse:  # square tiles: below the diagonal whole, on it masked
+            i = _value(i)
+            if merged:
+                yield i >= j, None
+                return
+            yield i > j, None
+            yield i == j, lambda shape: \
+                jax.lax.broadcasted_iota(jnp.int32, shape, 0) \
+                <= jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            return
+        q_lo = _value(i) * block_q
+        k_lo = j * block_k
+        crosses = k_lo + block_k - 1 > q_lo
+        if merged:
+            yield k_lo < q_lo + block_q, None
+            return
+
+        def mask(shape, crossing=True):
+            key_idx = j * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, shape, 1)
+            if not crossing:
+                return None
+            query_idx = q_lo + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            return key_idx <= query_idx
+
+        yield jnp.logical_and(k_lo < q_lo + block_q, crosses), mask
+        yield jnp.logical_not(crosses), functools.partial(mask,
+                                                          crossing=False)
+
+    def hold_key(self, i, j, block_q, block_k, reverse=False):
+        if reverse:
+            return jnp.minimum(j, i)
+        return jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+
+    def hold_query(self, j, i, block_q, block_k):
+        return jnp.maximum(i, j)
+
+
+CAUSAL = Causal()
+
+
+def _div(x, d: int):
+    """``x // d`` of a non-negative int32 (a shift where ``d`` is a power of
+    two: Mosaic's vector division is slow)."""
+    if d & (d - 1) == 0:
+        return jax.lax.shift_right_logical(
+            jnp.asarray(x, jnp.int32), jnp.int32(d.bit_length() - 1))
+    return jax.lax.div(jnp.asarray(x, jnp.int32), jnp.int32(d))
+
+
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class BlockDiffusion(MaskRule):
+    """The mask of a block-diffusion objective over a doubled sequence
+    ``[clean ‖ noised]`` of ``2·length`` positions in blocks of ``block``:
+    with ``c(p) = p < length`` and ``B(p) = (p mod length) // block``, query
+    ``t`` attends key ``s`` iff ``c(s) & c(t) & B(s) <= B(t)`` (the clean
+    copy is block-causal), or ``c(s) & ~c(t) & B(s) < B(t)`` (a noised block
+    sees the clean blocks before its own), or ``~c(s) & ~c(t) & B(s) = B(t)``
+    (a noised block sees itself, both directions).  Neither causal nor a
+    subset of it: ``length² + length·block`` pairs a head of ``4·length²``.
+
+    With tiles of ``T`` that divide ``length`` and hold whole blocks, a tile
+    runs iff its keys are clean and ``j mod n <= i mod n`` (``n = length /
+    T``), or both sides are noised and ``i = j``: ``n² + 2n`` of ``4n²``;
+    of those, the ``n(n − 1)`` strictly below a diagonal run with no mask.
+    Any other tile size runs every tile that holds an attended pair."""
+
+    length: int = 0
+    block: int = 1
+    forward: str = scopes.BLOCKDIFF_ATTN
+    reverse_scope: str = scopes.BLOCKDIFF_ATTN_BWD
+    reverse_calls: tuple = (scopes.BLOCKDIFF_ATTN_BWD_FUSED,
+                            scopes.BLOCKDIFF_ATTN_BWD_DKV,
+                            scopes.BLOCKDIFF_ATTN_BWD_DQ)
+    kept: tuple = ("blockdiff_attn_out", "blockdiff_attn_lse")
+    #: a query tile of the first noised block alone sees no clean key
+    first_key_tile_meets_all = False
+
+    def __post_init__(self):
+        if self.length < 1 or self.block < 1 or self.length % self.block:
+            raise ValueError(f"blocks of {self.block} do not tile a "
+                             f"sequence of {self.length}")
+
+    # block of a clean / of a noised position (non-negative arguments)
+    def _block(self, p):
+        return _div(p, self.block)
+
+    def _key_tiles(self, i, block_q, block_k):
+        """``(hi1, lo2, hi2)``: the clean key tiles ``0 … hi1`` and the
+        noised key tiles ``lo2 … hi2`` that hold a key which some query of
+        query tile ``i`` attends (an empty range has ``hi < lo``)."""
+        n, b = self.length, self.block
+        q_lo = i * block_q
+        q_hi = q_lo + block_q - 1
+        # the last clean key a clean query of the tile sees ends its own
+        # block; a noised query's, the block before its own
+        by_clean = jnp.where(
+            q_lo < n, (self._block(jnp.minimum(q_hi, n - 1)) + 1) * b - 1, -1)
+        last = jnp.maximum(jnp.minimum(q_hi, 2 * n - 1) - n, 0)
+        by_noised = jnp.where(q_hi >= n, self._block(last) * b - 1, -1)
+        key = jnp.maximum(by_clean, by_noised)
+        hi1 = jnp.where(key >= 0, _div(jnp.maximum(key, 0), block_k), -1)
+        first = jnp.maximum(q_lo, n) - n
+        lo2 = _div(n + self._block(first) * b, block_k)
+        hi2 = jnp.where(
+            q_hi >= n, _div(n + self._block(last) * b + b - 1, block_k), -1)
+        return hi1, lo2, hi2
+
+    def _runs(self, i, j, block_q, block_k):
+        hi1, lo2, hi2 = self._key_tiles(i, block_q, block_k)
+        return jnp.logical_or(j <= hi1,
+                              jnp.logical_and(j >= lo2, j <= hi2))
+
+    def _whole(self, i, j, block_q, block_k):
+        """Every pair of the tile attends: clean keys, all in blocks before
+        (a clean query tile: at or before) the first query's."""
+        n = self.length
+        q_lo, k_hi = i * block_q, j * block_k + block_k - 1
+        last_key = self._block(jnp.minimum(k_hi, n - 1))
+        clean = jnp.logical_and(q_lo + block_q <= n,
+                                last_key <= self._block(q_lo))
+        noised = jnp.logical_and(
+            q_lo >= n, last_key < self._block(jnp.maximum(q_lo - n, 0)))
+        return jnp.logical_and(k_hi < n, jnp.logical_or(clean, noised))
+
+    def tiles(self, i, j, block_q, block_k, *, n_real, reverse=False,
+              merged=False):
+        i = _value(i)
+        runs = self._runs(i, j, block_q, block_k)
+        whole = self._whole(i, j, block_q, block_k)
+        yield whole, None
+        yield jnp.logical_and(runs, jnp.logical_not(whole)), \
+            functools.partial(self.mask, q_lo=i * block_q, k_lo=j * block_k,
+                              reverse=reverse)
+
+    def mask(self, shape, q_lo, k_lo, reverse=False):
+        """The tile's mask from its offsets (a padded key lies in no block
+        of a real query's).  One comparison and one equality a pair: a key's
+        code is its block (a noised key's, past every clean block), a
+        query's two codes are the last clean block it sees and the noised
+        block it is in."""
+        n = self.length
+        blocks = n // self.block
+        rows, cols = shape
+        q_shape, q_dim = ((1, cols), 1) if reverse else ((rows, 1), 0)
+        k_shape, k_dim = ((rows, 1), 0) if reverse else ((1, cols), 1)
+        t = q_lo + jax.lax.broadcasted_iota(jnp.int32, q_shape, q_dim)
+        s = k_lo + jax.lax.broadcasted_iota(jnp.int32, k_shape, k_dim)
+        t_block = self._block(jnp.where(t < n, t, t - n))
+        s_block = self._block(jnp.where(s < n, s, s - n))
+        sees = jnp.where(t < n, t_block, t_block - 1)
+        own = jnp.where(t < n, -1, t_block + blocks)
+        code = jnp.where(s < n, s_block, s_block + blocks)
+        return jnp.logical_or(code <= sees, code == own)
+
+    def hold_key(self, i, j, block_q, block_k, reverse=False):
+        hi1, lo2, hi2 = self._key_tiles(i, block_q, block_k)
+        return jnp.where(
+            jnp.logical_and(j > hi1, lo2 <= hi2), jnp.clip(j, lo2, hi2),
+            jnp.clip(j, 0, jnp.maximum(hi1, 0)))
+
+    def hold_query(self, j, i, block_q, block_k):
+        n, b = self.length, self.block
+        k_lo = j * block_k
+        k_hi = k_lo + block_k - 1
+        first = self._block(jnp.minimum(k_lo, n - 1))  # of the clean keys
+        # clean queries from the first clean key's block on
+        lo1 = jnp.where(k_lo < n, _div(first * b, block_q), 0)
+        hi1 = jnp.where(k_lo < n, (n - 1) // block_q, -1)
+        # noised queries: after that block (clean keys), in the keys' own
+        # blocks (noised keys)
+        after = n + (first + 1) * b
+        lo_a = jnp.where(jnp.logical_and(k_lo < n, after < 2 * n),
+                         _div(jnp.minimum(after, 2 * n - 1), block_q),
+                         2 ** 30)
+        lo_b = jnp.where(k_hi >= n, _div(jnp.maximum(k_lo, n), block_q),
+                         2 ** 30)
+        hi_b = jnp.where(k_hi >= n,
+                         _div(jnp.minimum(k_hi, 2 * n - 1), block_q), -1)
+        lo2 = jnp.minimum(lo_a, lo_b)
+        hi2 = jnp.where(lo_a < 2 ** 30, (2 * n - 1) // block_q, hi_b)
+        return jnp.where(
+            jnp.logical_and(i > hi1, lo2 <= hi2), jnp.clip(i, lo2, hi2),
+            jnp.clip(i, lo1, jnp.maximum(hi1, lo1)))
+
+
+def _rule_tiles(rule: MaskRule, tile, i, j, block_q: int, block_k: int,
+                **kw):
+    """Run ``tile(mask)`` on the tiles of the grid that ``rule`` says run,
+    each under its condition."""
+    for condition, mask in rule.tiles(i, j, block_q, block_k, **kw):
+        if condition is None:
+            tile(mask)
+        else:
+            pl.when(condition)(functools.partial(tile, mask))
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *refs,
                   n_real: int, block_k: int, scale: float | None,
-                  causal: bool = False, keep_ref=None):
+                  rule: MaskRule = NO_MASK, keep_ref=None):
     """One (q-block, k-block) tile of online-softmax attention.  ``refs``:
     the running (max, sum, accumulator) scratch, after the log-sum-exp
     output where the call was built with one (the differentiated forward).
-    ``causal``: a query sees the keys at or before its own position; a tile
-    wholly above the diagonal is not computed, and only a tile that crosses
-    it pays for the mask.  ``keep_ref`` (a causal call's): the tile of each
-    query's key set, int8; every tile that runs is masked by it, and it
+    ``rule``: which tiles run and the mask inside one (:class:`MaskRule`); a
+    tile that the rule steps over is not computed, and only a tile that its
+    mask crosses pays for it.  ``keep_ref`` (a causal call's): the tile of
+    each query's key set, int8; every tile that runs is masked by it, and it
     holds the causal mask already."""
     *lse_ref, m_ref, s_ref, acc_ref = refs
     j = pl.program_id(2)
@@ -121,7 +411,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *refs,
         s_ref[:] = jnp.zeros_like(s_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def tile(q_lo=None):
+    def tile(mask=None):
         q = q_ref[0]          # (bq, ck)
         k = k_ref[0]          # (bk, ck)
         v = v_ref[0]          # (bk, cv)
@@ -134,19 +424,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *refs,
             # running max -1e30; its first kept key (at the latest its own
             # position's tile) rescales that to nothing
             scores = jnp.where(_kept(keep_ref[0]), scores, _NEG_INF)
-        else:
-            key_idx = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, scores.shape, 1)
-            if not causal:
-                # Mask keys past the true token count (N was padded to a
-                # block multiple).
-                scores = jnp.where(key_idx < n_real, scores, _NEG_INF)
-            elif q_lo is not None:
-                # the tile crosses the diagonal.  Padded keys lie past every
-                # real query, so this mask is theirs too
-                query_idx = q_lo + jax.lax.broadcasted_iota(
-                    jnp.int32, scores.shape, 0)
-                scores = jnp.where(key_idx <= query_idx, scores, _NEG_INF)
+        elif mask is not None:
+            # the rule's: keys past the true token count (N was padded to a
+            # block multiple), a diagonal that the tile crosses; as the key
+            # set's, a row with no key yet is rescaled by its first
+            seen = mask(scores.shape)
+            if seen is not None:
+                scores = jnp.where(seen, scores, _NEG_INF)
 
         m_prev = m_ref[:, :1]                            # (bq, 1)
         m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
@@ -158,19 +442,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *refs,
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         s_ref[:] = jnp.broadcast_to(s_new, s_ref.shape)
 
-    if causal:
-        block_q = q_ref.shape[1]
-        q_lo = pl.program_id(1) * block_q
-        k_lo = j * block_k
-        crosses = k_lo + block_k - 1 > q_lo
-        if keep_ref is not None:
-            pl.when(k_lo < q_lo + block_q)(tile)
-        else:
-            pl.when(jnp.logical_and(k_lo < q_lo + block_q, crosses))(
-                functools.partial(tile, q_lo))
-            pl.when(jnp.logical_not(crosses))(tile)
-    else:
-        tile()
+    _rule_tiles(rule, tile, lambda: pl.program_id(1), j, q_ref.shape[1],
+                block_k, n_real=n_real, merged=keep_ref is not None)
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -207,7 +480,7 @@ def _pad_tokens(x, n_padded: int):
 
 def _flash_local(q, k, v, keep=None, *, block_q: int, block_k: int,
                  scale: float | None, interpret: bool, with_lse: bool,
-                 causal: bool = False, group: int = 1, heads: int = 1):
+                 rule: MaskRule = NO_MASK, group: int = 1, heads: int = 1):
     """``keep``: int8 (B / heads, N, N), each query's key set, shared by the
     ``heads`` rows of ``q`` that are one sequence's query heads."""
     b, n, ck = q.shape
@@ -220,20 +493,18 @@ def _flash_local(q, k, v, keep=None, *, block_q: int, block_k: int,
 
     kernel = functools.partial(
         _flash_kernel if keep is None else _sparse_flash_kernel,
-        n_real=n, block_k=block_k, scale=scale, causal=causal)
-    # a causal call's tiles (``_CAUSAL_TILE``) pass Mosaic's default scope
+        n_real=n, block_k=block_k, scale=scale, rule=rule)
+    # a token call's tiles (``_CAUSAL_TILE``) pass Mosaic's default scope
     extra = dict(compiler_params=pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=_BWD_VMEM_LIMIT)) if causal else {}
+        vmem_limit_bytes=_BWD_VMEM_LIMIT)) if rule is not NO_MASK else {}
 
     def kv_map(b_, i, j):
         if group > 1:  # row ``b_`` is a query head of key/value head:
             b_ = b_ // group
-        if causal:
-            # past the query block's last tile the index stays where it is,
-            # so a tile that is stepped over starts no copy
-            j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
-        return (b_, j, 0)
+        # on a tile that the rule steps over the index stays on one that
+        # runs, so it starts no copy
+        return (b_, rule.hold_key(i, j, block_q, block_k), 0)
 
     in_specs = [
         pl.BlockSpec((1, block_q, ck), lambda b_, i, j: (b_, i, 0)),
@@ -241,7 +512,7 @@ def _flash_local(q, k, v, keep=None, *, block_q: int, block_k: int,
         pl.BlockSpec((1, block_k, cv), kv_map),
     ]
     operands = (q, k, v)
-    name = scopes.CAUSAL_ATTN if causal else scopes.PAM_KERNEL
+    name = rule.forward
     if keep is not None:
         def keep_map(b_, i, j):
             return (b_ // heads, i, kv_map(b_, i, j)[1])
@@ -283,12 +554,12 @@ def _flash_local(q, k, v, keep=None, *, block_q: int, block_k: int,
 
 def _flash_forward(q, k, v, block_q: int, block_k: int,
                    scale: float | None, interpret: bool,
-                   with_lse: bool = False, causal: bool = False,
+                   with_lse: bool = False, rule: MaskRule = NO_MASK,
                    group: int = 1, keep=None, heads: int = 1):
     return _on_local_batch(
         functools.partial(_flash_local, block_q=block_q, block_k=block_k,
                           scale=scale, interpret=interpret,
-                          with_lse=with_lse, causal=causal, group=group,
+                          with_lse=with_lse, rule=rule, group=group,
                           heads=heads),
         q, k, v, *(() if keep is None else (keep,)))
 
@@ -317,13 +588,13 @@ def _bwd_plan(n: int, ck: int) -> tuple[int, bool]:
     return block, resident <= _BWD_DQ_RESIDENT_LIMIT
 
 
-def _bwd_tile(q, k, v, do, lse, delta, *, key_block, n_real: int,
-              scale: float | None, diagonal: bool | None = None, keep=None):
+def _bwd_tile(q, k, v, do, lse, delta, *, scale: float | None, mask=None,
+              keep=None):
     """``(Pᵀ, dSᵀ)`` of one tile, keys on sublanes and queries on lanes —
     the orientation in which ``lse`` and ``delta`` (per query) are lane-
     dense rows and dV, dK need no transpose.  float32 throughout.
-    ``diagonal``: of a causal call, whether the tile's key block is its
-    query block, where a key counts for the queries at or after it.
+    ``mask``: the rule's for this tile (``MaskRule.tiles``: the padded keys,
+    a diagonal the tile lies on), or ``None`` where every pair attends.
     ``keep``: the tile of the queries' key sets, keys on sublanes, int8; it
     holds the causal mask and the padding's already."""
     st = jax.lax.dot_general(k, q, _NT,
@@ -331,18 +602,12 @@ def _bwd_tile(q, k, v, do, lse, delta, *, key_block, n_real: int,
     if scale is not None:
         st = st * scale
     pt = jnp.exp(st - lse)
-    block = k.shape[0]
     if keep is not None:
         pt = jnp.where(_kept(keep), pt, 0.0)
-    elif diagonal:  # padded keys lie past every real query: masked here too
-        pt = jnp.where(
-            jax.lax.broadcasted_iota(jnp.int32, pt.shape, 0)
-            <= jax.lax.broadcasted_iota(jnp.int32, pt.shape, 1), pt, 0.0)
-    elif diagonal is None and n_real % block:
-        # keys past the true token count (zero-padded)
-        key_idx = key_block * block + jax.lax.broadcasted_iota(
-            jnp.int32, pt.shape, 0)
-        pt = jnp.where(key_idx < n_real, pt, 0.0)
+    elif mask is not None:
+        seen = mask(pt.shape)
+        if seen is not None:
+            pt = jnp.where(seen, pt, 0.0)
     dpt = jax.lax.dot_general(v, do, _NT,
                               preferred_element_type=jnp.float32)
     dst = pt * (dpt - delta)
@@ -351,20 +616,9 @@ def _bwd_tile(q, k, v, do, lse, delta, *, key_block, n_real: int,
     return pt, dst
 
 
-def _causal_tiles(tile, key_block, query_block, sparse: bool = False):
-    """Run ``tile(diagonal)`` where a causal call has work: below the
-    diagonal as it is, on it masked, above it not at all.  ``sparse``: the
-    tile masks itself by its key set, on the diagonal as below it."""
-    if sparse:
-        pl.when(query_block >= key_block)(functools.partial(tile, False))
-        return
-    pl.when(query_block > key_block)(functools.partial(tile, False))
-    pl.when(query_block == key_block)(functools.partial(tile, True))
-
-
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *refs, n_real: int,
-                    scale: float | None, causal: bool = False,
+                    scale: float | None, rule: MaskRule = NO_MASK,
                     keep_ref=None):
     """The key-block sweep, queries innermost: dK and dV of the block
     accumulate in float32 scratch.  Built with a dQ output (the fused
@@ -378,11 +632,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def tile(diagonal=None):
+    def tile(mask=None):
         q, k, do = q_ref[0], k_ref[0], do_ref[0]
         pt, dst = _bwd_tile(q, k, v_ref[0], do, lse_ref[0], delta_ref[0],
-                            key_block=j, n_real=n_real, scale=scale,
-                            diagonal=diagonal,
+                            scale=scale, mask=mask,
                             keep=None if keep_ref is None else keep_ref[0])
         dst = dst.astype(q.dtype)
         dv_acc[:] += jax.lax.dot_general(pt.astype(do.dtype), do, _NN,
@@ -395,6 +648,10 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             block = q.shape[0]
             rows = pl.ds(pl.multiple_of(i * block, block), block)
 
+            if not rule.first_key_tile_meets_all:
+                dq_ref[0][0, rows, :] += dq   # zeroed at the sweep's start
+                return
+
             # causal: key block 0 counts for every query block, so each
             # row of dQ is still written before it is added to
             @pl.when(j == 0)
@@ -405,10 +662,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             def _add():
                 dq_ref[0][0, rows, :] += dq
 
-    if causal:
-        _causal_tiles(tile, j, i, sparse=keep_ref is not None)
-    else:
-        tile()
+    if dq_ref and not rule.first_key_tile_meets_all:
+        @pl.when(jnp.logical_and(i == 0, j == 0))
+        def _init_dq():
+            dq_ref[0][:] = jnp.zeros_like(dq_ref[0])
+
+    block = q_ref.shape[1]
+    _rule_tiles(rule, tile, i, j, block, block, n_real=n_real, reverse=True,
+                merged=keep_ref is not None)
 
     @pl.when(i == pl.num_programs(2) - 1)
     def _finalize():
@@ -430,7 +691,7 @@ def _sparse_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_acc, *, n_real: int, scale: float | None,
-                   causal: bool = False, keep_ref=None):
+                   rule: MaskRule = NO_MASK, keep_ref=None):
     """The query-block sweep of the two-sweep schedule, keys innermost."""
     j = pl.program_id(2)
 
@@ -438,20 +699,17 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    def tile(diagonal=None):
+    def tile(mask=None):
         k = k_ref[0]
         _, dst = _bwd_tile(q_ref[0], k, v_ref[0], do_ref[0], lse_ref[0],
-                           delta_ref[0], key_block=j, n_real=n_real,
-                           scale=scale, diagonal=diagonal,
+                           delta_ref[0], scale=scale, mask=mask,
                            keep=None if keep_ref is None else keep_ref[0])
         dq_acc[:] += jax.lax.dot_general(dst.astype(k.dtype), k, _TN,
                                          preferred_element_type=jnp.float32)
 
-    if causal:
-        _causal_tiles(tile, j, pl.program_id(1),
-                      sparse=keep_ref is not None)
-    else:
-        tile()
+    block = q_ref.shape[1]
+    _rule_tiles(rule, tile, lambda: pl.program_id(1), j, block, block,
+                n_real=n_real, reverse=True, merged=keep_ref is not None)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
@@ -460,7 +718,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_backward_local(q, k, v, out, lse, do, keep_t=None, *,
                           scale: float | None, interpret: bool,
-                          causal: bool = False, group: int = 1,
+                          rule: MaskRule = NO_MASK, group: int = 1,
                           heads: int = 1):
     """dq, dk, dv of flash position attention from the saved output and
     log-sum-exp.  Per tile: ``S = q·kᵀ`` (× ``scale``), ``P = exp(S − lse)``
@@ -475,10 +733,11 @@ def _flash_backward_local(q, k, v, out, lse, do, keep_t=None, *,
     output, then ``pam_bwd_dq`` on grid ``(batch, q_blocks, k_blocks)``):
     O(block) VMEM at any N, at the price of computing S and dP twice.
 
-    ``causal`` (the calls are then named ``causal_attn_bwd_…``): tiles above
-    the diagonal are stepped over in both schedules, and the inner axis'
-    block index stays on the diagonal's while they are, so they copy
-    nothing.  ``group`` > 1: a row of ``q`` is a query head and ``group`` of
+    ``rule`` (the calls take its names: ``causal_attn_bwd_…``,
+    ``blockdiff_attn_bwd_…``): the tiles that it says do not run are stepped
+    over in both schedules (a causal call's: those above the diagonal), and
+    the inner axis' block index stays on one that runs while they are, so
+    they copy nothing.  ``group`` > 1: a row of ``q`` is a query head and ``group`` of
     them read one row of ``k``, ``v``; each writes its own float32 dK, dV,
     summed over the group here.  ``keep_t`` (the calls are then named
     ``sparse_attn_bwd_…``): int8 (B / heads, N keys, N queries), the queries'
@@ -495,13 +754,11 @@ def _flash_backward_local(q, k, v, out, lse, do, keep_t=None, *,
     lse, delta = (_pad_tokens(x, nb * block)[:, None, :]
                   for x in (lse, delta))
 
-    static = dict(n_real=n, scale=scale, causal=causal)
+    static = dict(n_real=n, scale=scale, rule=rule)
     params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         vmem_limit_bytes=_BWD_VMEM_LIMIT)
-    names = (scopes.CAUSAL_ATTN_BWD_FUSED, scopes.CAUSAL_ATTN_BWD_DKV,
-             scopes.CAUSAL_ATTN_BWD_DQ) if causal else (
-        scopes.PAM_BWD_FUSED, scopes.PAM_BWD_DKV, scopes.PAM_BWD_DQ)
+    names = rule.reverse_calls
     dkv_kernel, dq_kernel, operands = _bwd_dkv_kernel, _bwd_dq_kernel, ()
     if keep_t is not None:
         names = (scopes.SPARSE_ATTN_BWD_FUSED, scopes.SPARSE_ATTN_BWD_DKV,
@@ -513,13 +770,15 @@ def _flash_backward_local(q, k, v, out, lse, do, keep_t=None, *,
         """In-specs of (q, k, v, dO, lse, δ); ``at_q`` / ``at_k``: which
         grid axis walks the query blocks / the key blocks."""
         def block_at(axis):
-            if not causal or axis != 2:
+            if axis != 2:
                 return lambda g: g[axis]
-            # the inner axis does not leave the tiles that run: the queries'
-            # index not below the key block's, the keys' not above the
-            # query block's
-            bound = jnp.maximum if axis == at_q else jnp.minimum
-            return lambda g: bound(g[2], g[1])
+            # the inner axis does not leave the tiles that run (a causal
+            # call's: the queries' index not below the key block's, the
+            # keys' not above the query block's)
+            if axis == at_q:
+                return lambda g: rule.hold_query(g[1], g[2], block, block)
+            return lambda g: rule.hold_key(g[1], g[2], block, block,
+                                           reverse=True)
         qi, ki = block_at(at_q), block_at(at_k)
         kv_row = (lambda g: g[0] // group) if group > 1 else (lambda g: g[0])
 
@@ -585,46 +844,46 @@ def _flash_backward_local(q, k, v, out, lse, do, keep_t=None, *,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_position_attention(q, k, v, block_q: int = 256, block_k: int = 256,
                              scale: float | None = None,
-                             interpret: bool = False, causal: bool = False,
-                             group: int = 1):
+                             interpret: bool = False,
+                             rule: MaskRule = NO_MASK, group: int = 1):
     """Flash position attention: same math as
     :func:`ops.attention.position_attention` (unscaled DANet energies unless
     ``scale``), O(N·block) memory, MXU-scheduled.  ``block_q`` / ``block_k``
     tile the forward; the reverse pass sizes its own tiles from the shapes.
 
-    ``q``/``k``: (B, N, Ck); ``v``: (B, N, Cv) -> (B, N, Cv).  ``causal``:
-    row ``t`` attends to rows ``<= t``.  ``group``: ``q`` has ``group``
+    ``q``/``k``: (B, N, Ck); ``v``: (B, N, Cv) -> (B, N, Cv).  ``rule``: a
+    :class:`MaskRule` — :data:`NO_MASK`, :data:`CAUSAL` (row ``t`` attends to
+    rows ``<= t``), a :class:`BlockDiffusion`.  ``group``: ``q`` has ``group``
     times the rows of ``k`` and ``v``, and rows ``g·group … g·group + group
     − 1`` of it read row ``g`` of theirs (grouped-query heads laid on the
     batch axis: :func:`flash_causal_attention`).
     """
     return _flash_forward(q, k, v, block_q, block_k, scale, interpret,
-                          causal=causal, group=group)
+                          rule=rule, group=group)
 
 
 #: ``checkpoint_name``s of what the causal reverse pass keeps of its forward
 #: call: a block that recomputes itself under a policy that saves these
 #: names (8 MB + 131 KB at the token cell's shape) runs no second forward
 #: call.  A policy that names nothing (``nn.remat``'s default) is unmoved
-KEPT_BY_REVERSE = ("causal_attn_out", "causal_attn_lse")
+KEPT_BY_REVERSE = CAUSAL.kept
 
 
-def _fwd(q, k, v, block_q, block_k, scale, interpret, causal, group):
+def _fwd(q, k, v, block_q, block_k, scale, interpret, rule, group):
     out, lse = _flash_forward(q, k, v, block_q, block_k, scale, interpret,
-                              with_lse=True, causal=causal, group=group)
-    if causal:
-        out, lse = map(checkpoint_name, (out, lse), KEPT_BY_REVERSE)
+                              with_lse=True, rule=rule, group=group)
+    if rule.kept:
+        out, lse = map(checkpoint_name, (out, lse), rule.kept)
     return out, (q, k, v, out, lse)
 
 
-def _bwd(block_q, block_k, scale, interpret, causal, group, res, g):
+def _bwd(block_q, block_k, scale, interpret, rule, group, res, g):
     # the flash backward as Mosaic calls: no recompute of the forward's
     # recurrence, no N×N array in HBM (see _flash_backward_local)
-    with jax.named_scope(scopes.CAUSAL_ATTN_BWD if causal
-                         else scopes.PAM_BWD):
+    with jax.named_scope(rule.reverse_scope):
         return _on_local_batch(
             functools.partial(_flash_backward_local, scale=scale,
-                              interpret=interpret, causal=causal,
+                              interpret=interpret, rule=rule,
                               group=group), *res, g)
 
 
@@ -656,7 +915,33 @@ def flash_causal_attention(q, k, v, interpret: bool = False):
 
     out = flash_position_attention(
         _heads_first(q), _heads_first(k), _heads_first(v), *_causal_blocks(s),
-        1 / math.sqrt(d), interpret=interpret, causal=True, group=hq // hkv)
+        1 / math.sqrt(d), interpret=interpret, rule=CAUSAL, group=hq // hkv)
+    return out.reshape(b, hq, s, d).transpose(0, 2, 1, 3)
+
+
+def flash_block_diffusion_attention(q, k, v, length: int, block: int,
+                                    interpret: bool = False):
+    """:func:`ops.attention.block_diffusion_attention` through the flash
+    kernels, forward and reverse, under the rule
+    :class:`BlockDiffusion`: ``q``: (B, 2·length, Hq, D), the clean copy of
+    a sequence and its noised copy end to end; ``k``, ``v``: (B, 2·length,
+    Hkv, D) -> (B, 2·length, Hq, D).  Of the grid's tiles those that hold an
+    attended pair run (24 of 64 at tiles of 1,024 and 4,096 tokens), half of
+    them with no mask; the calls are ``blockdiff_attn`` and
+    ``blockdiff_attn_bwd_…``, and a block that saves the rule's ``kept``
+    names keeps their output and log-sum-exp across its recomputation."""
+    b, s, hq, d = q.shape
+    hkv = k.shape[2]
+    if hq % hkv:
+        raise ValueError(f"{hq} query heads do not share {hkv} key/value "
+                         f"heads evenly")
+    if s != 2 * length:
+        raise ValueError(f"{s} positions are not a sequence of {length} "
+                         "and its noised copy")
+    out = flash_position_attention(
+        _heads_first(q), _heads_first(k), _heads_first(v), *_causal_blocks(s),
+        1 / math.sqrt(d), interpret=interpret,
+        rule=BlockDiffusion(length=length, block=block), group=hq // hkv)
     return out.reshape(b, hq, s, d).transpose(0, 2, 1, 3)
 
 
@@ -676,14 +961,14 @@ SPARSE_KEPT_BY_REVERSE = ("sparse_attn_out", "sparse_attn_lse",
 def _sparse_attention(q, k, v, keep, block_q, block_k, scale, interpret,
                       group, heads):
     return _flash_forward(q, k, v, block_q, block_k, scale, interpret,
-                          with_lse=True, causal=True, group=group, keep=keep,
+                          with_lse=True, rule=CAUSAL, group=group, keep=keep,
                           heads=heads)
 
 
 def _sparse_fwd(q, k, v, keep, block_q, block_k, scale, interpret, group,
                 heads):
     out, lse = _flash_forward(q, k, v, block_q, block_k, scale, interpret,
-                              with_lse=True, causal=True, group=group,
+                              with_lse=True, rule=CAUSAL, group=group,
                               keep=keep, heads=heads)
     out, lse = map(checkpoint_name, (out, lse), SPARSE_KEPT_BY_REVERSE[:2])
     return (out, lse), (q, k, v, keep, out, lse)
@@ -695,7 +980,7 @@ def _sparse_bwd(block_q, block_k, scale, interpret, group, heads, res, g):
         # the reverse tiles hold keys on sublanes and queries on lanes
         grads = _on_local_batch(
             functools.partial(_flash_backward_local, scale=scale,
-                              interpret=interpret, causal=True, group=group,
+                              interpret=interpret, rule=CAUSAL, group=group,
                               heads=heads),
             q, k, v, out, lse, g[0], keep.swapaxes(1, 2))
     return (*grads, None)

@@ -30,7 +30,7 @@ params + grads, not two.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Mapping
+from typing import Any, Callable, Mapping, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -44,7 +44,9 @@ from ..ops import (
     next_token_xent,
     se_presence_loss,
     softmax_xent_ignore,
+    weighted_token_xent,
 )
+from ..ops import diffusion
 from ..telemetry import counters as counters_lib
 from ..telemetry import scopes
 from . import mesh as mesh_lib
@@ -87,12 +89,14 @@ def _unpack_mask_bits(batch: Batch) -> dict:
 INPUT_KEY = "concat"
 TARGET_KEY = "crop_gt"
 
-#: the one batch key of the ``tokens`` task: ``(B, S)`` int32 token ids, input
-#: and (shifted) target at once
+#: the batch key of the ``tokens`` task: ``(B, S)`` int32 token ids, input
+#: and target at once (what else a token loss reads — a noised copy, a
+#: weight — the task's device stage adds: ``ops/diffusion.py``)
 TOKENS_KEY = "tokens"
-#: the loss type whose models read :data:`TOKENS_KEY` and carry no
-#: ``batch_stats``
+#: the token task's loss types (:data:`LOSSES`): their models read ids and
+#: carry no ``batch_stats``
 NEXT_TOKEN = "next_token"
+BLOCK_DIFFUSION = "block_diffusion"
 
 #: key under which a coalesced batch ships (data.coalesce_wire)
 WIRE_KEY = "wire"
@@ -321,33 +325,7 @@ def create_train_state(
     return fixed
 
 
-def _compute_loss(outputs, batch: Batch, weights, loss_type: str):
-    """Loss over a model's output tuple.
-
-    ``multi_sigmoid`` — the reference's weighted multi-output balanced BCE
-    (binary interactive segmentation, SegmentationMultiLosses semantics).
-    ``multi_softmax`` — per-output softmax CE with ignore_index=255 (the
-    multi-class DeepLabV3 configs; aux outputs default to 0.4 weight).
-    ``next_token`` — a token model's ``(logits, mtp_logits...)``: output
-    ``k`` is scored against the token ``k + 1`` places on; the weights are
-    ``(1, lambda, ...)`` and every multi-token-prediction output needs one.
-    """
-    if loss_type == NEXT_TOKEN:
-        if weights is None:
-            weights = (1.0,) * len(outputs)
-        if len(weights) != len(outputs):
-            raise ValueError(
-                f"loss weights {tuple(weights)} for {len(outputs)} token "
-                "outputs — give the next-token head and every "
-                "multi-token-prediction head a weight")
-        tokens = batch[TOKENS_KEY]
-        total = jnp.float32(0.0)
-        for k, (out, w) in enumerate(zip(outputs, weights)):
-            total = total + w * next_token_xent(out, tokens, shift=k + 1)
-        return total
-    inputs = batch[INPUT_KEY]
-    target = batch[TARGET_KEY]
-    void = batch.get("crop_void")
+def _check_weights(weights, outputs) -> None:
     if weights is not None and len(weights) != len(outputs):
         # zip would silently truncate — e.g. EncNet's (map, aux, se) tuple
         # under loss_weights=[1.0,0.4] would drop the SE-presence loss and
@@ -355,32 +333,112 @@ def _compute_loss(outputs, batch: Batch, weights, loss_type: str):
         raise ValueError(
             f"model.loss_weights has {len(weights)} entries but the model "
             f"emits {len(outputs)} outputs — give every output a weight")
-    if loss_type == "multi_sigmoid":
-        if target.ndim == inputs.ndim - 1:  # (B,H,W) vs (B,H,W,C) logits
-            target = target[..., None]
-        if void is not None and void.ndim == inputs.ndim - 1:
-            void = void[..., None]
-        return multi_output_loss(outputs, target, void=void, weights=weights)
-    if loss_type == "multi_softmax":
-        labels = target
-        if labels.ndim == outputs[0].ndim:  # squeeze trailing channel axis
-            labels = labels[..., 0]
-        labels = labels.astype(jnp.int32)
-        if weights is None:
-            # map aux heads 0.4 (DeepLab recipe); a 2D SE output gets the
-            # EncNet paper's 0.2
-            weights = (1.0,) + tuple(
-                0.2 if o.ndim == 2 else 0.4 for o in outputs[1:])
-        total = jnp.float32(0.0)
-        for out, w in zip(outputs, weights):
-            if out.ndim == 2:
-                # (B, C) vector head: EncNet's semantic-encoding branch —
-                # class-presence BCE, not a per-pixel CE
-                total = total + w * se_presence_loss(out, labels)
-            else:
-                total = total + w * softmax_xent_ignore(out, labels)
-        return total
-    raise ValueError(f"unknown loss_type: {loss_type!r}")
+
+
+def _multi_sigmoid_loss(outputs, batch: Batch, weights):
+    """The reference's weighted multi-output balanced BCE (binary
+    interactive segmentation, SegmentationMultiLosses semantics)."""
+    _check_weights(weights, outputs)
+    inputs, target = batch[INPUT_KEY], batch[TARGET_KEY]
+    void = batch.get("crop_void")
+    if target.ndim == inputs.ndim - 1:  # (B,H,W) vs (B,H,W,C) logits
+        target = target[..., None]
+    if void is not None and void.ndim == inputs.ndim - 1:
+        void = void[..., None]
+    return multi_output_loss(outputs, target, void=void, weights=weights)
+
+
+def _multi_softmax_loss(outputs, batch: Batch, weights):
+    """Per-output softmax CE with ignore_index=255 (the multi-class
+    DeepLabV3 configs; aux outputs default to 0.4 weight)."""
+    _check_weights(weights, outputs)
+    labels = batch[TARGET_KEY]
+    if labels.ndim == outputs[0].ndim:  # squeeze trailing channel axis
+        labels = labels[..., 0]
+    labels = labels.astype(jnp.int32)
+    if weights is None:
+        # map aux heads 0.4 (DeepLab recipe); a 2D SE output gets the
+        # EncNet paper's 0.2
+        weights = (1.0,) + tuple(
+            0.2 if o.ndim == 2 else 0.4 for o in outputs[1:])
+    total = jnp.float32(0.0)
+    for out, w in zip(outputs, weights):
+        if out.ndim == 2:
+            # (B, C) vector head: EncNet's semantic-encoding branch —
+            # class-presence BCE, not a per-pixel CE
+            total = total + w * se_presence_loss(out, labels)
+        else:
+            total = total + w * softmax_xent_ignore(out, labels)
+    return total
+
+
+def _next_token_loss(outputs, batch: Batch, weights):
+    """A token model's ``(logits, mtp_logits...)``: output ``k`` is scored
+    against the token ``k + 1`` places on; the weights are ``(1, lambda,
+    ...)`` and every multi-token-prediction output needs one."""
+    if weights is None:
+        weights = (1.0,) * len(outputs)
+    if len(weights) != len(outputs):
+        raise ValueError(
+            f"loss weights {tuple(weights)} for {len(outputs)} token "
+            "outputs — give the next-token head and every "
+            "multi-token-prediction head a weight")
+    tokens = batch[TOKENS_KEY]
+    total = jnp.float32(0.0)
+    for k, (out, w) in enumerate(zip(outputs, weights)):
+        total = total + w * next_token_xent(out, tokens, shift=k + 1)
+    return total
+
+
+def _block_diffusion_loss(outputs, batch: Batch, weights):
+    """A block-diffusion model's one output, the logits over the noised
+    copy's positions: position ``i`` is scored against token ``i`` with the
+    weight the noise gave it (``ops/diffusion.py``), no shift."""
+    (logits,) = outputs
+    return weighted_token_xent(logits, batch[TOKENS_KEY],
+                               batch[diffusion.LOSS_WEIGHT_KEY])
+
+
+class Loss(NamedTuple):
+    """What a loss type is to the steps."""
+    #: the batch keys handed to the model, in order
+    inputs: tuple
+    #: ``(outputs, batch, weights) -> scalar`` over the model's output tuple
+    loss: Callable
+    #: a token model's: ids in (nothing to cast), no ``batch_stats``, the
+    #: GSPMD step only, an evaluation that hands back the loss alone
+    tokens: bool = False
+    #: ``(batch) -> {declared counter: scalar}`` of the batch itself, handed
+    #: back beside what the model sowed
+    counters: Callable | None = None
+
+
+#: loss type -> :class:`Loss`: the one table that ``_compute_loss``,
+#: ``_loss_and_updates``, ``make_train_step`` and ``make_eval_step`` read; a
+#: new objective is one entry
+LOSSES = {
+    "multi_sigmoid": Loss((INPUT_KEY,), _multi_sigmoid_loss),
+    "multi_softmax": Loss((INPUT_KEY,), _multi_softmax_loss),
+    NEXT_TOKEN: Loss((TOKENS_KEY,), _next_token_loss, tokens=True),
+    BLOCK_DIFFUSION: Loss(
+        (TOKENS_KEY, diffusion.NOISED_KEY), _block_diffusion_loss,
+        tokens=True,
+        counters=lambda batch: {
+            diffusion.COUNTER_MASKED_SHARE: diffusion.masked_share(
+                batch[diffusion.LOSS_WEIGHT_KEY])}),
+}
+
+
+def loss_of(loss_type: str) -> Loss:
+    if loss_type not in LOSSES:
+        raise ValueError(f"unknown loss_type: {loss_type!r} "
+                         f"({' | '.join(LOSSES)})")
+    return LOSSES[loss_type]
+
+
+def _compute_loss(outputs, batch: Batch, weights, loss_type: str):
+    """Loss over a model's output tuple, by the entry of :data:`LOSSES`."""
+    return loss_of(loss_type).loss(outputs, batch, weights)
 
 
 def _loss_and_updates(model, params, batch_stats, batch: Batch, rng,
@@ -407,15 +465,13 @@ def _loss_and_updates(model, params, batch_stats, batch: Batch, rng,
     """
     variables = {"params": params, "batch_stats": batch_stats}
     counters = {}
-    if loss_type == NEXT_TOKEN:
-        inputs = batch[TOKENS_KEY]  # ids: nothing to cast
-    else:
-        inputs = batch[INPUT_KEY]
-        if precision is not None:
-            inputs = precision.cast_to_compute(inputs)
+    entry = loss_of(loss_type)
+    inputs = tuple(batch[k] for k in entry.inputs)
+    if precision is not None and not entry.tokens:  # ids: nothing to cast
+        inputs = tuple(map(precision.cast_to_compute, inputs))
     if train:
         outputs, mutated = model.apply(
-            variables, inputs, train=True,
+            variables, *inputs, train=True,
             mutable=["batch_stats", "losses", counters_lib.COLLECTION],
             rngs={"dropout": rng},
         )
@@ -425,8 +481,10 @@ def _loss_and_updates(model, params, batch_stats, batch: Batch, rng,
                   jnp.float32(0.0))
         counters = counters_lib.reduce_sown(
             mutated.get(counters_lib.COLLECTION, {}))
+        if entry.counters is not None:
+            counters.update(entry.counters(batch))
     else:
-        outputs = model.apply(variables, inputs, train=False)
+        outputs = model.apply(variables, *inputs, train=False)
         new_stats = batch_stats
         aux = jnp.float32(0.0)
     with jax.named_scope(scopes.LOSS):
@@ -497,8 +555,10 @@ def make_train_step(
     readback stays on the trainer's existing loss-fetch boundary (no
     extra host syncs).  Multi-step programs return ``((K,), (K, 2))``.
 
-    ``loss_type="next_token"`` (the ``tokens`` task): the batch is
-    ``{"tokens": (B, S) int32}`` and the model has no ``batch_stats``.
+    A token loss type (the ``tokens`` task; :data:`LOSSES`): the batch is
+    ``{"tokens": (B, S) int32}`` — with ``noised`` and ``loss_weight`` under
+    ``block_diffusion``, which ``augment`` (the task's device stage) or the
+    caller has added — and the model has no ``batch_stats``.
 
     A model that sows into its ``counters`` collection
     (``telemetry/counters.py``; an expert layer's dropped tokens and load)
@@ -545,7 +605,7 @@ def make_train_step(
         from .plan import PlanError, reduce_buckets_conflict, \
             shardings_use_axis
 
-        if loss_type == NEXT_TOKEN:
+        if loss_of(loss_type).tokens:
             raise PlanError(
                 "train.reduce_buckets is the convolutional nets' bucketed "
                 "reduce (cross-replica BatchNorm inside shard_map); the "
@@ -759,16 +819,21 @@ def make_eval_step(model, loss_weights: tuple[float, ...] | None = None,
     the prepared val path (data.val_prepared + data.packbits_masks): the
     mask is 25% of the 3-channel uint8 val batch's bytes.
 
-    ``loss_type="next_token"``: ``(state, {"tokens"}) -> ((), loss)`` — the
-    mean next-token cross-entropy alone (no prediction module, no logits
-    handed back: they are the vocabulary times the batch)."""
+    A token loss type: ``(state, {"tokens", ...}) -> ((), loss)`` — the
+    token task's loss alone over the model's evaluation outputs (no
+    prediction module, no logits handed back: they are the vocabulary times
+    the batch); ``preprocess`` adds what the loss reads beside the ids (a
+    block-diffusion loss's noise, from a fixed key)."""
+    entry = loss_of(loss_type)
 
     def token_step_fn(state: TrainState, batch: Batch):
-        (logits,) = model.apply(
+        if preprocess is not None:
+            batch = preprocess(batch)
+        outputs = model.apply(
             {"params": state.params, "batch_stats": state.batch_stats},
-            batch[TOKENS_KEY], train=False)
+            *(batch[k] for k in entry.inputs), train=False)
         with jax.named_scope(scopes.LOSS):
-            return (), next_token_xent(logits, batch[TOKENS_KEY])
+            return (), entry.loss(outputs, batch, None)
 
     def step_fn(state: TrainState, batch: Batch):
         if packbits_masks:
@@ -783,7 +848,7 @@ def make_eval_step(model, loss_weights: tuple[float, ...] | None = None,
             loss = _compute_loss(outputs, batch, loss_weights, loss_type)
         return outputs, loss
 
-    if loss_type == NEXT_TOKEN:
+    if entry.tokens:
         step_fn = token_step_fn
     if mesh is None:
         return jax.jit(step_fn)

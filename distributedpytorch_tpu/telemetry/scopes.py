@@ -55,6 +55,14 @@ CAUSAL_ATTN_BWD = "causal_attn_bwd"
 CAUSAL_ATTN_BWD_FUSED = "causal_attn_bwd_fused"
 CAUSAL_ATTN_BWD_DKV = "causal_attn_bwd_dkv"
 CAUSAL_ATTN_BWD_DQ = "causal_attn_bwd_dq"
+#: the same kernels under a block-diffusion mask (``models/sdar_lm.py``; the
+#: rule ``ops/pallas_attention.py::BlockDiffusion``).  Names of their own:
+#: the causal cells' roofline pattern holds on to ``causal_attn…``
+BLOCKDIFF_ATTN = "blockdiff_attn"
+BLOCKDIFF_ATTN_BWD = "blockdiff_attn_bwd"
+BLOCKDIFF_ATTN_BWD_FUSED = "blockdiff_attn_bwd_fused"
+BLOCKDIFF_ATTN_BWD_DKV = "blockdiff_attn_bwd_dkv"
+BLOCKDIFF_ATTN_BWD_DQ = "blockdiff_attn_bwd_dq"
 #: a learned sparse attention's Mosaic calls (``models/keye_lm.py``): the
 #: causal kernels given a per-query key set, the head-averaged probabilities
 #: its selector is aligned with, the selector's index scores forward and

@@ -513,8 +513,8 @@ class SentinelConfig:
 @dataclass
 class Config:
     task: str = "instance"              # instance (reference) | semantic
-                                        # | tokens (next-token training
-                                        # of model.name=nemotron_h)
+                                        # | tokens (a token model under
+                                        # its loss: models.TOKEN_MODELS)
     data: DataConfig = field(default_factory=DataConfig)
     model: ModelConfig = field(default_factory=ModelConfig)
     train: TrainConfig = field(default_factory=TrainConfig)

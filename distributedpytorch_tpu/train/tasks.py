@@ -102,6 +102,9 @@ class Task:
     #: (cfg, device_augment, device_guidance) -> the step's fused stage or
     #: None; no member at all: no device stage, and no flip to one
     device_stage: Callable | None = None
+    #: (task, cfg) -> the task as this configuration has it, where the
+    #: model's configuration decides part of it (:func:`get` with ``cfg``)
+    for_config: Callable | None = None
 
 
 # ------------------------------------------------------------ image tasks
@@ -414,14 +417,34 @@ def _jaccard(metrics: dict) -> tuple:
 
 
 # ------------------------------------------------------------- token task
+def _token_model(cfg):
+    """The configuration's token model: a description until it is
+    initialised, so building it to ask it something is free."""
+    return build_from_config(cfg.model, dtype=cfg.model.dtype)
+
+
+def _tokens_for_config(task: "Task", cfg) -> "Task":
+    """The token task as the model's configuration has it: the loss type
+    the model trains under (next-token unless it says otherwise) and the
+    device stage its loss needs (a block-diffusion model's noise,
+    ``ops/diffusion.py``: the step's first on-device data stage)."""
+    model = _token_model(cfg)
+    loss_type = getattr(model, "loss_type", task.loss_type)
+    stage = getattr(model, "device_stage", None)
+    if loss_type == task.loss_type and stage is None:
+        return task  # the model states nothing of its own
+    return dataclasses.replace(
+        task, loss_type=loss_type,
+        device_stage=stage and (lambda cfg, augment, guidance: stage))
+
+
 def _tokens_datasets(cfg, ctx: DataContext) -> tuple:
     """(train, val) token sources (data/tokens.py): the packed uint32
     file when ``data.token_file`` names one — its last
     ``token_val_samples`` windows are the val split — else the seeded
     synthetic source."""
-    # the ids a source may draw are the model's to say; the module is a
-    # description until it is initialised, so building it here is free
-    vocab = build_from_config(cfg.model, dtype=cfg.model.dtype).vocab_size
+    # the ids a source may draw are the model's to say
+    vocab = _token_model(cfg).vocab_size
     n_val = cfg.data.token_val_samples
     if cfg.data.token_file:
         whole = PackedTokens(cfg.data.token_file, cfg.data.seq_len)
@@ -450,7 +473,7 @@ def _tokens_memory_inputs(cfg, model, state_struct) -> tuple:
 
 
 def _tokens_evaluate(eval_step, state, loader, cfg, mesh, val_wire):
-    # mean next-token loss over the val sequences (the loader
+    # mean of the token task's loss over the val sequences (the loader
     # wrap-pads its last batch: every sequence is scored)
     losses = [eval_step(state, b)[1] for b in prefetch_to_device(
         iter(loader), mesh, size=cfg.data.device_prefetch,
@@ -484,10 +507,13 @@ SEMANTIC = Task(
     train_transform=_semantic_train_transform,
     device_stage=functools.partial(_image_device_stage, semantic=True))
 
-#: next-token training of a token model: another batch ({tokens}), another
-#: loss, no BatchNorm statistics
+#: training of a token model under the token task's loss: another batch
+#: ({tokens}), another loss, no BatchNorm statistics.  The loss type and the
+#: device stage are the model's configuration's to say (``for_config``):
+#: next-token and none, unless the model states its own
 TOKENS = Task(
     name="tokens", loss_type=NEXT_TOKEN, device_keys=(TOKENS_KEY,),
+    for_config=_tokens_for_config,
     check=_tokens_check, datasets=_tokens_datasets,
     init_input=lambda cfg: {"input_shape": (1, cfg.data.seq_len),
                             "input_dtype": jnp.int32},
@@ -499,7 +525,11 @@ TOKENS = Task(
 TASKS = {t.name: t for t in (INSTANCE, SEMANTIC, TOKENS)}
 
 
-def get(name: str) -> Task:
+def get(name: str, cfg=None) -> Task:
+    """The task of that name; with ``cfg``, as that configuration has it."""
     if name not in TASKS:
         raise ValueError(f"unknown task: {name!r} ({' | '.join(TASKS)})")
-    return TASKS[name]
+    task = TASKS[name]
+    if cfg is not None and task.for_config is not None:
+        return task.for_config(task, cfg)
+    return task

@@ -23,6 +23,7 @@ threshold-max Jaccard gating best saves.
 from __future__ import annotations
 
 import contextlib
+import functools
 import json
 import os
 import sys
@@ -191,7 +192,7 @@ class Trainer:
                 f"{' | '.join(model_tasks(cfg.model.name))}")
         #: what the loop is told of the task (train/tasks.py): it asks this
         #: object, never the task's name
-        self.task = tasks.get(cfg.task)
+        self.task = tasks.get(cfg.task, cfg)
         self.task.check(cfg)
         if cfg.data.echo < 1:
             raise ValueError(f"data.echo must be >= 1, got {cfg.data.echo}")
@@ -443,8 +444,8 @@ class Trainer:
                 shard_opt_state=self.plan.shard_opt_state)
         loss_weights = cfg.model.loss_weights
         if loss_weights is None:
-            # a model may state its own (a token model: the next-token
-            # head, then its prediction module's lambda)
+            # a model may state its own (a token model: its first head,
+            # then its prediction module's lambda)
             loss_weights = getattr(self.model, "loss_weights", None)
         # The plan's TP / ZeRO-1 layouts flow from the created state
         # into the compiled steps (live shardings — exactly what
@@ -452,6 +453,13 @@ class Trainer:
         st_sh = self.plan.state_shardings(self.state, self.mesh)
         augment = self.task.device_stage and self.task.device_stage(
             cfg, cfg.data.device_augment, cfg.data.device_guidance)
+        eval_stage = None
+        if augment is not None and self.task.train_transform is None:
+            # no augmentation moved off the host: a stage the task's loss
+            # cannot do without (a token model's noise), which evaluation
+            # runs too, from a fixed key
+            eval_stage = functools.partial(augment,
+                                           rng=jax.random.PRNGKey(0))
         # --- self-healing sentinel (train/sentinel.py; see fit()): built
         # before the steps because monitor_grads changes their outputs
         sc = cfg.sentinel
@@ -549,7 +557,7 @@ class Trainer:
         self.eval_step = make_eval_step(
             self.model, loss_weights=loss_weights, mesh=self.mesh,
             loss_type=self.task.loss_type, state_shardings=st_sh,
-            preprocess=val_wire.preprocess(cfg),
+            preprocess=eval_stage or val_wire.preprocess(cfg),
             packbits_masks=val_wire.packbits)
 
         # --- checkpointing
@@ -986,7 +994,8 @@ class Trainer:
         the reason as a RECOMMENDATION naming the config keys — the
         governor logs it instead of acting."""
         cfg = self.cfg
-        if self.task.device_stage is None:
+        if self.task.device_stage is None \
+                or self.task.train_transform is None:
             return False, (f"task={self.task.name} has no host "
                            "augmentation to move")
         moves_guidance = self.task.guidance_on_device(cfg)

@@ -125,7 +125,8 @@ def interpreted_kernels(monkeypatch):
     for name in ("flash_position_attention", "flash_channel_attention",
                  "flash_causal_attention", "flash_sparse_attention",
                  "flash_head_mean_probs", "flash_indexer_scores",
-                 "flash_indexer_scores_grads", "flash_topk_keep"):
+                 "flash_indexer_scores_grads", "flash_topk_keep",
+                 "flash_block_diffusion_attention"):
         monkeypatch.setattr(
             pa, name, functools.partial(getattr(pa, name), interpret=True))
 

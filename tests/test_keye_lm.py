@@ -187,24 +187,39 @@ def test_reference_blocks_change_memory_not_arithmetic(whole, monkeypatch):
 
 
 # ---------------------------------------------------------- (b) the share
-@pytest.mark.parametrize("held", [4, 1, 8])
-def test_expert_shares_add_up_to_the_uncut_layer(held):
+def _sdar_share():
+    """``sdar_lm``'s expert layer is this model's ``GatedMoE`` under its own
+    configuration, against its own plain reference (8 shares of 1: the
+    benchmark cell's eight-way cut)."""
+    from reference import sdar_lm as sdar_ref
+
+    from distributedpytorch_tpu.models import sdar_lm
+
+    return sdar_ref, sdar_lm.LMConfig, dict(sdar_lm.PRESETS["tiny"])
+
+
+@pytest.mark.parametrize("held,family", [(4, "keye"), (1, "keye"),
+                                         (8, "keye"), (1, "sdar")])
+def test_expert_shares_add_up_to_the_uncut_layer(held, family):
     """The routed parts that every share of the experts computes (each with
-    the router as wide as all of them) add up to what the uncut reference
-    gives for the whole layer; the program's share is the reference's."""
-    uncut = tiny(num_experts=8)
-    full = ref.make_weights(jax.random.PRNGKey(5), uncut)["l01"]
+    the router as wide as all of them; nothing is shared by the shares: no
+    shared expert) add up to what the uncut reference gives for the whole
+    layer; the program's share is the reference's."""
+    ref_mod, config_cls, preset = (ref, kl.LMConfig, tiny()) \
+        if family == "keye" else _sdar_share()
+    uncut = dict(preset, num_experts=8)
+    full = ref_mod.make_weights(jax.random.PRNGKey(5), uncut)["l01"]
     u = jax.random.normal(jax.random.PRNGKey(6), (2, 16, 64))
-    x = ref.rms_norm(u, full["norm"], uncut["rms_norm_eps"])
-    want = ref.gated_moe(full, x, uncut)
+    x = ref_mod.rms_norm(u, full["norm"], uncut["rms_norm_eps"])
+    want = ref_mod.gated_moe(full, x, uncut)
     total = jnp.zeros_like(want)
     for off in range(0, 8, held):
-        cfg = tiny(num_experts=held, expert_offset=off,
+        cfg = dict(preset, num_experts=held, expert_offset=off,
                    published={"num_experts": 8})
         share = dict(full, w1=full["w1"][off:off + held],
                      w2=full["w2"][off:off + held])
-        part = ref.gated_moe(share, x, cfg)
-        out, _ = kl.GatedMoE(kl.LMConfig.from_dict(cfg), jnp.float32).apply(
+        part = ref_mod.gated_moe(share, x, cfg)
+        out, _ = kl.GatedMoE(config_cls.from_dict(cfg), jnp.float32).apply(
             {"params": share}, u, mutable=["counters"])
         assert rel_gap(out - u, part) <= OUT_RTOL
         total = total + part
@@ -683,8 +698,8 @@ def test_scope_table_holds_the_new_parts(whole):
 
 
 def test_token_models_are_one_table():
-    assert set(TOKEN_MODELS) == {"nemotron_h", "keye_lm"}
-    assert MODEL_TASKS == {"nemotron_h": ("tokens",), "keye_lm": ("tokens",)}
+    assert set(TOKEN_MODELS) == {"nemotron_h", "keye_lm", "sdar_lm"}
+    assert MODEL_TASKS == {name: ("tokens",) for name in TOKEN_MODELS}
     assert isinstance(build_model("keye_lm"), kl.KeyeLM)
     assert isinstance(build_model("nemotron_h"), nh.NemotronH)
     with pytest.raises(ValueError, match="nemotron_h | keye_lm"):

@@ -451,3 +451,81 @@ def test_sparse_attention_block_compiles_at_the_cells_shape(v5e_mesh,
         events = [ln.split(" = ")[0].strip() + " custom-call"
                   for ln in _custom_calls(hlo)]
         assert sum(bool(rx.search(e)) for e in events) == n, metric
+
+
+def test_block_diffusion_attention_block_compiles_at_the_cells_shape(
+        v5e_mesh, monkeypatch):
+    """``sdar_lm``'s attention block with the flash kernels live under the
+    block-diffusion rule, forward and reverse under ``nn.remat``'s policy,
+    in bfloat16 over one sequence of 4,096 tokens — 8,192 positions, clean ‖
+    noised — at the published widths (32 query heads to 4 key/value heads of
+    128, block length 4): ONE forward call and ONE fused reverse call (the
+    output and the log-sum-exp are kept: the replay runs no second forward
+    call), under their own names (neither the causal cells' roofline
+    pattern nor DANet's matches them, and the new metric's matches both);
+    no array of token pairs of any dtype; the scope table puts both calls
+    under ``attn``."""
+    import json
+    import os
+
+    from flax import linen as nn
+
+    from distributedpytorch_tpu.models import sdar_lm as sl
+
+    monkeypatch.setattr(danet_mod, "_on_tpu", lambda: True)
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "sdar_30b_a3b_stage_ep8.json")) as f:
+        cfg = sl.LMConfig.from_dict(json.load(f))
+    assert (cfg.q_heads, cfg.kv_heads, cfg.head_dim, cfg.block_length) == (
+        32, 4, 128, 4)
+    one = _one_chip(v5e_mesh)
+    positions = 2 * 4096
+
+    class Block(nn.Module):
+        @nn.compact
+        def __call__(self, u):
+            with jax.named_scope(scopes.ATTN):
+                return nn.remat(
+                    sl.BlockDiffusionAttention,
+                    policy=sl._KEEP_FLASH_RESIDUALS)(
+                        cfg, jnp.bfloat16, name="l00")(u)
+
+    layer = Block()
+    u = jax.ShapeDtypeStruct((1, positions, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, cfg.hidden_size),
+                                             jnp.bfloat16)))["params"])
+
+    def loss(p, v):
+        return layer.apply({"params": p}, v).astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, u).compile().as_text()
+    calls = [ln.split(" = ")[0].strip().lstrip("%")
+             for ln in _custom_calls(hlo)]
+    assert sorted(c.split(".")[0] for c in calls) == [
+        scopes.BLOCKDIFF_ATTN, scopes.BLOCKDIFF_ATTN_BWD_FUSED]
+    assert not re.search(rf"\[(\d+,)*{positions},{positions}\]", hlo)
+    for ln in _custom_calls(hlo):  # one key/value row a group, not repeated
+        operands = ln.split("operand_layout_constraints=", 1)[1]
+        assert f"bf16[32,{positions},128]" in operands, ln[:300]
+        assert operands.count(f"bf16[4,{positions},128]") == 2, ln[:300]
+    table = scopes.scope_table(hlo)
+    for call in calls:
+        assert table[call].layer == scopes.ATTN
+        assert table[call].path.startswith("attn/l00"), table[call]
+    with open(os.path.join(here, "benchmarks", "metrics",
+                           "blockdiff_attn_kernel_roofline.json")) as f:
+        mine = re.compile(json.load(f)["args"]["event_pattern"])
+    events = [f"%{c} custom-call" for c in calls]
+    assert all(mine.search(e) for e in events)
+    for other in ("attn_kernel_roofline", "sparse_attn_kernel_roofline",
+                  "pam_kernel_roofline", "pam_backward_kernel_roofline"):
+        with open(os.path.join(here, "benchmarks", "metrics",
+                               other + ".json")) as f:
+            theirs = re.compile(json.load(f)["args"]["event_pattern"])
+        assert not any(theirs.search(e) for e in events), other
