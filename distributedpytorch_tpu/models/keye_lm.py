@@ -21,7 +21,10 @@ Every layer is two blocks, each ``x <- x + f(RMSNorm(x))``:
 * :class:`GatedMoE` — a softmax router over ALL published experts, top-k
   renormalised, gated (SiLU) experts, no shared expert; the layer is told
   which experts it holds and computes their part of the result through the
-  dropless grouped product of ``parallel/moe.py``, as ``nemotron_h``'s does.
+  dropless grouped product of ``parallel/moe.py``, as ``nemotron_h``'s does;
+  a rematerialised block keeps the router's logits, the top-k's results and
+  the dispatch's rows (:data:`EXPERT_KEPT`), so its reverse pass replays no
+  product, selection or sort.
 
 then a final RMSNorm and the untied head.
 
@@ -364,6 +367,36 @@ class SparseAttention(nn.Module):
         return u + _dot(out, o_proj, self.dtype, out=u.dtype)
 
 
+#: ``checkpoint_name``s of what an expert block's reverse pass reads of its
+#: forward pass past a product or a selection: the router's logits (before
+#: the softmax, whose rule reads its own result), both results of the top-k
+#: (the normalisation reads the values) and the two arrays of the dispatch
+#: that the routed experts' residuals hold.  A block that keeps them replays
+#: no product, no top-k and no sort (the routed sum feeds the block's output
+#: alone, so the replay holds no forward chunk loop either way)
+EXPERT_KEPT = (_LOGITS, _TOP, _IDX, _ROWS, _GROUP_SIZES) = (
+    "moe_router_logits", "moe_topk_gates", "moe_topk_idx",
+    "moe_dispatch_rows", "moe_group_sizes")
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(1,))
+def chosen_gates(gates, k: int):
+    """``jax.lax.top_k(gates, k)``, differentiated through its *named*
+    results: ``top_k``'s own rule reads the ids it made itself, which no
+    policy keeps, so a rematerialised block would select again; and the
+    values gathered by kept ids (``take_along_axis``) cost the cell six
+    times the selection (PERF.md section 6, PR 38)."""
+    return tuple(jax.lax.top_k(gates, k))
+
+
+@chosen_gates.defjvp
+def _chosen_gates_jvp(k, primals, tangents):
+    top, idx = map(checkpoint_name, jax.lax.top_k(primals[0], k),
+                   (_TOP, _IDX))
+    return (top, idx), (jnp.take_along_axis(tangents[0], idx, axis=-1),
+                        np.zeros(idx.shape, jax.dtypes.float0))
+
+
 class GatedMoE(nn.Module):
     cfg: LMConfig
     dtype: Any = F32
@@ -380,14 +413,19 @@ class GatedMoE(nn.Module):
 
         x = rms_norm(u, norm, c.norm_eps).reshape(-1, d)
         with jax.named_scope(scopes.MOE_ROUTER):
-            gates = jax.nn.softmax(_dot32(x, router), axis=-1)
-            top, idx = jax.lax.top_k(gates, c.experts_per_token)
+            gates = jax.nn.softmax(
+                checkpoint_name(_dot32(x, router), _LOGITS), axis=-1)
+            top, idx = chosen_gates(gates, c.experts_per_token)
             weights = gates[:, off:off + held]
             if c.norm_topk:
                 weights = weights / top.sum(-1, keepdims=True)
         with jax.named_scope(scopes.MOE_DISPATCH):
             disp = moe_lib.dropless_dispatch(
                 idx, expert_offset=off, n_held=held)
+            disp = disp._replace(
+                rows=checkpoint_name(disp.rows, _ROWS),
+                group_sizes=checkpoint_name(disp.group_sizes,
+                                            _GROUP_SIZES))
         # names its own parts: dispatch / routed_experts / combine
         routed, chunks_run = moe_lib.dropless_routed(
             x, weights, w1, w2, disp, swiglu,
@@ -401,8 +439,20 @@ class GatedMoE(nn.Module):
         return u + routed.astype(u.dtype).reshape(u.shape)
 
 
+def expert_kept_bytes(c, tokens: int) -> int:
+    """Bytes of :data:`EXPERT_KEPT` in one expert block over ``tokens``
+    positions: float32 logits, the top-k's gates and ids, the row buffer's
+    tokens and the held experts' group sizes."""
+    return 4 * (tokens * (c.experts_total + 2 * c.experts_per_token)
+                + moe_lib.dropless_buffer_rows(
+                    tokens, c.experts_per_token, c.experts_held)
+                + c.experts_held)
+
+
 _KEEP_SPARSE_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
     *pallas_attention.SPARSE_KEPT_BY_REVERSE, *INDEX_GRADS_KEPT)
+KEEP_EXPERT_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
+    *EXPERT_KEPT)
 
 
 class KeyeLM(nn.Module):
@@ -442,7 +492,7 @@ class KeyeLM(nn.Module):
             # reads of the forward call, the key set, and the alignment
             # loss's gradient with respect to the indexer's operands
             attn = nn.remat(attn, policy=_KEEP_SPARSE_RESIDUALS)
-            moe = nn.remat(moe)
+            moe = nn.remat(moe, policy=KEEP_EXPERT_RESIDUALS)
         for i in range(c.layers):
             with jax.named_scope(scopes.ATTN):
                 x = attn(c, self.dtype, name=layer_name(2 * i))(x, positions)
@@ -459,9 +509,9 @@ class KeyeLM(nn.Module):
         planner's memory model (``parallel/plan.py``): what per-block
         recomputation keeps (every block's input; an attention block's
         output, log-sum-exp and key set, and the index scores' float32
-        gradients), the largest single block while it
-        is recomputed and differentiated, and the head's float32 logits
-        with their gradient."""
+        gradients; an expert block's :data:`EXPERT_KEPT`), the largest
+        single block while it is recomputed and differentiated, and the
+        head's float32 logits with their gradient."""
         c = self.cfg
         t = batch * seq_len
         item = jnp.dtype(self.dtype).itemsize
@@ -469,6 +519,8 @@ class KeyeLM(nn.Module):
         kept = (2 * c.layers + 2) * t * c.hidden_size * item
         if not self.remat:
             kept *= 8
+        else:
+            kept += c.layers * expert_kept_bytes(c, t)
         kept += c.layers * t * 4 * (
             c.index_heads * c.index_head_dim + c.index_head_dim
             + c.index_heads)

@@ -16,7 +16,9 @@ character of ``hybrid_override_pattern``:
 * ``E``  :class:`LatentMoE` — sigmoid router over ALL published experts,
   top-k with the score-correction bias, experts in a latent space, one shared
   expert; the layer is told which experts it holds and computes their part
-  of the result with the dropless grouped product of ``parallel/moe.py``.
+  of the result with the dropless grouped product of ``parallel/moe.py``; a
+  rematerialised block keeps the results of its products and selections
+  (:data:`EXPERT_KEPT`), so its reverse pass replays elementwise work only.
 
 then a final RMSNorm and the untied head, plus the multi-token-prediction
 module (:class:`MTPModule`, DeepSeek-V3 wiring, embedding and head shared).
@@ -42,6 +44,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import pallas_attention
 from ..ops.attention import causal_attention
@@ -349,6 +352,22 @@ class Attention(nn.Module):
         return u + _dot(out, o_proj, self.dtype, out=u.dtype)
 
 
+#: ``checkpoint_name``s of what an expert block's reverse pass reads of its
+#: forward pass past a product or a selection: the router's logits (before
+#: the sigmoid, whose rule reads its own result), its choice and the chosen
+#: scores (a gather of 22 in 512 a token costs three times the top-22 on
+#: the chip), the two arrays of the dispatch that the routed experts'
+#: residuals hold, the latent rows, the routed sum as ``latent_up``'s
+#: product reads it, and the shared expert's pre-activation.  A block that
+#: keeps them replays elementwise passes only: no product, no top-k, no
+#: gather, no sort, no chunk loop
+EXPERT_KEPT = (_LOGITS, _IDX, _CHOSEN, _ROWS, _GROUP_SIZES, _LOW, _ROUTED,
+               _PRE) = (
+    "moe_router_logits", "moe_topk_idx", "moe_chosen_scores",
+    "moe_dispatch_rows", "moe_group_sizes", "moe_latent_rows",
+    "moe_routed_sum", "moe_shared_pre")
+
+
 class LatentMoE(nn.Module):
     cfg: LMConfig
     dtype: Any = F32
@@ -378,27 +397,35 @@ class LatentMoE(nn.Module):
 
         x = rms_norm(u, norm, c.norm_eps).reshape(-1, d)
         with jax.named_scope(scopes.MOE_ROUTER):
-            scores = jax.nn.sigmoid(jnp.dot(x.astype(F32), router))
-            _, idx = jax.lax.top_k(scores + router_bias,
-                                   c.experts_per_token)
+            scores = jax.nn.sigmoid(checkpoint_name(
+                jnp.dot(x.astype(F32), router), _LOGITS))
+            idx = checkpoint_name(jax.lax.top_k(
+                scores + router_bias, c.experts_per_token)[1], _IDX)
             weights = scores[:, off:off + held]
             if c.norm_topk:
-                denom = jnp.take_along_axis(scores, idx, axis=-1).sum(-1)
-                weights = weights / (denom[:, None] + 1e-20)
+                chosen = checkpoint_name(
+                    jnp.take_along_axis(scores, idx, axis=-1), _CHOSEN)
+                weights = weights / (chosen.sum(-1)[:, None] + 1e-20)
             weights = weights * c.routed_scale                # (N, held)
         with jax.named_scope(scopes.MOE_LATENT):
-            low = _dot(x, latent_down, self.dtype)
+            low = checkpoint_name(_dot(x, latent_down, self.dtype), _LOW)
         with jax.named_scope(scopes.MOE_DISPATCH):
             disp = moe_lib.dropless_dispatch(
                 idx, expert_offset=off, n_held=held)
+            disp = disp._replace(
+                rows=checkpoint_name(disp.rows, _ROWS),
+                group_sizes=checkpoint_name(disp.group_sizes,
+                                            _GROUP_SIZES))
         # names its own parts: dispatch / routed_experts / combine
         routed, chunks_run = moe_lib.dropless_routed(
             low, weights, w1, w2, disp, relu2)
         with jax.named_scope(scopes.MOE_LATENT):
+            # in the product's operand dtype: the cast the product makes
+            routed = checkpoint_name(routed.astype(self.dtype), _ROUTED)
             routed = _dot(routed, latent_up, self.dtype, out=F32)
         with jax.named_scope(scopes.MOE_SHARED_EXPERT):
-            shared = _dot(relu2(_dot(x, shared_up, self.dtype)),
-                          shared_down, self.dtype, out=F32)
+            pre = checkpoint_name(_dot(x, shared_up, self.dtype), _PRE)
+            shared = _dot(relu2(pre), shared_down, self.dtype, out=F32)
         self.sow(counters.COLLECTION, moe_lib.COUNTER_DROPPED, disp.dropped)
         self.sow(counters.COLLECTION, moe_lib.COUNTER_LOAD,
                  moe_lib.expert_load_max_over_mean(disp))
@@ -406,27 +433,32 @@ class LatentMoE(nn.Module):
         return u + (routed + shared).astype(u.dtype).reshape(u.shape)
 
 
-_BLOCKS = {"M": (scopes.MAMBA, MambaMixer), "*": (scopes.ATTN, Attention),
-           "E": (scopes.MOE, LatentMoE)}
 _KEEP_FLASH_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
     *pallas_attention.KEPT_BY_REVERSE)
+_KEEP_EXPERT_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
+    *EXPERT_KEPT)
+#: kind -> (layer scope, block, what a rematerialised block keeps beside its
+#: input: an attention block what its flash reverse pass reads of the forward
+#: call (where the kernels run; nothing otherwise), an expert block the
+#: results of its products and selections)
+_BLOCKS = {"M": (scopes.MAMBA, MambaMixer, None),
+           "*": (scopes.ATTN, Attention, _KEEP_FLASH_RESIDUALS),
+           "E": (scopes.MOE, LatentMoE, _KEEP_EXPERT_RESIDUALS)}
 
 
 def _run_blocks(module: nn.Module, pattern: str, x, *, remat: bool):
     """The blocks of ``pattern`` as children ``l00``, ``l01``, ... of
     ``module``, each under its layer's scope and, with ``remat``, recomputed
-    whole in the reverse pass (only the block's input is kept)."""
+    in the reverse pass from the block's input and what :data:`_BLOCKS`
+    keeps for its kind."""
     c, dtype = module.cfg, module.dtype
     for i, kind in enumerate(pattern):
         if kind not in _BLOCKS:
             raise ValueError(f"unknown layer kind {kind!r} in pattern "
                              f"{pattern!r} (M | * | E)")
-        layer, cls = _BLOCKS[kind]
+        layer, cls, kept = _BLOCKS[kind]
         if remat:
-            # an attention block keeps what its flash reverse pass reads of
-            # the forward call (where the kernels run; nothing otherwise)
-            cls = nn.remat(cls, policy=_KEEP_FLASH_RESIDUALS
-                           if kind == "*" else None)
+            cls = nn.remat(cls, policy=kept)
         with jax.named_scope(layer):
             x = cls(c, dtype, name=layer_name(i))(x)
     return x
@@ -509,19 +541,29 @@ class NemotronH(nn.Module):
     def activation_bytes(self, batch: int, seq_len: int) -> int:
         """A bound on the step's live activations on one device, for the
         planner's memory model (``parallel/plan.py``): every block's input
-        (what per-block recomputation keeps), the largest single block
-        while it is recomputed and differentiated, and the two heads'
-        float32 logits with their gradients."""
+        and every expert block's :data:`EXPERT_KEPT` (what per-block
+        recomputation keeps), the largest single block while it is
+        recomputed and differentiated, and the two heads' float32 logits
+        with their gradients."""
         c = self.cfg
         t = batch * seq_len
         item = jnp.dtype(self.dtype).itemsize
-        n_blocks = len(c.pattern) + len(c.mtp_pattern) + 2
-        kept = n_blocks * t * c.hidden_size * item
+        blocks = c.pattern + c.mtp_pattern
+        kept = (len(blocks) + 2) * t * c.hidden_size * item
+        buffer_rows = moe_lib.dropless_buffer_rows(
+            t, c.experts_per_token, c.experts_held)
         if not self.remat:
             kept *= 8
+        else:  # float32 logits, ids and chosen scores (where the weights
+            # are normalised), rows, group sizes; then the latent rows, the
+            # routed sum and the shared expert's pre-activation
+            kept += blocks.count("E") * (
+                4 * (t * (c.experts_total
+                          + (1 + c.norm_topk) * c.experts_per_token)
+                     + buffer_rows + c.experts_held)
+                + item * t * (2 * c.latent_size + c.shared_hidden))
         # an expert layer's wide arrays are one chunk of the row buffer
-        rows = moe_lib.chunk_rows_of(moe_lib.dropless_buffer_rows(
-            t, c.experts_per_token, c.experts_held))
+        rows = moe_lib.chunk_rows_of(buffer_rows)
         if danet.auto_wants_flash(self.dtype):
             # the flash kernels: q, out and their gradients, the reverse
             # pass's float32 dQ and per-query-head dK, dV, then k, v and
@@ -538,7 +580,7 @@ class NemotronH(nn.Module):
             "M": 4 * t * c.mamba_heads * c.chunk_size * 4
             + 6 * t * (2 * c.mamba_inner + c.conv_dim) * 4,
         }
-        live = max(per_kind[k] for k in set(c.pattern + c.mtp_pattern))
+        live = max(per_kind[k] for k in set(blocks))
         heads = (2 if c.mtp_pattern else 1) * 2 * t * c.vocab_size * 4
         return int(kept + live + heads)
 

@@ -58,7 +58,8 @@ from ..ops import diffusion, pallas_attention
 from ..parallel import moe as moe_lib
 from ..telemetry import scopes
 from . import danet
-from .keye_lm import GatedMoE, expert_chunk_rows, rotary_angles, rotate
+from .keye_lm import (KEEP_EXPERT_RESIDUALS, GatedMoE, expert_chunk_rows,
+                      expert_kept_bytes, rotary_angles, rotate)
 from .nemotron_h import (_dense_init, _dot, _ones, layer_name, load_preset,
                          rms_norm)
 
@@ -225,7 +226,7 @@ class SdarLM(nn.Module):
             # per block; an attention block keeps what its reverse pass
             # reads of the forward call
             attn = nn.remat(attn, policy=_KEEP_FLASH_RESIDUALS)
-            moe = nn.remat(moe)
+            moe = nn.remat(moe, policy=KEEP_EXPERT_RESIDUALS)
         for i in range(c.layers):
             with jax.named_scope(scopes.ATTN):
                 x = attn(c, self.dtype, name=layer_name(2 * i))(x)
@@ -241,15 +242,18 @@ class SdarLM(nn.Module):
         """A bound on the step's live activations on one device, for the
         planner's memory model (``parallel/plan.py``): what per-block
         recomputation keeps over the 2·``seq_len`` positions (every block's
-        input; an attention block's output and log-sum-exp), the largest
-        single block while it is recomputed and differentiated, and the
-        head's float32 logits over ``seq_len`` with their gradient."""
+        input; an attention block's output and log-sum-exp; an expert
+        block's ``keye_lm.EXPERT_KEPT``), the largest single block while it
+        is recomputed and differentiated, and the head's float32 logits over
+        ``seq_len`` with their gradient."""
         c = self.cfg
         t = 2 * batch * seq_len
         item = jnp.dtype(self.dtype).itemsize
         kept = (2 * c.layers + 2) * t * c.hidden_size * item
         if not self.remat:
             kept *= 8
+        else:
+            kept += c.layers * expert_kept_bytes(c, t)
         if danet.auto_wants_flash(self.dtype):
             kept += c.layers * t * c.q_heads * (c.head_dim * item + 4)
             attn = t * c.head_dim * (c.q_heads * (4 * item + 3 * 4)

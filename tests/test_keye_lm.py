@@ -31,6 +31,7 @@ sys.path.insert(0, os.path.join(REPO, "benchmarks"))
 
 from reference import keye_lm as ref  # noqa: E402
 
+import expert_remat_cases as kept_cases  # noqa: E402
 from distributedpytorch_tpu.models import (MODEL_TASKS, TOKEN_MODELS,  # noqa: E402
                                            build_model)
 from distributedpytorch_tpu.models import keye_lm as kl  # noqa: E402
@@ -557,17 +558,6 @@ def test_alignment_loss_is_the_kl_and_its_gradient(small_tiles, form, s,
         assert rel_gap(g3, 3 * g1) <= 1e-5
 
 
-def eqns_named(jaxpr, primitive):
-    """Every equation of ``primitive`` in ``jaxpr``, sub-jaxprs included."""
-    found = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == primitive:
-            found.append(eqn)
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            found += eqns_named(sub, primitive)
-    return found
-
-
 def calls_named(text, name):
     return text.count(f"name={name}\n") + text.count(f"name={name} ")
 
@@ -612,7 +602,7 @@ def test_replay_recomputes_neither_scores_nor_target(remat_block):
     form, loss, params, u, calls = remat_block
     jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(params, u)
     assert len(calls) == 1
-    replay, = eqns_named(jaxpr.jaxpr, "remat2")
+    replay, = kept_cases.eqns_named(jaxpr.jaxpr, "remat2")
     assert replay.params["differentiated"]
     inside, whole = str(replay.params["jaxpr"]), str(jaxpr)
     if form == "kernel":
@@ -868,3 +858,65 @@ def test_expert_chunks_hold_the_expected_rows_and_a_quarter_more(expected,
     if expected <= 2 * moe_lib.CHUNK_ROWS:  # a few chunks: none of them
         # begins between the expected rows and a quarter more
         assert -(-int(1.25 * expected) // rows) == chunks
+
+
+# ------------------------------- what a rematerialised expert block keeps
+@pytest.mark.parametrize("policy,runs", [("kept", 1), ("bare", 2)])
+def test_replay_of_an_expert_block_holds_no_product_or_selection(policy,
+                                                                 runs):
+    """The gradient of a block that keeps ``EXPERT_KEPT`` runs the router's
+    product and the top-k once, under a bare ``nn.remat`` twice; the routed
+    sum feeds the block's output alone, so the forward chunk loop is dead
+    in the replay either way."""
+    cfg = kl.LMConfig.from_dict(tiny())
+    got = kept_cases.replay_counts(
+        *kept_cases.block_case(
+            kl.GatedMoE, cfg,
+            kl.KEEP_EXPERT_RESIDUALS if policy == "kept" else None),
+        widths=(cfg.experts_total,), k=cfg.experts_per_token)
+    # no gather either way: the chosen gates are the top-k's own values
+    assert got == {"top_k": runs, "gathers": 0, "products": [runs],
+                   "forward_loops": 1}
+
+
+def test_model_rematerialises_its_expert_blocks_under_the_policy():
+    """The whole model's gradient holds one router product a layer (a
+    router 12 wide: no other weight of the preset is)."""
+    cfg = tiny(published={"num_experts": 12})
+    model = build_model("keye_lm", lm_config=cfg)
+    tokens = jnp.zeros((2, 20), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            tokens)["params"]
+    assert model.remat
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: program_loss(model, p, tokens)))(params).jaxpr
+    assert kept_cases.forward_products(
+        jaxpr, tokens.size, cfg["hidden_size"], 12) \
+        == cfg["num_hidden_layers"]
+
+
+def test_kept_expert_block_gives_the_bare_blocks_loss_and_gradients():
+    kept_cases.assert_kept_block_is_the_bare_blocks(
+        kl.GatedMoE, kl.LMConfig.from_dict(tiny()),
+        kl.KEEP_EXPERT_RESIDUALS, GRAD_RTOL)
+
+
+def test_activation_bytes_count_what_an_expert_block_keeps(monkeypatch):
+    """The planner is charged every layer's named arrays: float32 logits,
+    the top-k's gates and ids, the rows and the group sizes."""
+    cfg, batch, length = tiny(), 2, 24
+    model = kl.build_keye_lm(cfg)
+    kept = kept_cases.kept_bytes(kl.GatedMoE, model.cfg, kl.EXPERT_KEPT,
+                                 batch, length, jnp.float32)
+    tokens = batch * length
+    assert kept == kl.expert_kept_bytes(model.cfg, tokens) \
+        == 4 * (tokens * (8 + 2 * 2) + tokens * 2 + 4)
+
+    def acts():
+        return [kl.build_keye_lm(cfg, remat=r).activation_bytes(batch, length)
+                for r in (True, False)]
+
+    charged = acts()
+    monkeypatch.setattr(kl, "expert_kept_bytes", lambda c, t: 0)
+    kept_once, plain = (a - b for a, b in zip(charged, acts()))
+    assert (kept_once, plain) == (cfg["num_hidden_layers"] * kept, 0)

@@ -28,6 +28,7 @@ sys.path.insert(0, os.path.join(REPO, "benchmarks"))
 
 from reference import nemotron_h as ref  # noqa: E402
 
+import expert_remat_cases as kept_cases  # noqa: E402
 from distributedpytorch_tpu.models import build_model  # noqa: E402
 from distributedpytorch_tpu.models import nemotron_h as nh  # noqa: E402
 from distributedpytorch_tpu.parallel import (  # noqa: E402
@@ -726,3 +727,75 @@ def test_benchmark_configuration_keeps_every_published_width():
     n = sum(int(np.prod(s)) for s, _ in jax.tree.leaves(
         ref.param_spec(cfg), is_leaf=ref._is_leaf))
     assert 837e6 < n < 839e6
+
+
+# ------------------------------- what a rematerialised expert block keeps
+@pytest.mark.parametrize("policy,runs", [("kept", 1), ("bare", 2)])
+def test_replay_of_an_expert_block_holds_no_product_selection_or_loop(
+        policy, runs):
+    """The gradient of a block that keeps ``EXPERT_KEPT`` runs the top-22,
+    the gather of the chosen scores, the router's, ``latent_down``'s and
+    ``shared_up``'s products and the forward chunk loop once; under a bare
+    ``nn.remat`` each runs twice (the routed sum feeds ``latent_up``'s
+    weight gradient, so the loop is live in the replay)."""
+    cfg = nh.LMConfig.from_dict(tiny())
+    got = kept_cases.replay_counts(
+        *kept_cases.block_case(
+            nh.LatentMoE, cfg,
+            nh._KEEP_EXPERT_RESIDUALS if policy == "kept" else None),
+        widths=(cfg.experts_total, cfg.latent_size, cfg.shared_hidden),
+        k=cfg.experts_per_token)
+    assert got == {"top_k": runs, "gathers": runs, "products": [runs] * 3,
+                   "forward_loops": runs}
+
+
+def test_model_rematerialises_its_expert_blocks_under_the_policy(whole,
+                                                                 share_cfg):
+    """``_run_blocks`` wraps every ``E`` block, the prediction module's
+    too: the whole model's gradient holds one router product and one top-k
+    a block."""
+    model, params, tokens = whole
+    assert model.remat
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: sum(
+        (o ** 2).sum() for o in model.apply(
+            {"params": p}, tokens, train=True,
+            mutable=["counters"])[0])))(params).jaxpr
+    blocks = (share_cfg["hybrid_override_pattern"]
+              + share_cfg["mtp_hybrid_override_pattern"]).count("E")
+    assert blocks == 3
+    assert len(kept_cases.eqns_named(jaxpr, "top_k")) == blocks
+    assert kept_cases.forward_products(
+        jaxpr, tokens.size, share_cfg["hidden_size"],
+        share_cfg["published"]["n_routed_experts"]) == blocks
+
+
+def test_kept_expert_block_gives_the_bare_blocks_loss_and_gradients():
+    kept_cases.assert_kept_block_is_the_bare_blocks(
+        nh.LatentMoE, nh.LMConfig.from_dict(tiny()),
+        nh._KEEP_EXPERT_RESIDUALS, GRAD_RTOL)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_activation_bytes_count_what_an_expert_block_keeps(dtype):
+    """One more ``E`` block costs the planner its input and the bytes of
+    the arrays the block names; with no recomputation nothing is named
+    apart."""
+    cfg, batch, length = tiny(num_nextn_predict_layers=0), 2, 24
+    item = jnp.dtype(dtype).itemsize
+
+    def act(pattern, remat=True):
+        return nh.build_nemotron_h(
+            dict(cfg, hybrid_override_pattern=pattern), dtype=dtype,
+            remat=remat).activation_bytes(batch, length)
+
+    block_input = batch * length * cfg["hidden_size"] * item
+    kept = kept_cases.kept_bytes(nh.LatentMoE, nh.LMConfig.from_dict(cfg),
+                                 nh.EXPERT_KEPT, batch, length, dtype)
+    tokens = batch * length
+    # logits and chosen scores float32, ids, rows and group sizes int32;
+    # three in ``dtype``
+    assert kept == 4 * (tokens * (8 + 2 * 3) + tokens * 3 + 8) \
+        + item * tokens * (2 * 32 + 96)
+    assert act("MEE") - act("ME") == block_input + kept
+    assert act("MEE", remat=False) - act("ME", remat=False) \
+        == 8 * block_input

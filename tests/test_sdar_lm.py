@@ -36,6 +36,7 @@ sys.path.insert(0, os.path.join(REPO, "benchmarks"))
 from reference import nets  # noqa: E402
 from reference import sdar_lm as ref  # noqa: E402
 
+import expert_remat_cases as kept_cases  # noqa: E402
 import flash_jaxpr_cases  # noqa: E402
 from distributedpytorch_tpu.models import build_model  # noqa: E402
 from distributedpytorch_tpu.models import sdar_lm as sl  # noqa: E402
@@ -709,3 +710,61 @@ def test_expert_chunk_rows_are_the_configurations_where_it_states_them():
     buffer_rows = moe_lib.dropless_buffer_rows(8192, 8, 16)
     assert moe_lib.chunk_rows_of(buffer_rows, 17408) == 17408
     assert sl.build_sdar_lm(tiny()).cfg.expert_chunk_rows is None
+
+
+# ------------------------------- what a rematerialised expert block keeps
+@pytest.mark.parametrize("policy,runs", [("kept", 1), ("bare", 2)])
+def test_replay_of_an_expert_block_holds_no_product_or_selection(policy,
+                                                                 runs):
+    """The Keye model's expert block under this model's configuration: one
+    router product and one top-k in the gradient where a bare ``nn.remat``
+    runs two."""
+    cfg = sl.LMConfig.from_dict(tiny())
+    got = kept_cases.replay_counts(
+        *kept_cases.block_case(
+            sl.GatedMoE, cfg,
+            sl.KEEP_EXPERT_RESIDUALS if policy == "kept" else None),
+        widths=(cfg.experts_total,), k=cfg.experts_per_token)
+    # no gather either way: the chosen gates are the top-k's own values
+    assert got == {"top_k": runs, "gathers": 0, "products": [runs],
+                   "forward_loops": 1}
+
+
+def test_model_rematerialises_its_expert_blocks_under_the_policy(whole):
+    """The whole model's gradient, over the doubled sequence, holds one
+    router product and one top-k a layer."""
+    cfg, model, params, batch = whole
+    assert model.remat
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: program_loss(model, p, batch)[0]))(params).jaxpr
+    assert len(kept_cases.eqns_named(jaxpr, "top_k")) \
+        == cfg["num_hidden_layers"]
+    assert kept_cases.forward_products(
+        jaxpr, 2 * batch["tokens"].size, cfg["hidden_size"],
+        cfg["published"]["num_experts"]) == cfg["num_hidden_layers"]
+
+
+def test_kept_expert_block_gives_the_bare_blocks_loss_and_gradients():
+    kept_cases.assert_kept_block_is_the_bare_blocks(
+        sl.GatedMoE, sl.LMConfig.from_dict(tiny()),
+        sl.KEEP_EXPERT_RESIDUALS, GRAD_RTOL)
+
+
+def test_activation_bytes_count_what_an_expert_block_keeps(monkeypatch):
+    """The planner is charged every layer's named arrays over the doubled
+    sequence."""
+    from distributedpytorch_tpu.models.keye_lm import EXPERT_KEPT
+
+    cfg, batch, length = tiny(), 2, 24
+    model = sl.build_sdar_lm(cfg)
+    kept = kept_cases.kept_bytes(sl.GatedMoE, model.cfg, EXPERT_KEPT, batch,
+                                 2 * length, jnp.float32)
+
+    def acts():
+        return [sl.build_sdar_lm(cfg, remat=r).activation_bytes(batch, length)
+                for r in (True, False)]
+
+    charged = acts()
+    monkeypatch.setattr(sl, "expert_kept_bytes", lambda c, t: 0)
+    kept_once, plain = (a - b for a, b in zip(charged, acts()))
+    assert (kept_once, plain) == (cfg["num_hidden_layers"] * kept, 0)
