@@ -29,6 +29,9 @@ from typing import Iterator, Sequence
 
 import numpy as np
 
+from ..telemetry import feed
+from ..telemetry.trace import annotation
+from ..telemetry.scopes import INPUT_BATCH
 from . import transforms as T
 
 #: default guidance channel, matching the live reference pipeline
@@ -469,8 +472,12 @@ class DataLoader:
             # index-level skip: the skipped batches cost nothing (no decode)
             batches = batches[self.start_batch:]
         if self.num_workers == 0:
-            for idxs in batches:
-                yield collate([self._load_one(i) for i in idxs])
+            for b, idxs in enumerate(batches, self.start_batch):
+                # no producer: every batch is built while the consumer waits
+                feed.COUNTS.batch += 1
+                with annotation(INPUT_BATCH, batch=b):
+                    batch = collate([self._load_one(i) for i in idxs])
+                yield batch
             return
         yield from self._iter_prefetched(batches)
 
@@ -512,11 +519,15 @@ class DataLoader:
         def producer():
             with cf.ThreadPoolExecutor(max_workers=self.num_workers) as pool:
                 try:
-                    for idxs in batches:
+                    for b, idxs in enumerate(batches, self.start_batch):
                         if stop.is_set():
                             return
-                        samples = list(pool.map(self._load_one, idxs))
-                        if not put_bounded(collate(samples)):
+                        # the feed's time busy: first sample to collate,
+                        # not the wait for room in the queue after it
+                        with annotation(INPUT_BATCH, batch=b):
+                            batch = collate(
+                                list(pool.map(self._load_one, idxs)))
+                        if not put_bounded(batch):
                             return
                 except BaseException as e:  # surface worker errors to consumer
                     # UNbounded put: an error must reach the consumer
@@ -531,6 +542,9 @@ class DataLoader:
         t.start()
         try:
             while True:
+                # whom the loop waits on: a queue found empty here is a
+                # batch the producer had not built when its consumer came
+                ready = not out_q.empty()
                 item = out_q.get()
                 with room:
                     room.notify()
@@ -538,6 +552,8 @@ class DataLoader:
                     break
                 if isinstance(item, BaseException):
                     raise item
+                feed.COUNTS.batch += 1
+                feed.COUNTS.batch_ready += ready
                 yield item
         finally:
             stop.set()

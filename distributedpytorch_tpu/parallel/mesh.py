@@ -36,6 +36,9 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..chaos import sites as chaos_sites
+from ..telemetry import feed
+from ..telemetry.trace import annotation
+from ..telemetry.scopes import INPUT_PLACE
 
 #: canonical axis names, in mesh order
 DATA_AXIS = "data"
@@ -237,7 +240,7 @@ def shard_batch(mesh: Mesh, batch: Mapping[str, np.ndarray]) -> dict:
 
 def prefetch_to_device(batches, mesh: Mesh, size: int = 2,
                        keys: tuple[str, ...] | None = None,
-                       transform=None):
+                       transform=None, start: int = 0):
     """Iterate ``batches`` with up to ``size`` of them already placed on the
     mesh (batch-dim sharded) ahead of consumption.
 
@@ -265,35 +268,49 @@ def prefetch_to_device(batches, mesh: Mesh, size: int = 2,
     filter would be pointless — it may introduce new keys, so it runs
     first).  Used by data.coalesce_wire to keep the full-batch pack memcpy
     off the dispatch thread.
+
+    ``start`` is the first batch's index in its epoch (a resumed epoch
+    starts past 0): what the ``input/place`` span of each batch carries,
+    as the loader's ``input/batch`` span of the same batch does.
     """
     import collections
     import concurrent.futures as cf
 
-    def place(batch):
-        if transform is not None:
-            batch = transform(batch)
-        if keys is not None:
-            batch = {k: v for k, v in batch.items() if k in keys}
-        # chaos seam: latency here is a slow H2D pipe, raised errors are
-        # a dying transfer, poisoning tears the host batch pre-placement
-        batch = chaos_sites.fire("device/put", payload=batch)
-        return shard_batch(mesh, batch)
+    def place(i, batch):
+        with annotation(INPUT_PLACE, batch=i):
+            if transform is not None:
+                batch = transform(batch)
+            if keys is not None:
+                batch = {k: v for k, v in batch.items() if k in keys}
+            # chaos seam: latency here is a slow H2D pipe, raised errors
+            # are a dying transfer, poisoning tears the host batch
+            # pre-placement
+            batch = chaos_sites.fire("device/put", payload=batch)
+            return shard_batch(mesh, batch)
 
     if not callable(size) and size <= 0:  # synchronous degradation
-        for batch in batches:
-            yield place(batch)
+        for i, batch in enumerate(batches, start):
+            yield place(i, batch)
         return
     live_size = size if callable(size) else (lambda: size)
 
     futures: collections.deque = collections.deque()
+
+    def fetch():
+        """The head placement, counted: was it done when the loop came?"""
+        head = futures.popleft()
+        feed.COUNTS.fetch += 1
+        feed.COUNTS.fetch_ready += head.done()
+        return head.result()
+
     with cf.ThreadPoolExecutor(max_workers=1) as pool:
         try:
-            for batch in batches:
-                futures.append(pool.submit(place, batch))
+            for i, batch in enumerate(batches, start):
+                futures.append(pool.submit(place, i, batch))
                 while len(futures) > max(1, int(live_size())):
-                    yield futures.popleft().result()
+                    yield fetch()
             while futures:
-                yield futures.popleft().result()
+                yield fetch()
         finally:
             # abandoned generator (early break/exception upstream): drop
             # queued placements so shutdown doesn't run them pointlessly

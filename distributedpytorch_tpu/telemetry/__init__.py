@@ -4,11 +4,14 @@ One process-wide surface for "what is this process doing":
 
 * :mod:`registry`   — thread-safe counters/gauges/histograms
   (:func:`get_registry` is the process singleton);
-* :mod:`spans`      — nested host spans mirrored into XPlane device
-  traces via ``jax.profiler.TraceAnnotation``;
+* :mod:`spans`      — nested host spans, mirrored into XPlane device
+  traces via ``jax.profiler.TraceAnnotation`` while a capture records;
 * :mod:`goodput`    — wall-clock attribution ({step, compile,
   checkpoint, eval, input_wait, idle}) + MFU estimation with the
   device-kind peak-FLOPs table;
+* :mod:`feed`       — the input feed's two pairs of counters (batches
+  handed out and found built, fetches and found placed) and the
+  ``input/*`` spans of the loader's producer and the placement thread;
 * :mod:`prometheus` — text exposition for ``GET /metrics``;
 * :mod:`trace`      — on-demand bounded ``jax.profiler`` capture
   (SIGUSR2 / ``POST /debug/trace``) without restarting the process;
@@ -32,8 +35,8 @@ Every future perf PR reports into this layer; the train loop, the
 checkpoint manager, the evaluator and the serve front are already wired.
 """
 
-from . import (events, goodput, lowering, prometheus, registry, scopes, spans,
-               timeline, trace)
+from . import (events, feed, goodput, lowering, prometheus, registry, scopes,
+               spans, timeline, trace)
 from .events import EventLog, events_block
 from .timeline import Timeline, load_timeline
 from .goodput import (
@@ -47,13 +50,13 @@ from .goodput import (
 from .lowering import LoweredProgram, lower_cached
 from .prometheus import render_text
 from .registry import MetricsRegistry, get_registry, is_enabled, set_enabled
-from .spans import current_span, span
+from .spans import span
 from .trace import TraceCapture
 
 __all__ = [
     "BUCKETS", "EventLog", "FeedWindow", "GoodputAccountant",
     "LoweredProgram", "MetricsRegistry", "Timeline",
-    "TraceCapture", "current_span", "events", "events_block",
+    "TraceCapture", "events", "events_block", "feed",
     "get_accountant", "get_registry",
     "goodput", "is_enabled", "load_timeline", "lower_cached", "lowering",
     "mfu_estimate",

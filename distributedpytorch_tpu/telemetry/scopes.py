@@ -121,6 +121,19 @@ KERNEL_SCOPE_LAYER = {PAM_KERNEL: "head", PAM_BWD: "head", CAM_BWD: "head",
 #: (``goodput/<bucket>``) and the trainer's dispatch (``StepTraceAnnotation``)
 GOODPUT_PREFIX = "goodput/"
 STEP_ANNOTATION = "train"
+#: the input feed's spans, each with the batch's index in the epoch as its
+#: ``batch`` argument: the loader's producer from the first sample of a
+#: batch to its collate (not its wait for room in the queue), and the
+#: placement thread's wire transform, key filter and ``shard_batch``
+INPUT_BATCH = "input/batch"
+INPUT_PLACE = "input/place"
+#: every name the program itself puts on the profiler's host timeline is the
+#: step annotation or starts with one of these: the goodput buckets, the
+#: feed, and the ``telemetry.span`` paths of the evaluator, the checkpoint
+#: manager, the preemption guard and the consensus primitive.  Declared, not
+#: remembered: a reader of a trace holds on to these.
+PROGRAM_SPAN_PREFIXES = (GOODPUT_PREFIX, "input/", "eval/", "checkpoint/",
+                         "preempt/", "consensus/")
 
 
 def bucket_scope(k: int) -> str:
@@ -581,8 +594,9 @@ HOST_PLANE = "/host:CPU"
 
 def read_device_events(trace_dir: str) -> dict:
     """``{"devices": {plane: {"ops": [...], "modules": [...]}}, "host":
-    [...]}`` of the newest ``.xplane.pb`` under ``trace_dir``, events as
-    ``[name, start_ns, end_ns]``."""
+    [...]}`` of the newest ``.xplane.pb`` under ``trace_dir``, device events
+    as ``[name, start_ns, end_ns]``, host events with their thread (the
+    plane's line) as a fourth element."""
     import jax
 
     files = sorted(glob.glob(os.path.join(
@@ -602,47 +616,66 @@ def read_device_events(trace_dir: str) -> dict:
                                 for e in line.events]
             out["devices"][plane.name] = dev
         elif plane.name == HOST_PLANE:
-            for line in plane.lines:
+            for i, line in enumerate(plane.lines):
                 out["host"].extend(
-                    [e.name, e.start_ns, e.start_ns + e.duration_ns]
+                    [e.name, e.start_ns, e.start_ns + e.duration_ns, i]
                     for e in line.events if e.duration_ns > 0)
     return out
 
 
 def program_spans(host: list) -> list:
     """The host events that the program itself put on the profiler's clock
-    (``goodput/<bucket>``, the train step, ``telemetry.span`` paths), apart
-    from the profiler's own and the Python tracer's."""
-    from . import spans
-
-    seen = spans.seen_paths()
-    return [h for h in host if h[0].startswith(GOODPUT_PREFIX)
-            or h[0] == STEP_ANNOTATION or h[0] in seen]
+    (the train step and the declared :data:`PROGRAM_SPAN_PREFIXES`), apart
+    from the profiler's own."""
+    return [h for h in host if h[0] == STEP_ANNOTATION
+            or h[0].startswith(PROGRAM_SPAN_PREFIXES)]
 
 
-def idle_gaps(ops: list, host: list, top: int = 10) -> list:
-    """The ``top`` longest gaps between device ops, each named by the
-    innermost host span that covers its middle."""
+def loop_thread_spans(host: list) -> list:
+    """Of ``host`` (events with their thread as fourth element), those on
+    the thread that dispatched the train steps.  A device gap is the loop's
+    to explain: a feed worker's span that happens to run across it says
+    nothing about why the chip waited.  Events that carry no thread, or a
+    trace with no step in it, come back whole."""
+    loop = {h[3] for h in host if h[0] == STEP_ANNOTATION and len(h) > 3}
+    if not loop:
+        return host
+    return [h for h in host if len(h) > 3 and h[3] in loop]
+
+
+def idle_gaps(ops: list, host: list, top: int = 10) -> tuple[list, dict]:
+    """``(longest, by_span)``: the ``top`` longest gaps between device ops,
+    each named by the innermost of ``host``'s spans that covers its middle,
+    and the idle seconds of ALL gaps summed per such name."""
     gaps, cur_e = [], None
     for _, s, e in sorted(ops, key=lambda ev: ev[1]):
         if cur_e is not None and s > cur_e:
             gaps.append((cur_e, s))
         cur_e = e if cur_e is None else max(cur_e, e)
-    out = []
-    for s, e in sorted(gaps, key=lambda g: g[0] - g[1])[:top]:
+    # one sweep over gaps and spans in time order: a whole epoch's trace
+    # holds a gap between most pairs of ops
+    spans = sorted(host, key=lambda h: h[1])
+    i, active, named, by_span = 0, [], [], {}
+    for s, e in sorted(gaps):
         mid = (s + e) / 2
-        cover = [h for h in host if h[1] <= mid <= h[2]]
-        name = min(cover, key=lambda h: h[2] - h[1])[0] if cover \
+        while i < len(spans) and spans[i][1] <= mid:
+            active.append(spans[i])
+            i += 1
+        active = [h for h in active if h[2] >= mid]
+        name = min(active, key=lambda h: h[2] - h[1])[0] if active \
             else "no host span"
-        out.append([name, (e - s) / 1e9])
-    return out
+        named.append([name, (e - s) / 1e9])
+        by_span[name] = by_span.get(name, 0.0) + (e - s) / 1e9
+    named.sort(key=lambda g: -g[1])
+    return named[:top], by_span
 
 
 def summarize_capture(raw: dict, table: ScopeTable) -> dict:
     """``scope_summary.json`` of one capture: per device, the executions of
     the table's step program and the ops inside them attributed by it, in
     milliseconds per step; the program's own host spans in the trace, and the
-    first device's longest idle gaps by the innermost of them."""
+    first device's idle gaps (the longest, and the total per name) by the
+    innermost of those on the loop's thread."""
     rx = re.compile("^" + re.escape(table.module))
     devices = []  # (steps, ops inside them, their attribution)
     for plane in sorted(raw["devices"]):
@@ -673,6 +706,7 @@ def summarize_capture(raw: dict, table: ScopeTable) -> dict:
 
     steps0, ops0, _ = devices[0]
     host = program_spans(raw["host"])
+    longest, idle_by_span = idle_gaps(ops0, loop_thread_spans(host))
     out.update({
         "steps": steps0,
         "busy_ms_per_step": sum(1e3 * a["busy_s"] / steps
@@ -684,7 +718,8 @@ def summarize_capture(raw: dict, table: ScopeTable) -> dict:
             per_step_ms("by_path").items(), key=lambda kv: -kv[1])[:20],
         "mixed_share": share("mixed_s"),
         "unresolved_share": share("unresolved_s"),
-        "idle_gaps": idle_gaps(ops0, host),
+        "idle_gaps": longest,
+        "idle_by_span_s": idle_by_span,
         "host_spans": {name: sum(1 for h in host if h[0] == name)
                        for name in sorted({h[0] for h in host})},
     })

@@ -19,8 +19,9 @@ forward / backward / optimizer, :mod:`telemetry.scopes`) and
 phase, the heaviest module paths, the longest idle gaps by host span) land
 beside the XPlane files, and one ``trace_summary`` event goes into the
 flight recorder.  While a capture is active :func:`capturing` is true:
-``GoodputAccountant.account`` and the trainer's dispatch then annotate the
-profiler's own timeline, and make no profiler call otherwise.
+``GoodputAccountant.account``, ``telemetry.span``, the input feed and the
+trainer's dispatch then annotate the profiler's own timeline
+(:func:`annotation`), and make no profiler call otherwise.
 
 Safety properties, each deliberate:
 
@@ -57,8 +58,26 @@ _capturing = False
 
 def capturing() -> bool:
     """Whether a :class:`TraceCapture` is recording right now: the one read
-    that host annotations (``goodput/<bucket>``, the train step) are behind."""
+    that every host annotation of the program (``goodput/<bucket>``, the
+    train step, ``telemetry.span`` paths, the input feed's ``input/*``) is
+    behind."""
     return _capturing
+
+
+#: what :func:`annotation` hands back while nothing records
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def annotation(name: str, **args):
+    """A span on the profiler's own timeline while a capture records
+    (``jax.profiler.TraceAnnotation(name, **args)``; ``args`` show beside
+    the event), a shared no-op otherwise: off, this is the one module
+    attribute read and no JAX call."""
+    if not _capturing:
+        return _NO_ANNOTATION
+    import jax
+
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 class TraceCapture:
@@ -213,7 +232,14 @@ class TraceCapture:
         self._program = None
         try:
             os.makedirs(self._dir, exist_ok=True)
-            jax.profiler.start_trace(self._dir)
+            opts = None
+            if steps is None:
+                # a region (a whole epoch) without the Python tracer: its
+                # interpreter calls would swamp the trace, and the program's
+                # own spans name the time; a bounded capture keeps its frames
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0
+            jax.profiler.start_trace(self._dir, profiler_options=opts)
         except Exception as e:  # another trace active, or profiler error
             self._failed("start", e)
             return

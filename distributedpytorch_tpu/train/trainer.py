@@ -49,6 +49,7 @@ from ..parallel import (
 from ..parallel import plan as plan_lib
 from ..telemetry import TraceCapture, get_accountant, mfu_estimate, scopes
 from ..telemetry import events as events_lib
+from ..telemetry import feed as feed_lib
 from ..telemetry import set_enabled as telemetry_set_enabled
 from ..utils.helpers import generate_param_report
 from ..utils.profiling import device_memory_stats
@@ -935,12 +936,20 @@ class Trainer:
         if not self.cfg.telemetry:
             return
         rep = get_accountant().report()
+        fed = feed_lib.publish()
         if history is not None:
             history["goodput"] = rep
         scalars = {f"goodput/{b}_s": round(v, 4)
                    for b, v in rep["buckets"].items()}
         scalars["goodput/total_s"] = round(rep["total_s"], 4)
         scalars["goodput/productive_frac"] = round(rep["goodput"], 4)
+        # beside input_wait, on whom: the share of batches the loader had
+        # built, and of placements that had finished, when the loop came
+        for stage in ("batch", "fetch"):
+            if fed[f"input_{stage}_total"]:
+                scalars[f"input/{stage}_ready_frac"] = round(
+                    fed[f"input_{stage}_ready_total"]
+                    / fed[f"input_{stage}_total"], 4)
         # MFU is a device metric: off-TPU there is no peak to divide by
         if self._flops_per_step and self._prod_steps \
                 and jax.devices()[0].platform == "tpu":
@@ -1345,7 +1354,8 @@ class Trainer:
                 keys=(WIRE_KEY,) if cfg.data.coalesce_wire
                 else self.task.device_keys,
                 transform=(self._pack_wire_transform
-                           if cfg.data.coalesce_wire else None))
+                           if cfg.data.coalesce_wire else None),
+                start=start_batch)
             if echo > 1:
                 batches = echoed(batches)
             batches = waited(batches)
@@ -1432,6 +1442,10 @@ class Trainer:
                             f"{epoch}) — divergence; lower optim.lr, "
                             "enable optim.grad_clip_norm, or set "
                             "optim.loss_scale for bf16 underflow")
+                    if cfg.telemetry:
+                        # the feed's four counters reach the registry here
+                        # and at the fit's end, never per step
+                        feed_lib.publish()
                     if self.is_main:
                         # Attribute each logged loss to the step that
                         # crossed a cadence boundary, indexing that step's
@@ -1886,10 +1900,18 @@ class Trainer:
                 {f"val/new_best_{name}": best, "val/epoch": epoch}, step)
 
     # -------------------------------------------------------------------- fit
-    def fit(self, guard: PreemptionGuard | None = None) -> dict:
+    def fit(self, guard: PreemptionGuard | None = None,
+            epochs: int | None = None) -> dict:
         """The full loop (reference train_pascal.py:180-308): train each
         epoch; validate every ``eval_every``; snapshot every
         ``snapshot_every``; save best on threshold-max Jaccard improvement.
+
+        Trains epochs ``self.start_epoch`` up to ``epochs`` (``cfg.epochs``
+        when None) and leaves ``start_epoch`` at the next epoch to train, so
+        a further ``fit`` on this trainer continues where this one ended
+        (compiled programs, loaders and the run dir stay): ``fit(epochs=1)``
+        then ``fit()`` trains every epoch once.  Schedules span
+        ``cfg.epochs`` whatever ``epochs`` says.
 
         Preemption: unless disabled (``checkpoint.save_on_preempt=false``),
         SIGTERM/SIGINT triggers a consensus stop, one final full-state
@@ -1909,6 +1931,8 @@ class Trainer:
         entered ``guard`` to drive stops programmatically (e.g. a
         wall-clock watchdog calling ``trip()``)."""
         cfg = self.cfg
+        epochs = cfg.epochs if epochs is None else int(epochs)
+        first_epoch = self.start_epoch
         history = {"train_loss": [], "val": []}
         if cfg.profile_epoch is not None and self.is_main and not \
                 (self.start_epoch <= cfg.profile_epoch < cfg.epochs):
@@ -1928,7 +1952,7 @@ class Trainer:
         events_lib.emit(
             "trainer", "fit_start", step=int(self.state.step),
             epoch=self.start_epoch,
-            payload={"epochs": cfg.epochs,
+            payload={"epochs": epochs,
                      "resumed": bool(self.resume_meta),
                      "plan_crossing": bool(self.resume_plan_crossing)})
         # chaos: arm an env-named fault plan (DPTPU_CHAOS_PLAN) for this
@@ -1962,28 +1986,30 @@ class Trainer:
                 self.ckpt.save(int(self.state.step), self.state,
                                extra={"epoch": self.start_epoch - 1})
                 self.ckpt.wait()
+            #: profile_epoch's capture while it is open: the epoch's train
+            #: steps, its validation and its save (for the last epoch the
+            #: final wait too) land in ONE trace, on the device's clock
+            profiling = stack.enter_context(contextlib.ExitStack())
             epoch = self.start_epoch
-            while epoch < cfg.epochs:
+            while epoch < epochs:
                 t0 = time.perf_counter()
                 sb = self._resume_start_batch  # only the run's first epoch
                 self._resume_start_batch = 0
                 estep0 = int(self.state.step)
+                profiling.close()  # the previous epoch's, if it was traced
                 if cfg.profile_epoch == epoch and self._trace is not None:
                     # Op-level device trace of one epoch (SURVEY §5.1: the
                     # reference had only wall-clock prints), through the
                     # one capture path: XPlane files for tensorboard/xprof
                     # plus scope_table.json / scope_summary.json under the
                     # run dir.
-                    ctx = self._trace.region(
-                        os.path.join(self.run_dir, "profile"))
-                else:
-                    ctx = contextlib.nullcontext()
+                    profiling.enter_context(self._trace.region(
+                        os.path.join(self.run_dir, "profile")))
                 try:
-                    with ctx:
-                        epoch_loss = self.train_epoch(
-                            epoch, guard=guard, start_batch=sb,
-                            abort_check=(self._poll_overlapped_val_error
-                                         if cfg.val_overlap else None))
+                    epoch_loss = self.train_epoch(
+                        epoch, guard=guard, start_batch=sb,
+                        abort_check=(self._poll_overlapped_val_error
+                                     if cfg.val_overlap else None))
                 except _DivergenceDetected as d:
                     # rollback-and-replay: restore the last committed
                     # checkpoint, quarantine the bad window, re-enter the
@@ -2075,6 +2101,9 @@ class Trainer:
                 # epoch to hide behind; land it before the last save wait
                 self._join_overlapped_val(history)
                 self.ckpt.wait()
+            profiling.close()  # a traced last epoch holds that wait
+            # a further fit continues here (a preempted epoch is replayed)
+            self.start_epoch = epoch
             # after the last save has landed, so its wait is in the books
             self._report_goodput(history)
             # recovery block (the bench/report schema, train/sentinel.py):
@@ -2110,7 +2139,7 @@ class Trainer:
                     {"preempted": bool(history.get("preempted")),
                      "completed": not history.get("preempted"),
                      "final_step": int(self.state.step),
-                     "start_epoch": self.start_epoch,
+                     "start_epoch": first_epoch,
                      "epochs": cfg.epochs,
                      "epochs_recorded": len(history["train_loss"]),
                      "recovery": history["recovery"],

@@ -428,7 +428,10 @@ _RAW = {
                 ["%all-reduce.3 = f32[8]{0} all-reduce(...)", 1500, 2000],
                 ["%multiply_add_fusion = f32[8]{0} fusion(...)", 2100, 3600],
                 ["%fusion.2 = f32[8]{0} fusion(...)", 3600, 4100]]}},
-    "host": [["goodput/input_wait", 1990, 2110], ["outer", 0, 5000]],
+    # [name, start, end, thread]: the loop's thread holds the step; a feed
+    # worker's span across the gap's middle must not name it
+    "host": [["train", 0, 1900, 0], ["goodput/input_wait", 1990, 2110, 0],
+             ["input/batch", 2040, 2060, 1], ["outer", 0, 5000, 0]],
 }
 
 
@@ -470,7 +473,9 @@ def test_capture_leaves_table_summary_and_event(
     assert summary["mixed_share"] == pytest.approx(3000 / 4000)
     assert summary["unresolved_share"] == 0
     assert summary["idle_gaps"] == [["goodput/input_wait", 100e-9]]
-    assert summary["host_spans"] == {"goodput/input_wait": 1}  # not "outer"
+    assert summary["idle_by_span_s"] == {"goodput/input_wait": 100e-9}
+    assert summary["host_spans"] == {"goodput/input_wait": 1, "train": 1,
+                                     "input/batch": 1}  # not "outer"
     recorded = [e for e in events_lib.read_events_file(log.path)
                 if e["kind"] == "trace_summary"]
     assert len(recorded) == 1
