@@ -23,6 +23,7 @@ from .nemotron_h import NemotronH, build_nemotron_h
 from .pspnet import PSPNet, PyramidPooling
 from .resnet import ResNet, resnet50, resnet101
 from .sdar_lm import SdarLM, build_sdar_lm
+from .zaya_lm import ZayaLM, build_zaya_lm
 
 #: the tasks (``Config.task``) a model trains under, by its ``build_model``
 #: name; a name not listed is a segmentation net.  What the trainer checks a
@@ -33,7 +34,8 @@ SEGMENTATION_TASKS = ("instance", "semantic")
 #: this table; a new token model is one entry
 TOKEN_MODELS = {"nemotron_h": (build_nemotron_h, ("tokens",)),
                 "keye_lm": (build_keye_lm, ("tokens",)),
-                "sdar_lm": (build_sdar_lm, ("tokens",))}
+                "sdar_lm": (build_sdar_lm, ("tokens",)),
+                "zaya_lm": (build_zaya_lm, ("tokens",))}
 MODEL_TASKS = {name: tasks for name, (_, tasks) in TOKEN_MODELS.items()}
 
 
@@ -56,10 +58,10 @@ def build_model(
     **kw,
 ):
     """Construct a segmentation model by name — or, by a name of
-    :data:`TOKEN_MODELS` (``nemotron_h``, ``keye_lm``, ``sdar_lm``), a token model of the
-    ``tokens`` task (``lm_config``: a preset's name, a JSON file of the
-    published keys, or that dict; ``remat``; the image options do not apply
-    to it).
+    :data:`TOKEN_MODELS` (``nemotron_h``, ``keye_lm``, ``sdar_lm``,
+    ``zaya_lm``), a token model of the ``tokens`` task (``lm_config``: a
+    preset's name, a JSON file of the published keys, or that dict;
+    ``remat``; the image options do not apply to it).
 
     ``dtype`` may be a string ('float32' / 'bfloat16') for config-file use.
     """
@@ -241,6 +243,7 @@ __all__ = [
     "PyramidPooling",
     "ResNet",
     "SdarLM",
+    "ZayaLM",
     "build_from_config",
     "build_model",
     "resnet50",

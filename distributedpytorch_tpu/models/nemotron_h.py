@@ -192,6 +192,18 @@ def _dot(x, w, dtype, out=None):
                    preferred_element_type=F32).astype(out or dtype)
 
 
+def causal_depthwise_conv(x, w, b):
+    """A causal convolution over time, each channel apart, in float32: ``b +
+    sum_i w[i] * x[:, t - (K - 1) + i]`` with ``x[:, t < 0] = 0``.  ``x``:
+    (B, L, C); ``w``: (K, C); ``b``: (C,)."""
+    k, length = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    acc = b.astype(F32)
+    for i in range(k):
+        acc = acc + padded[:, i:i + length].astype(F32) * w[i]
+    return acc
+
+
 def ssd_chunked(x, dt, a, bm, cm, chunk: int):
     """Mamba-2's recurrence ``h_t = exp(dt_t a) h_{t-1} + dt_t x_t (x) B_t``,
     ``y_t = C_t . h_t`` in the chunked (SSD) form.
@@ -302,11 +314,8 @@ class MambaMixer(nn.Module):
         xbc = zxbcdt[..., inner:inner + c.conv_dim].astype(self.dtype)
         dt = zxbcdt[..., inner + c.conv_dim:]
         with jax.named_scope(scopes.MAMBA_CONV):
-            padded = jnp.pad(xbc, ((0, 0), (c.conv_kernel - 1, 0), (0, 0)))
-            acc = conv_b.astype(F32)
-            for i in range(c.conv_kernel):
-                acc = acc + padded[:, i:i + length].astype(F32) * conv_w[i]
-            xbc = jax.nn.silu(acc).astype(self.dtype)
+            xbc = jax.nn.silu(causal_depthwise_conv(xbc, conv_w, conv_b)
+                              ).astype(self.dtype)
         x = xbc[..., :inner].reshape(b, length, h, p)
         bm = xbc[..., inner:inner + g * n].reshape(b, length, g, n)
         cm = xbc[..., inner + g * n:].reshape(b, length, g, n)

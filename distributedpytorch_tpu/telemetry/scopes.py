@@ -106,6 +106,10 @@ MOE_LATENT = "latent"
 ATTN_INDEXER = "indexer"
 ATTN_TOPK_SELECT = "topk_select"
 ATTN_INDEX_ALIGN = "index_align"
+#: the elementwise and grouped mixing of a compressed convolutional
+#: attention block under ``attn/l<i>/``: the value shift, both causal
+#: convolutions, the q-k mean, the L2 norm and temperature, the half rotary
+ATTN_CCA_MIX = "cca_mix"
 TOKEN_LAYERS = (EMBED, MAMBA, ATTN, MOE, MTP, LM_HEAD)
 #: ops of the model that sit in no sub-module (the logits' final upsample)
 MODEL = "model"

@@ -565,3 +565,70 @@ def test_block_diffusion_attention_block_compiles_at_the_cells_shape(
                                other + ".json")) as f:
             theirs = re.compile(json.load(f)["args"]["event_pattern"])
         assert not any(theirs.search(e) for e in events), other
+
+
+def test_cca_attention_block_compiles_at_the_cells_shape(v5e_mesh,
+                                                         monkeypatch):
+    """``zaya_lm``'s compressed convolutional attention block with the causal
+    flash kernels live, forward and reverse under ``nn.remat``'s policy, in
+    bfloat16 over one sequence of 8,192 tokens at the published widths (8
+    query heads to 2 key/value heads of 128 in the latent): ONE forward call
+    and ONE fused reverse call, the cell's roofline pattern matching both;
+    no array of token pairs; the mixing compiled under ``attn/l00/cca_mix``
+    in the forward and the reverse phase."""
+    import json
+    import os
+
+    from flax import linen as nn
+
+    from distributedpytorch_tpu.models import zaya_lm as zl
+
+    monkeypatch.setattr(danet_mod, "_on_tpu", lambda: True)
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "zaya1_8b_stage_ep2.json")) as f:
+        cfg = zl.LMConfig.from_dict(json.load(f))
+    assert (cfg.q_heads, cfg.kv_heads, cfg.head_dim, cfg.rotary_dim) == (
+        8, 2, 128, 64)
+    one = _one_chip(v5e_mesh)
+    seq = 8192
+
+    class Block(nn.Module):
+        @nn.compact
+        def __call__(self, u):
+            with jax.named_scope(scopes.ATTN):
+                return nn.remat(zl.CCAttention,
+                                policy=zl._KEEP_FLASH_RESIDUALS)(
+                    cfg, jnp.bfloat16, name="l00")(u)
+
+    layer = Block()
+    u = jax.ShapeDtypeStruct((1, seq, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, cfg.hidden_size),
+                                             jnp.bfloat16)))["params"])
+
+    def loss(p, v):
+        return layer.apply({"params": p}, v).astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, u).compile().as_text()
+    calls = [ln.split(" = ")[0].strip().lstrip("%")
+             for ln in _custom_calls(hlo)]
+    assert sorted(c.split(".")[0] for c in calls) == [
+        scopes.CAUSAL_ATTN, scopes.CAUSAL_ATTN_BWD_FUSED]
+    assert not re.search(rf"\[(\d+,)*{seq},{seq}\]", hlo)
+    for ln in _custom_calls(hlo):  # one key/value row a group, not repeated
+        operands = ln.split("operand_layout_constraints=", 1)[1]
+        assert f"bf16[8,{seq},128]" in operands, ln[:300]
+        assert operands.count(f"bf16[2,{seq},128]") == 2, ln[:300]
+    table = scopes.scope_table(hlo)
+    mixing = {s.phase for s in table.values()
+              if s.path.startswith(f"attn/l00/{scopes.ATTN_CCA_MIX}")}
+    assert mixing == {"fwd", "bwd"}
+    with open(os.path.join(here, "benchmarks", "metrics",
+                           "cca_attn_kernel_roofline.json")) as f:
+        mine = re.compile(json.load(f)["args"]["event_pattern"])
+    assert all(mine.search(f"%{c} custom-call") for c in calls)
