@@ -32,9 +32,12 @@ layer.  Then a final RMSNorm and the untied head.
 In bfloat16 on a TPU (``models/danet.py::auto_wants_flash``) attention runs
 as the Mosaic flash kernels under the rule
 ``ops/pallas_attention.py::BlockDiffusion`` (``blockdiff_attn``,
-``blockdiff_attn_bwd_…``): no (2L, 2L) array reaches HBM, and a
-rematerialised block keeps the forward call's output and log-sum-exp, so its
-reverse pass runs no second forward call.  Otherwise the einsum form runs.
+``blockdiff_attn_bwd_…``): no (2L, 2L) array reaches HBM.  A
+rematerialised block keeps the forward call's output and log-sum-exp, its
+heads-first operands, and the normed input, q and k before their norms and v
+(:data:`ATTN_KEPT`), so its reverse pass runs no second forward call, no
+product of the normed input and no transpose to heads-first.  Otherwise the
+einsum form runs.
 
 The counts of experts and vocabulary rows in the configuration are what this
 chip HOLDS of a stated deployment; widths are never cut.  The last held
@@ -52,6 +55,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import attention as attention_ops
 from ..ops import diffusion, pallas_attention
@@ -130,6 +134,18 @@ class LMConfig:
             expert_chunk_rows=c.get("expert_chunk_rows"))
 
 
+#: ``checkpoint_name``s of what an attention block's reverse pass reads of
+#: its forward pass before the flash call: the normed input (the three
+#: projections' weight gradients read it), q and k before their head norms
+#: (the norms' reverse reads them) and v (the einsum form's reverse reads it;
+#: the flash path's reads the call's heads-first copy, which the rule names).
+#: With the rule's names beside them the replay holds no product of the
+#: normed input and no transpose: the norms' statistics, rotary's angles and
+#: the weights' casts are all it recomputes
+ATTN_KEPT = (_X, _Q, _K, _V) = ("sdar_attn_x", "sdar_attn_q", "sdar_attn_k",
+                                "sdar_attn_v")
+
+
 class BlockDiffusionAttention(nn.Module):
     """``u`` (B, 2L, d), the clean copy's states then the noised copy's."""
 
@@ -150,10 +166,10 @@ class BlockDiffusionAttention(nn.Module):
         q_norm = self.param("q_norm", _ones, (hd,))
         k_norm = self.param("k_norm", _ones, (hd,))
 
-        x = rms_norm(u, norm, c.norm_eps)
-        q = _dot(x, q_proj, self.dtype).reshape(b, doubled, qh, hd)
-        k = _dot(x, k_proj, self.dtype).reshape(b, doubled, kvh, hd)
-        v = _dot(x, v_proj, self.dtype).reshape(b, doubled, kvh, hd)
+        x = checkpoint_name(rms_norm(u, norm, c.norm_eps), _X)
+        q, k, v = (checkpoint_name(_dot(x, w, self.dtype).reshape(
+            b, doubled, heads, hd), name) for w, heads, name in (
+                (q_proj, qh, _Q), (k_proj, kvh, _K), (v_proj, kvh, _V)))
         # token i of either copy stands at position i
         positions = jnp.broadcast_to(
             jnp.tile(jnp.arange(length, dtype=jnp.int32), 2),
@@ -171,7 +187,20 @@ class BlockDiffusionAttention(nn.Module):
         return u + _dot(out, o_proj, self.dtype, out=u.dtype)
 
 
-_KEEP_FLASH_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
+def attn_kept_bytes(c: LMConfig, tokens: int, item: int, flash: bool) -> int:
+    """Bytes of what one attention block names over ``tokens`` positions
+    at ``item`` bytes an element: :data:`ATTN_KEPT` and, where the flash
+    kernels run, the call's heads-first operands, its output and its float32
+    log-sum-exp (the rule's ``kept_operands`` and ``kept``)."""
+    q, kv = c.q_heads * c.head_dim, c.kv_heads * c.head_dim
+    kept = tokens * (c.hidden_size + q + 2 * kv) * item
+    if flash:
+        kept += tokens * ((2 * q + 2 * kv) * item + 4 * c.q_heads)
+    return kept
+
+
+KEEP_ATTN_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
+    *ATTN_KEPT, *pallas_attention.BlockDiffusion.kept_operands,
     *pallas_attention.BlockDiffusion.kept)
 
 
@@ -223,9 +252,8 @@ class SdarLM(nn.Module):
             x = jnp.take(embed, ids, axis=0).astype(self.dtype)
         attn, moe = BlockDiffusionAttention, GatedMoE
         if self.remat:
-            # per block; an attention block keeps what its reverse pass
-            # reads of the forward call
-            attn = nn.remat(attn, policy=_KEEP_FLASH_RESIDUALS)
+            # per block; each keeps by name what its reverse pass reads
+            attn = nn.remat(attn, policy=KEEP_ATTN_RESIDUALS)
             moe = nn.remat(moe, policy=KEEP_EXPERT_RESIDUALS)
         for i in range(c.layers):
             with jax.named_scope(scopes.ATTN):
@@ -242,20 +270,21 @@ class SdarLM(nn.Module):
         """A bound on the step's live activations on one device, for the
         planner's memory model (``parallel/plan.py``): what per-block
         recomputation keeps over the 2·``seq_len`` positions (every block's
-        input; an attention block's output and log-sum-exp; an expert
-        block's ``keye_lm.EXPERT_KEPT``), the largest single block while it
-        is recomputed and differentiated, and the head's float32 logits over
-        ``seq_len`` with their gradient."""
+        input; what an attention block names, :func:`attn_kept_bytes`; an
+        expert block's ``keye_lm.EXPERT_KEPT``), the largest single block
+        while it is recomputed and differentiated, and the head's float32
+        logits over ``seq_len`` with their gradient."""
         c = self.cfg
         t = 2 * batch * seq_len
         item = jnp.dtype(self.dtype).itemsize
+        flash = danet.auto_wants_flash(self.dtype)
         kept = (2 * c.layers + 2) * t * c.hidden_size * item
         if not self.remat:
             kept *= 8
         else:
-            kept += c.layers * expert_kept_bytes(c, t)
-        if danet.auto_wants_flash(self.dtype):
-            kept += c.layers * t * c.q_heads * (c.head_dim * item + 4)
+            kept += c.layers * (attn_kept_bytes(c, t, item, flash)
+                                + expert_kept_bytes(c, t))
+        if flash:
             attn = t * c.head_dim * (c.q_heads * (4 * item + 3 * 4)
                                      + 4 * c.kv_heads * item)
         else:  # the einsum form: every head's scores, whole

@@ -268,6 +268,11 @@ class BlockDiffusion(MaskRule):
                             scopes.BLOCKDIFF_ATTN_BWD_DKV,
                             scopes.BLOCKDIFF_ATTN_BWD_DQ)
     kept: tuple = ("blockdiff_attn_out", "blockdiff_attn_lse")
+    #: ``checkpoint_name``s of the call's operands, heads-first, as the
+    #: reverse pass reads them: a block that keeps them replays neither the
+    #: transposes nor what makes q, k and v
+    kept_operands = ("blockdiff_attn_q", "blockdiff_attn_k",
+                     "blockdiff_attn_v")
     #: a query tile of the first noised block alone sees no clean key
     first_key_tile_meets_all = False
 
@@ -929,7 +934,8 @@ def flash_block_diffusion_attention(q, k, v, length: int, block: int,
     attended pair run (24 of 64 at tiles of 1,024 and 4,096 tokens), half of
     them with no mask; the calls are ``blockdiff_attn`` and
     ``blockdiff_attn_bwd_…``, and a block that saves the rule's ``kept``
-    names keeps their output and log-sum-exp across its recomputation."""
+    and ``kept_operands`` names keeps their output and log-sum-exp and the
+    heads-first operands across its recomputation."""
     b, s, hq, d = q.shape
     hkv = k.shape[2]
     if hq % hkv:
@@ -938,10 +944,12 @@ def flash_block_diffusion_attention(q, k, v, length: int, block: int,
     if s != 2 * length:
         raise ValueError(f"{s} positions are not a sequence of {length} "
                          "and its noised copy")
+    rule = BlockDiffusion(length=length, block=block)
     out = flash_position_attention(
-        _heads_first(q), _heads_first(k), _heads_first(v), *_causal_blocks(s),
-        1 / math.sqrt(d), interpret=interpret,
-        rule=BlockDiffusion(length=length, block=block), group=hq // hkv)
+        *map(checkpoint_name, map(_heads_first, (q, k, v)),
+             rule.kept_operands),
+        *_causal_blocks(s), 1 / math.sqrt(d), interpret=interpret, rule=rule,
+        group=hq // hkv)
     return out.reshape(b, hq, s, d).transpose(0, 2, 1, 3)
 
 

@@ -1,5 +1,6 @@
 """What a rematerialised expert block keeps (``EXPERT_KEPT`` of
-models/nemotron_h.py and models/keye_lm.py): the counts and comparisons that
+models/nemotron_h.py and models/keye_lm.py), and SDAR's attention block
+(``ATTN_KEPT`` of models/sdar_lm.py): the counts and comparisons that
 tests/test_nemotron_h.py, test_keye_lm.py and test_sdar_lm.py make, each on
 its own model's block.  A policy saves the result of the ``name`` primitive,
 and a derivative whose residual is another variable still replays its
@@ -29,13 +30,20 @@ def eqns_named(jaxpr, primitive):
     return found
 
 
+def input_products(jaxpr, shape: tuple, width: int) -> int:
+    """The products of an operand ``shape`` with a (``shape[-1]``,
+    ``width``) weight in ``jaxpr``: a forward product of a block's normed
+    input (its two reverse products have other operands)."""
+    return [tuple(v.aval.shape for v in e.invars)
+            for e in eqns_named(jaxpr, "dot_general")].count(
+                (tuple(shape), (shape[-1], width)))
+
+
 def forward_products(jaxpr, tokens: int, hidden: int, width: int) -> int:
     """The products ``(tokens, hidden) @ (hidden, width)`` in ``jaxpr``: a
     forward product of the block's normed input (its two reverse products
     have other operands)."""
-    return [tuple(v.aval.shape for v in e.invars)
-            for e in eqns_named(jaxpr, "dot_general")].count(
-                ((tokens, hidden), (hidden, width)))
+    return input_products(jaxpr, (tokens, hidden), width)
 
 
 def block_case(cls, cfg, policy="none"):

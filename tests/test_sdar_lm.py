@@ -638,8 +638,11 @@ def test_activation_bytes_follow_the_attention_form_that_runs(monkeypatch):
     einsum = model.activation_bytes(1, 4096)
     monkeypatch.setattr(danet, "_on_tpu", lambda: True)
     flash = model.activation_bytes(1, 4096)
-    # the einsum form holds 32 heads' float32 (8192, 8192) arrays
-    assert einsum > 3 * 32 * 8192 * 8192 * 4 > 8 * flash
+    # the einsum form holds 32 heads' float32 (8192, 8192) arrays; the
+    # kernel path's attention blocks keep 269.5 MB a layer by name
+    kept = 7 * sl.attn_kept_bytes(model.cfg, 8192, 2, True)
+    assert 1.85e9 < kept < 1.95e9
+    assert einsum > 3 * 32 * 8192 * 8192 * 4 > 8 * (flash - kept)
     assert 1e9 < flash < 6e9
 
 
@@ -766,5 +769,106 @@ def test_activation_bytes_count_what_an_expert_block_keeps(monkeypatch):
 
     charged = acts()
     monkeypatch.setattr(sl, "expert_kept_bytes", lambda c, t: 0)
+    kept_once, plain = (a - b for a, b in zip(charged, acts()))
+    assert (kept_once, plain) == (cfg["num_hidden_layers"] * kept, 0)
+
+
+# ---------------------------- what a rematerialised attention block keeps
+#: eight query heads of 16: q's width (128) is no other product's, and
+#: blocks of 2 tile the 10 positions a copy of ``kept_cases.POSITIONS``
+ATTN_TINY = tiny(num_attention_heads=8, block_length=2)
+
+
+@pytest.fixture(params=["einsum", "kernels"])
+def attn_form(request, monkeypatch):
+    """The attention form a block runs: the einsum form, or the flash
+    kernels in the pallas interpreter."""
+    from distributedpytorch_tpu.models import danet
+
+    if request.param == "kernels":
+        request.getfixturevalue("interpreted_kernels")
+        monkeypatch.setattr(danet, "auto_wants_flash", lambda dtype: True)
+    return request.param
+
+
+def heads_first_transposes(jaxpr, shape):
+    """Transposes of a (B, S, H, D) array ``shape`` to heads-first."""
+    return sum(e.params["permutation"] == (0, 2, 1, 3)
+               and e.invars[0].aval.shape == tuple(shape)
+               for e in kept_cases.eqns_named(jaxpr, "transpose"))
+
+
+@pytest.mark.parametrize("policy,runs", [("kept", 1), ("bare", 2)])
+def test_replay_of_an_attention_block_holds_no_product_of_its_input(
+        attn_form, policy, runs):
+    """One forward product of the normed input a projection in the gradient
+    where a bare ``nn.remat`` runs two: q's, and k's and v's (alike in
+    width: two); on the kernel path one transpose to heads-first of k and
+    of v where the bare block runs two."""
+    cfg = sl.LMConfig.from_dict(ATTN_TINY)
+    loss, params, u = kept_cases.block_case(
+        sl.BlockDiffusionAttention, cfg,
+        sl.KEEP_ATTN_RESIDUALS if policy == "kept" else None)
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(params, u).jaxpr
+    q, kv = cfg.q_heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    assert [kept_cases.input_products(jaxpr, u.shape, w)
+            for w in (q, kv)] == [runs, 2 * runs]
+    if attn_form == "kernels":
+        assert heads_first_transposes(
+            jaxpr, u.shape[:2] + (cfg.kv_heads, cfg.head_dim)) == 2 * runs
+
+
+def test_kept_attention_block_gives_the_bare_blocks_loss_and_gradients(
+        attn_form):
+    kept_cases.assert_kept_block_is_the_bare_blocks(
+        sl.BlockDiffusionAttention, sl.LMConfig.from_dict(ATTN_TINY),
+        sl.KEEP_ATTN_RESIDUALS, GRAD_RTOL)
+
+
+def test_kernel_path_keeps_the_attention_blocks_operands_by_name(
+        whole, monkeypatch, interpreted_kernels):
+    """The whole model's gradient on the kernel path names every array an
+    attention block keeps once a layer, and holds one forward q product and
+    one transpose to heads-first of k and of v a layer: none is replayed."""
+    from distributedpytorch_tpu.models import danet
+
+    _, _, _, batch = whole
+    monkeypatch.setattr(danet, "auto_wants_flash", lambda dtype: True)
+    model = build_model("sdar_lm", lm_config=ATTN_TINY)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(1),
+                            batch["tokens"])["params"]
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: program_loss(model, p, batch)[0]))(params).jaxpr
+    c, layers = model.cfg, ATTN_TINY["num_hidden_layers"]
+    names = [e.params["name"] for e in kept_cases.eqns_named(jaxpr, "name")]
+    for name in (*sl.ATTN_KEPT, *pa.BlockDiffusion.kept_operands):
+        assert names.count(name) == layers, name
+    x = batch["tokens"].shape[:1] + (2 * batch["tokens"].shape[1],
+                                     c.hidden_size)
+    assert kept_cases.input_products(jaxpr, x, c.q_heads * c.head_dim) \
+        == layers
+    assert heads_first_transposes(jaxpr, x[:2] + (c.kv_heads, c.head_dim)) \
+        == 2 * layers
+
+
+def test_activation_bytes_count_what_an_attention_block_keeps(
+        attn_form, monkeypatch):
+    """The planner is charged every layer's named arrays: the block's own,
+    and on the kernel path the call's heads-first operands, output and
+    log-sum-exp."""
+    cfg, batch, length = tiny(), 2, 24
+    model = sl.build_sdar_lm(cfg)
+    names = sl.ATTN_KEPT + ((*pa.BlockDiffusion.kept_operands,
+                             *pa.BlockDiffusion.kept)
+                            if attn_form == "kernels" else ())
+    kept = kept_cases.kept_bytes(sl.BlockDiffusionAttention, model.cfg,
+                                 names, batch, 2 * length, jnp.float32)
+
+    def acts():
+        return [sl.build_sdar_lm(cfg, remat=r).activation_bytes(batch, length)
+                for r in (True, False)]
+
+    charged = acts()
+    monkeypatch.setattr(sl, "attn_kept_bytes", lambda c, t, item, flash: 0)
     kept_once, plain = (a - b for a, b in zip(charged, acts()))
     assert (kept_once, plain) == (cfg["num_hidden_layers"] * kept, 0)

@@ -524,7 +524,7 @@ def test_block_diffusion_attention_block_compiles_at_the_cells_shape(
             with jax.named_scope(scopes.ATTN):
                 return nn.remat(
                     sl.BlockDiffusionAttention,
-                    policy=sl._KEEP_FLASH_RESIDUALS)(
+                    policy=sl.KEEP_ATTN_RESIDUALS)(
                         cfg, jnp.bfloat16, name="l00")(u)
 
     layer = Block()
