@@ -18,6 +18,7 @@ from .ccnet import CCNet, CrissCrossAttention, RCCAHead
 from .danet import DANet, DANetHead
 from .deeplab import ASPP, DeepLabV3, FCN, FCNHead
 from .encnet import EncNet, EncNetHead, Encoding
+from .kanana_lm import KananaLM, build_kanana_lm
 from .keye_lm import KeyeLM, build_keye_lm
 from .nemotron_h import NemotronH, build_nemotron_h
 from .pspnet import PSPNet, PyramidPooling
@@ -35,7 +36,8 @@ SEGMENTATION_TASKS = ("instance", "semantic")
 TOKEN_MODELS = {"nemotron_h": (build_nemotron_h, ("tokens",)),
                 "keye_lm": (build_keye_lm, ("tokens",)),
                 "sdar_lm": (build_sdar_lm, ("tokens",)),
-                "zaya_lm": (build_zaya_lm, ("tokens",))}
+                "zaya_lm": (build_zaya_lm, ("tokens",)),
+                "kanana_lm": (build_kanana_lm, ("tokens",))}
 MODEL_TASKS = {name: tasks for name, (_, tasks) in TOKEN_MODELS.items()}
 
 
@@ -59,9 +61,9 @@ def build_model(
 ):
     """Construct a segmentation model by name — or, by a name of
     :data:`TOKEN_MODELS` (``nemotron_h``, ``keye_lm``, ``sdar_lm``,
-    ``zaya_lm``), a token model of the ``tokens`` task (``lm_config``: a
-    preset's name, a JSON file of the published keys, or that dict;
-    ``remat``; the image options do not apply to it).
+    ``zaya_lm``, ``kanana_lm``), a token model of the ``tokens`` task
+    (``lm_config``: a preset's name, a JSON file of the published keys, or
+    that dict; ``remat``; the image options do not apply to it).
 
     ``dtype`` may be a string ('float32' / 'bfloat16') for config-file use.
     """
@@ -237,6 +239,7 @@ __all__ = [
     "RCCAHead",
     "FCN",
     "FCNHead",
+    "KananaLM",
     "KeyeLM",
     "NemotronH",
     "PSPNet",

@@ -362,19 +362,18 @@ class Attention(nn.Module):
 
 
 #: ``checkpoint_name``s of what an expert block's reverse pass reads of its
-#: forward pass past a product or a selection: the router's logits (before
-#: the sigmoid, whose rule reads its own result), its choice and the chosen
-#: scores (a gather of 22 in 512 a token costs three times the top-22 on
+#: forward pass past a product or a selection: the router's
+#: (``parallel/moe.py::ROUTER_KEPT``: its logits, its choice and the chosen
+#: scores — a gather of 22 in 512 a token costs three times the top-22 on
 #: the chip), the two arrays of the dispatch that the routed experts'
 #: residuals hold, the latent rows, the routed sum as ``latent_up``'s
 #: product reads it, and the shared expert's pre-activation.  A block that
 #: keeps them replays elementwise passes only: no product, no top-k, no
 #: gather, no sort, no chunk loop
-EXPERT_KEPT = (_LOGITS, _IDX, _CHOSEN, _ROWS, _GROUP_SIZES, _LOW, _ROUTED,
-               _PRE) = (
-    "moe_router_logits", "moe_topk_idx", "moe_chosen_scores",
+_ROWS, _GROUP_SIZES, _LOW, _ROUTED, _PRE = (
     "moe_dispatch_rows", "moe_group_sizes", "moe_latent_rows",
     "moe_routed_sum", "moe_shared_pre")
+EXPERT_KEPT = moe_lib.ROUTER_KEPT + (_ROWS, _GROUP_SIZES, _LOW, _ROUTED, _PRE)
 
 
 class LatentMoE(nn.Module):
@@ -405,17 +404,9 @@ class LatentMoE(nn.Module):
                                  (c.shared_hidden, d))
 
         x = rms_norm(u, norm, c.norm_eps).reshape(-1, d)
-        with jax.named_scope(scopes.MOE_ROUTER):
-            scores = jax.nn.sigmoid(checkpoint_name(
-                jnp.dot(x.astype(F32), router), _LOGITS))
-            idx = checkpoint_name(jax.lax.top_k(
-                scores + router_bias, c.experts_per_token)[1], _IDX)
-            weights = scores[:, off:off + held]
-            if c.norm_topk:
-                chosen = checkpoint_name(
-                    jnp.take_along_axis(scores, idx, axis=-1), _CHOSEN)
-                weights = weights / (chosen.sum(-1)[:, None] + 1e-20)
-            weights = weights * c.routed_scale                # (N, held)
+        weights, idx = moe_lib.sigmoid_route(
+            x, router, router_bias, k=c.experts_per_token, held=held,
+            offset=off, norm_topk=c.norm_topk, scale=c.routed_scale)
         with jax.named_scope(scopes.MOE_LATENT):
             low = checkpoint_name(_dot(x, latent_down, self.dtype), _LOW)
         with jax.named_scope(scopes.MOE_DISPATCH):

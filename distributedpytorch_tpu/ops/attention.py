@@ -15,7 +15,9 @@ they are pure jnp functions the flax modules call, designed for the MXU:
   one key/value block and carries the same running (max, sum, acc) state).
 
 :func:`causal_attention` is a token model's layer (``models/nemotron_h.py``):
-causal, grouped-query, scaled by 1/sqrt(head_dim), heads kept apart.
+causal, grouped-query, scaled by 1/sqrt(head_dim), heads kept apart; its
+values may be narrower than its queries and keys (``models/kanana_lm.py``'s
+latent attention: 192-wide q and k, 128-wide v).
 :func:`causal_attention` with ``keep`` is the same over a per-query key set
 (:func:`head_mean_probs` its probabilities, heads averaged), and
 :func:`indexer_scores` with :func:`topk_keep` / :func:`threshold_keep` are
@@ -164,7 +166,7 @@ def _causal_scores(q, k, keep=None):
 
 def _attend(sc, v, b, length, dtype):
     """Softmax over the keys of grouped scores (B, G, R, S, S), float32, and
-    the probabilities' product with the values: (B, S, Hq, D)."""
+    the probabilities' product with the values: (B, S, Hq, Dv)."""
     w = jax.nn.softmax(sc, axis=-1).astype(dtype)
     out = jnp.einsum("bgrqk,bkgd->bqgrd", w, v,
                      preferred_element_type=jnp.float32)
@@ -210,13 +212,16 @@ def block_diffusion_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      keep: jax.Array | None = None) -> jax.Array:
-    """Causal grouped-query attention, scores scaled by 1/sqrt(head_dim).
+    """Causal grouped-query attention, scores scaled by 1/sqrt(D), ``D`` the
+    width of q and k.
 
-    ``q``: (B, S, Hq, D); ``k``, ``v``: (B, S, Hkv, D), ``Hq`` a multiple of
-    ``Hkv``: query heads ``g·r … g·r + r − 1`` read key/value head ``g`` ->
-    (B, S, Hq, D) in ``q``'s dtype.  ``keep`` (bool, (B, S, S)): query ``t``
-    of every head attends only to the keys ``s <= t`` with ``keep[b, t, s]``
-    (a learned sparse attention's key set).  The float32 (B, Hq, S, S) scores
+    ``q``: (B, S, Hq, D); ``k``: (B, S, Hkv, D); ``v``: (B, S, Hkv, Dv),
+    values as wide as the keys or not (latent attention's are narrower);
+    ``Hq`` a multiple of ``Hkv``: query heads ``g·r … g·r + r − 1`` read
+    key/value head ``g`` -> (B, S, Hq, Dv) in ``q``'s dtype.  ``keep``
+    (bool, (B, S, S)): query ``t`` of every head attends only to the keys
+    ``s <= t`` with ``keep[b, t, s]`` (a learned sparse attention's key
+    set).  The float32 (B, Hq, S, S) scores
     and their probabilities in ``q``'s dtype are whole arrays here: the form
     that runs off-TPU and in float32; ``ops/pallas_attention.py::
     flash_causal_attention`` / ``flash_sparse_attention`` are the same

@@ -908,20 +908,25 @@ def flash_causal_attention(q, k, v, interpret: bool = False):
     neither computed nor fetched.  Softmax statistics in float32, MXU
     operands in the inputs' dtype.
 
-    ``q``: (B, S, Hq, D); ``k``, ``v``: (B, S, Hkv, D) with ``Hq`` a
+    ``q``: (B, S, Hq, D); ``k``: (B, S, Hkv, D); ``v``: (B, S, Hkv, Dv),
+    as wide as the keys or narrower (latent attention: D 192, Dv 128; the
+    kernels take the two widths apart, scores scaled by 1/√D), with ``Hq`` a
     multiple of ``Hkv``: query heads ``g·r … g·r + r − 1`` read key/value
     head ``g`` through the kernels' index maps, nothing is repeated in HBM.
-    Returns (B, S, Hq, D)."""
+    Returns (B, S, Hq, Dv)."""
     b, s, hq, d = q.shape
-    hkv = k.shape[2]
+    hkv, dv = k.shape[2], v.shape[-1]
     if hq % hkv:
         raise ValueError(f"{hq} query heads do not share {hkv} key/value "
                          f"heads evenly")
+    if k.shape[-1] != d:
+        raise ValueError(f"keys {k.shape[-1]} wide do not meet queries {d} "
+                         "wide")
 
     out = flash_position_attention(
         _heads_first(q), _heads_first(k), _heads_first(v), *_causal_blocks(s),
         1 / math.sqrt(d), interpret=interpret, rule=CAUSAL, group=hq // hkv)
-    return out.reshape(b, hq, s, d).transpose(0, 2, 1, 3)
+    return out.reshape(b, hq, s, dv).transpose(0, 2, 1, 3)
 
 
 def flash_block_diffusion_attention(q, k, v, length: int, block: int,

@@ -49,6 +49,7 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..telemetry import counters, scopes
@@ -266,6 +267,41 @@ class Dispatch(NamedTuple):
     kept: jax.Array
     group_sizes: jax.Array
     dropped: jax.Array
+
+
+#: ``checkpoint_name``s of what :func:`sigmoid_route`'s reverse pass reads
+#: of its forward pass past a product or a selection: the logits (before the
+#: sigmoid, whose rule reads its own result), the choice and the chosen
+#: scores (the gather that normalises the weights)
+ROUTER_KEPT = (_LOGITS, _IDX, _CHOSEN) = (
+    "moe_router_logits", "moe_topk_idx", "moe_chosen_scores")
+
+
+def sigmoid_route(x: jax.Array, router: jax.Array, bias: jax.Array, *,
+                  k: int, held: int, offset: int, norm_topk: bool,
+                  scale: float, precision=None) -> tuple:
+    """A token model's sigmoid router over EVERY published expert, under the
+    ``router`` scope: ``s = sigmoid(x @ router)`` in float32, the ``k``
+    experts of largest ``s + bias`` (the score-correction bias moves the
+    choice and not the weights; no gradient reaches it through the
+    selection), and the weights of the ``held`` experts from ``offset`` on,
+    ``s_e / (sum of the chosen s)`` where ``norm_topk``, times ``scale``.
+    ``x``: (N, d) -> ``(weights (N, held) float32, idx (N, k))``; a held
+    expert that a token did not choose has a weight here and no row in the
+    dispatch.  ``precision``: the product's (``None``: the default's).  What
+    the reverse pass reads is named :data:`ROUTER_KEPT`.  The expert layers
+    of ``models/nemotron_h.py`` and ``models/kanana_lm.py`` call it."""
+    with jax.named_scope(scopes.MOE_ROUTER):
+        scores = jax.nn.sigmoid(checkpoint_name(
+            jnp.dot(x.astype(jnp.float32), router, precision=precision),
+            _LOGITS))
+        idx = checkpoint_name(jax.lax.top_k(scores + bias, k)[1], _IDX)
+        weights = scores[:, offset:offset + held]
+        if norm_topk:
+            chosen = checkpoint_name(
+                jnp.take_along_axis(scores, idx, axis=-1), _CHOSEN)
+            weights = weights / (chosen.sum(-1)[:, None] + 1e-20)
+        return weights * scale, idx
 
 
 def dropless_buffer_rows(n_tokens: int, k: int, n_held: int) -> int:

@@ -110,6 +110,13 @@ ATTN_INDEX_ALIGN = "index_align"
 #: attention block under ``attn/l<i>/``: the value shift, both causal
 #: convolutions, the q-k mean, the L2 norm and temperature, the half rotary
 ATTN_CCA_MIX = "cca_mix"
+#: a multi-head latent attention block's latent path under ``attn/l<i>/``:
+#: the key/value down-projection, the latent's RMSNorm, the up-projection
+#: into every head's keys and values, the rotary parts of q and of the one
+#: shared key head, that key head's broadcast and the assembly of q and k
+MLA_LATENT = "mla_latent"
+#: a dense feed-forward block (a token model's leading dense layer)
+MLP = "mlp"
 TOKEN_LAYERS = (EMBED, MAMBA, ATTN, MOE, MTP, LM_HEAD)
 #: ops of the model that sit in no sub-module (the logits' final upsample)
 MODEL = "model"

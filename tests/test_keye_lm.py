@@ -794,7 +794,7 @@ def test_scope_table_holds_the_new_parts(whole):
 
 def test_token_models_are_one_table():
     assert set(TOKEN_MODELS) == {"nemotron_h", "keye_lm", "sdar_lm",
-                                 "zaya_lm"}
+                                 "zaya_lm", "kanana_lm"}
     assert MODEL_TASKS == {name: ("tokens",) for name in TOKEN_MODELS}
     assert isinstance(build_model("keye_lm"), kl.KeyeLM)
     assert isinstance(build_model("nemotron_h"), nh.NemotronH)

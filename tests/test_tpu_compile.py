@@ -632,3 +632,76 @@ def test_cca_attention_block_compiles_at_the_cells_shape(v5e_mesh,
                            "cca_attn_kernel_roofline.json")) as f:
         mine = re.compile(json.load(f)["args"]["event_pattern"])
     assert all(mine.search(f"%{c} custom-call") for c in calls)
+
+
+def test_latent_attention_block_compiles_at_the_cells_shape(v5e_mesh,
+                                                            monkeypatch):
+    """``kanana_lm``'s multi-head latent attention block with the causal
+    flash kernels live, forward and reverse under ``nn.remat``'s policy, in
+    bfloat16 over one sequence of 8,192 tokens at the published widths (32
+    heads, q and k 192 = 128 + 64 wide, v 128, the latent 512): ONE forward
+    call and ONE fused reverse call (at 192 channels the reverse pass's
+    resident dQ is 16 MiB, the fused schedule's limit: ``_bwd_plan``), each
+    reading q and k at 192 and v at 128, the cell's roofline pattern
+    matching both; no array of token pairs; the latent path compiled under
+    ``attn/l00/mla_latent`` in the forward and the reverse phase."""
+    import json
+    import os
+
+    from flax import linen as nn
+
+    from distributedpytorch_tpu.models import kanana_lm as kl
+
+    monkeypatch.setattr(danet_mod, "_on_tpu", lambda: True)
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "kanana2_30b_a3b_stage_ep8.json")) as f:
+        cfg = kl.LMConfig.from_dict(json.load(f))
+    assert (cfg.heads, cfg.qk_dim, cfg.v_dim, cfg.kv_rank) == (
+        32, 192, 128, 512)
+    seq = 8192
+    assert pa._bwd_plan(seq, cfg.qk_dim) == (512, True)
+    one = _one_chip(v5e_mesh)
+
+    class Block(nn.Module):
+        @nn.compact
+        def __call__(self, u):
+            with jax.named_scope(scopes.ATTN):
+                return nn.remat(kl.LatentAttention,
+                                policy=kl.KEEP_ATTN_RESIDUALS)(
+                    cfg, jnp.bfloat16, name="l00")(u)
+
+    layer = Block()
+    u = jax.ShapeDtypeStruct((1, seq, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, cfg.hidden_size),
+                                             jnp.bfloat16)))["params"])
+
+    def loss(p, v):
+        return layer.apply({"params": p}, v).astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, u).compile().as_text()
+    calls = [ln.split(" = ")[0].strip().lstrip("%")
+             for ln in _custom_calls(hlo)]
+    assert sorted(c.split(".")[0] for c in calls) == [
+        scopes.CAUSAL_ATTN, scopes.CAUSAL_ATTN_BWD_FUSED]
+    # the up-projection's output is (8192, 32 x 256): as many channels as
+    # tokens, so an array of token pairs is looked for with its heads
+    assert not re.search(rf"\[(\d+,)*32,{seq},{seq}\]", hlo)
+    assert not re.search(rf"\[(\d+,)*{seq},32,{seq}\]", hlo)
+    for ln in _custom_calls(hlo):
+        operands = ln.split("operand_layout_constraints=", 1)[1]
+        assert operands.count(f"bf16[32,{seq},192]") == 2, ln[:300]
+        assert f"bf16[32,{seq},128]" in operands, ln[:300]
+    table = scopes.scope_table(hlo)
+    latent = {s.phase for s in table.values()
+              if s.path.startswith(f"attn/l00/{scopes.MLA_LATENT}")}
+    assert latent == {"fwd", "bwd"}
+    with open(os.path.join(here, "benchmarks", "metrics",
+                           "mla_attn_kernel_roofline.json")) as f:
+        mine = re.compile(json.load(f)["args"]["event_pattern"])
+    assert all(mine.search(f"%{c} custom-call") for c in calls)
