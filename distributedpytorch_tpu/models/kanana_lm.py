@@ -34,10 +34,12 @@ then a final RMSNorm and the untied head.
 In bfloat16 on a TPU (``models/danet.py::auto_wants_flash``) attention runs
 as the causal Mosaic flash kernels of ``ops/pallas_attention.py``, forward
 and reverse, at q and k 192 wide and v 128 wide; otherwise the einsum form.
-A rematerialised attention block keeps the flash call's output and
-log-sum-exp and the latent before its norm (:data:`ATTN_KEPT`: 9.4 MB a
-layer at 8,192 positions, where the expanded keys and values would be 168
-MB); an expert block keeps the router's logits, choice and chosen scores,
+A rematerialised attention block keeps what its reverse pass reads past a
+product (:data:`ATTN_KEPT`): the latent before its norm, and the flash
+call's heads-first q, k and v, output and log-sum-exp — 346 MB a layer at
+8,192 positions — so its replay runs no projection, no rotary of a head and
+no transpose to heads-first; an expert block keeps
+the router's logits, choice and chosen scores,
 the dispatch's rows and the shared experts' pre-activation; the dense block
 its pre-activation.  The counts of experts and vocabulary rows in the
 configuration are what this chip HOLDS of a stated deployment; widths are
@@ -190,11 +192,20 @@ def rotate_pairs(x, ang):
 
 
 # ------------------------------------------------------------------ blocks
-#: ``checkpoint_name`` of the latent and the shared key head before their
-#: norm and rotary (``x Wkva``): kept with the flash call's output and
-#: log-sum-exp, the replay runs no key/value down-projection
+#: ``checkpoint_name``s of what an attention block's reverse pass reads of
+#: its forward pass past a product: the latent with the shared key head
+#: before their norm and rotary (``x Wkva``: the up-projection's weight
+#: gradient and the norm's reverse read it), and the attention's operands —
+#: q after its rotary, the keys assembled, v — which the causal rule below
+#: names heads-first on the kernel path, as the reverse call reads them.
+#: With the call's output and log-sum-exp beside them the replay holds no
+#: product, no rotary of a head and no transpose to heads-first; it renorms
+#: the input, one elementwise pass (keeping it too measured no faster on
+#: the chip: PERF.md section 4)
 _LATENT = "mla_kv_latent"
-ATTN_KEPT = (*pallas_attention.KEPT_BY_REVERSE, _LATENT)
+MLA_CAUSAL = pallas_attention.Causal(
+    kept_operands=("mla_attn_q", "mla_attn_k", "mla_attn_v"))
+ATTN_KEPT = (_LATENT, *MLA_CAUSAL.kept_operands, *MLA_CAUSAL.kept)
 
 
 class LatentAttention(nn.Module):
@@ -230,10 +241,15 @@ class LatentAttention(nn.Module):
                 -1)
             v = kv[..., dn:]
         # bfloat16 on a TPU: the Mosaic flash kernels, forward and reverse,
-        # and no (length, length) array reaches HBM; else the einsum form
-        attend = pallas_attention.flash_causal_attention \
-            if danet.auto_wants_flash(self.dtype) else causal_attention
-        out = attend(q, k, v).reshape(b, length, h * dv)
+        # and no (length, length) array reaches HBM; else the einsum form,
+        # whose reverse reads q, k and v as they are
+        if danet.auto_wants_flash(self.dtype):
+            out = pallas_attention.flash_causal_attention(q, k, v,
+                                                          rule=MLA_CAUSAL)
+        else:
+            out = causal_attention(*map(checkpoint_name, (q, k, v),
+                                        MLA_CAUSAL.kept_operands))
+        out = out.reshape(b, length, h * dv)
         return u + _dot(out, o_proj, self.dtype, out=u.dtype)
 
 
@@ -314,6 +330,18 @@ class SigmoidMoE(nn.Module):
         return u + (routed + shared).astype(u.dtype).reshape(u.shape)
 
 
+def attn_kept_bytes(c: LMConfig, tokens: int, item: int, flash: bool) -> int:
+    """Bytes of what one attention block names over ``tokens`` positions at
+    ``item`` bytes an element: :data:`ATTN_KEPT` — the latent, q, k and v,
+    and where the flash kernels run the call's output and its float32
+    log-sum-exp."""
+    kept = tokens * (c.kv_rank + c.qk_rope_dim
+                     + c.heads * (2 * c.qk_dim + c.v_dim)) * item
+    if flash:
+        kept += tokens * c.heads * (c.v_dim * item + 4)
+    return kept
+
+
 KEEP_ATTN_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
     *ATTN_KEPT)
 KEEP_MLP_RESIDUALS = jax.checkpoint_policies.save_only_these_names(_MLP_PRE)
@@ -368,7 +396,7 @@ class KananaLM(nn.Module):
         """A bound on the step's live activations on one device, for the
         planner's memory model (``parallel/plan.py``): what per-block
         recomputation keeps (every block's input; an attention block's
-        :data:`ATTN_KEPT`, a dense block's pre-activation, an expert
+        :func:`attn_kept_bytes`, a dense block's pre-activation, an expert
         block's :data:`EXPERT_KEPT`), the largest single block while it is
         recomputed and differentiated, and the head's float32 logits with
         their gradient."""
@@ -383,9 +411,7 @@ class KananaLM(nn.Module):
         if not self.remat:
             kept *= 8
         else:
-            kept += c.layers * t * ((c.kv_rank + c.qk_rope_dim) * item
-                                    + (c.heads * (c.v_dim * item + 4)
-                                       if flash else 0))
+            kept += c.layers * attn_kept_bytes(c, t, item, flash)
             kept += c.dense_layers * t * 2 * c.dense_hidden * item
             kept += moe_layers * (
                 4 * (t * (c.experts_total + 2 * c.experts_per_token)

@@ -126,7 +126,9 @@ class MaskRule:
     ``forward``, ``reverse_scope``, ``reverse_calls`` name the Mosaic calls
     (forward; the reverse pass's scope and its fused / dK-dV / dQ calls);
     ``kept``: the ``checkpoint_name``s of the forward call's output and
-    log-sum-exp, for a block that keeps them across its recomputation.
+    log-sum-exp, for a block that keeps them across its recomputation;
+    ``kept_operands``: those of the call's q, k and v, heads-first as the
+    reverse pass reads them (none: the operands are not named).
 
     Tiles are addressed by block index (``i`` queries, ``j`` keys) and size;
     ``reverse``: a reverse-pass tile, square, keys on sublanes and queries on
@@ -138,6 +140,7 @@ class MaskRule:
     reverse_calls: tuple = (scopes.PAM_BWD_FUSED, scopes.PAM_BWD_DKV,
                             scopes.PAM_BWD_DQ)
     kept: tuple = ()
+    kept_operands: tuple = ()
     #: the first key tile counts for every query tile, so the fused reverse
     #: call writes each row of its resident dQ before it adds to it
     first_key_tile_meets_all = True
@@ -268,11 +271,10 @@ class BlockDiffusion(MaskRule):
                             scopes.BLOCKDIFF_ATTN_BWD_DKV,
                             scopes.BLOCKDIFF_ATTN_BWD_DQ)
     kept: tuple = ("blockdiff_attn_out", "blockdiff_attn_lse")
-    #: ``checkpoint_name``s of the call's operands, heads-first, as the
-    #: reverse pass reads them: a block that keeps them replays neither the
+    #: a block that keeps the call's operands replays neither the
     #: transposes nor what makes q, k and v
-    kept_operands = ("blockdiff_attn_q", "blockdiff_attn_k",
-                     "blockdiff_attn_v")
+    kept_operands: tuple = ("blockdiff_attn_q", "blockdiff_attn_k",
+                            "blockdiff_attn_v")
     #: a query tile of the first noised block alone sees no clean key
     first_key_tile_meets_all = False
 
@@ -901,7 +903,8 @@ flash_position_attention.defvjp(_fwd, _bwd)
 _CAUSAL_TILE = (1024, 1024)
 
 
-def flash_causal_attention(q, k, v, interpret: bool = False):
+def flash_causal_attention(q, k, v, interpret: bool = False,
+                           rule: Causal = CAUSAL):
     """:func:`ops.attention.causal_attention` through the flash kernels,
     forward and reverse: no (S, S) array of scores, probabilities or their
     gradients reaches HBM, and the half of the tiles above the diagonal is
@@ -913,7 +916,9 @@ def flash_causal_attention(q, k, v, interpret: bool = False):
     kernels take the two widths apart, scores scaled by 1/√D), with ``Hq`` a
     multiple of ``Hkv``: query heads ``g·r … g·r + r − 1`` read key/value
     head ``g`` through the kernels' index maps, nothing is repeated in HBM.
-    Returns (B, S, Hq, Dv)."""
+    Returns (B, S, Hq, Dv).  ``rule``: :data:`CAUSAL`, or a :class:`Causal`
+    whose ``kept_operands`` name the heads-first operands, for a block that
+    keeps them across its recomputation."""
     b, s, hq, d = q.shape
     hkv, dv = k.shape[2], v.shape[-1]
     if hq % hkv:
@@ -924,8 +929,8 @@ def flash_causal_attention(q, k, v, interpret: bool = False):
                          "wide")
 
     out = flash_position_attention(
-        _heads_first(q), _heads_first(k), _heads_first(v), *_causal_blocks(s),
-        1 / math.sqrt(d), interpret=interpret, rule=CAUSAL, group=hq // hkv)
+        *_operands(rule, q, k, v), *_causal_blocks(s), 1 / math.sqrt(d),
+        interpret=interpret, rule=rule, group=hq // hkv)
     return out.reshape(b, hq, s, dv).transpose(0, 2, 1, 3)
 
 
@@ -951,10 +956,8 @@ def flash_block_diffusion_attention(q, k, v, length: int, block: int,
                          "and its noised copy")
     rule = BlockDiffusion(length=length, block=block)
     out = flash_position_attention(
-        *map(checkpoint_name, map(_heads_first, (q, k, v)),
-             rule.kept_operands),
-        *_causal_blocks(s), 1 / math.sqrt(d), interpret=interpret, rule=rule,
-        group=hq // hkv)
+        *_operands(rule, q, k, v), *_causal_blocks(s), 1 / math.sqrt(d),
+        interpret=interpret, rule=rule, group=hq // hkv)
     return out.reshape(b, hq, s, d).transpose(0, 2, 1, 3)
 
 
@@ -1007,6 +1010,15 @@ def _heads_first(x):
     first axis."""
     b, s, h, d = x.shape
     return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+
+def _operands(rule: MaskRule, q, k, v) -> tuple:
+    """q, k, v heads-first, named by ``rule.kept_operands`` where it names
+    them."""
+    operands = map(_heads_first, (q, k, v))
+    if rule.kept_operands:  # each named as it is made
+        operands = map(checkpoint_name, operands, rule.kept_operands)
+    return tuple(operands)
 
 
 def _causal_blocks(s: int) -> tuple[int, int]:

@@ -131,6 +131,18 @@ def interpreted_kernels(monkeypatch):
             pa, name, functools.partial(getattr(pa, name), interpret=True))
 
 
+@pytest.fixture(params=["einsum", "kernels"])
+def attn_form(request, monkeypatch):
+    """The attention form a block runs: the einsum form, or the flash
+    kernels in the pallas interpreter."""
+    from distributedpytorch_tpu.models import danet
+
+    if request.param == "kernels":
+        request.getfixturevalue("interpreted_kernels")
+        monkeypatch.setattr(danet, "auto_wants_flash", lambda dtype: True)
+    return request.param
+
+
 @pytest.fixture(scope="session", autouse=True)
 def _threadsan_witness():
     """DPTPU_THREADSAN=1 arms the jaxrace runtime witness for the whole

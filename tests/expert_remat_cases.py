@@ -1,11 +1,12 @@
 """What a rematerialised expert block keeps (``EXPERT_KEPT`` of
-models/nemotron_h.py and models/keye_lm.py), and SDAR's attention block
-(``ATTN_KEPT`` of models/sdar_lm.py): the counts and comparisons that
-tests/test_nemotron_h.py, test_keye_lm.py and test_sdar_lm.py make, each on
-its own model's block.  A policy saves the result of the ``name`` primitive,
-and a derivative whose residual is another variable still replays its
-producers: so what is counted here is products, selections and loops in the
-differentiated jaxpr, not names.
+models/nemotron_h.py and models/keye_lm.py), and SDAR's and Kanana-2's
+attention blocks (``ATTN_KEPT`` of models/sdar_lm.py and
+models/kanana_lm.py): the counts and comparisons that
+tests/test_nemotron_h.py, test_keye_lm.py, test_sdar_lm.py and
+test_kanana_lm.py make, each on its own model's block.  A policy saves the
+result of the ``name`` primitive, and a derivative whose residual is another
+variable still replays its producers: so what is counted here is products,
+selections, transposes and loops in the differentiated jaxpr, not names.
 """
 
 import math
@@ -44,6 +45,14 @@ def forward_products(jaxpr, tokens: int, hidden: int, width: int) -> int:
     forward product of the block's normed input (its two reverse products
     have other operands)."""
     return input_products(jaxpr, (tokens, hidden), width)
+
+
+def heads_first_transposes(jaxpr, shape) -> int:
+    """Transposes of a (B, S, H, D) array ``shape`` to heads-first in
+    ``jaxpr``."""
+    return sum(e.params["permutation"] == (0, 2, 1, 3)
+               and e.invars[0].aval.shape == tuple(shape)
+               for e in eqns_named(jaxpr, "transpose"))
 
 
 def block_case(cls, cfg, policy="none"):

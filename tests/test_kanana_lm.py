@@ -455,6 +455,26 @@ def test_nemotron_step_traces_as_before(precision):
     assert router_jaxpr_cases.traced(precision) == want
 
 
+@pytest.mark.parametrize("rule", ["causal", "mla"])
+def test_only_kananas_causal_rule_names_the_calls_operands(rule):
+    """:data:`CAUSAL` — the rule of Nemotron's, ZAYA1's and the sparse
+    path's calls — names the call's output and log-sum-exp and no operand;
+    Kanana's rule names its heads-first q, k and v besides.  Both run the
+    calls named ``causal_attn…``, which the roofline reader finds."""
+    q, k, v = _heads(256, 4, 1, 24, 16, 0)
+    the_rule = pa.CAUSAL if rule == "causal" else kl.MLA_CAUSAL
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: (pa.flash_causal_attention(
+            *a, interpret=True, rule=the_rule) ** 2).sum(),
+        (0, 1, 2)))(q, k, v).jaxpr
+    names = [e.params["name"] for e in kept_cases.eqns_named(jaxpr, "name")]
+    assert names == [*the_rule.kept_operands, *pa.CAUSAL.kept]
+    assert pa.CAUSAL.kept_operands == ()
+    assert [e.params["name"] for e in kept_cases.eqns_named(
+        jaxpr, "pallas_call")] == [scopes.CAUSAL_ATTN,
+                                   scopes.CAUSAL_ATTN_BWD_FUSED]
+
+
 # ----------------------------------------------- scopes and remat policies
 def test_scope_table_holds_the_latent_path_and_the_experts(whole):
     cfg, model, params, tokens = whole
@@ -483,13 +503,24 @@ def test_scope_table_holds_the_latent_path_and_the_experts(whole):
     assert not any(s.path.startswith("moe/l01") for s in table.values())
 
 
+def input_products(jaxpr, c: kl.LMConfig, positions: tuple) -> list:
+    """The forward products of an attention block over ``positions`` in
+    ``jaxpr``: q's and the latent's of the normed input, the
+    up-projection's of the normed latent."""
+    x, latent = positions + (c.hidden_size,), positions + (c.kv_rank,)
+    return [kept_cases.input_products(jaxpr, shape, width) for shape, width
+            in ((x, c.heads * c.qk_dim), (x, c.kv_rank + c.qk_rope_dim),
+                (latent, c.heads * (c.qk_nope_dim + c.v_dim)))]
+
+
 @pytest.mark.parametrize("policy,runs", [("kept", 1), ("bare", 2)])
-def test_replay_of_an_attention_block_runs_no_down_projection(whole, policy,
-                                                              runs):
-    """A block that keeps :data:`ATTN_KEPT` runs its key/value
-    down-projection once in the gradient, a bare ``nn.remat`` twice; q's
-    product and the up-projection run twice either way (what the block
-    keeps is the latent, not the expanded keys and values)."""
+def test_replay_of_an_attention_block_runs_no_down_projection(
+        whole, attn_form, policy, runs):
+    """A block that keeps :data:`ATTN_KEPT` runs no product of its input in
+    its replay — q's product, the key/value down-projection and the
+    up-projection each run once in the gradient, where a bare ``nn.remat``
+    runs each twice; on the kernel path q and the keys go heads-first once
+    each, where the bare block transposes them twice."""
     from flax import linen as nn
 
     cfg, _, params, _ = whole
@@ -497,21 +528,76 @@ def test_replay_of_an_attention_block_runs_no_down_projection(whole, policy,
     cls = nn.remat(kl.LatentAttention, policy=kl.KEEP_ATTN_RESIDUALS
                    if policy == "kept" else None)
     block = cls(c, jnp.float32)
-    u = jax.random.normal(jax.random.PRNGKey(2), kept_cases.POSITIONS + (64,))
+    u = jax.random.normal(jax.random.PRNGKey(2),
+                          kept_cases.POSITIONS + (c.hidden_size,))
 
     def loss(p, u):
         return (block.apply({"params": p}, u) ** 2).sum()
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(params["l00"], u).jaxpr
-    tokens = math.prod(kept_cases.POSITIONS)
-    assert kept_cases.input_products(
-        jaxpr, kept_cases.POSITIONS + (64,), c.kv_rank + c.qk_rope_dim) \
-        + kept_cases.forward_products(jaxpr, tokens, 64,
-                                      c.kv_rank + c.qk_rope_dim) == runs
-    assert kept_cases.input_products(
-        jaxpr, kept_cases.POSITIONS + (64,), c.heads * c.qk_dim) \
-        + kept_cases.forward_products(jaxpr, tokens, 64,
-                                      c.heads * c.qk_dim) == 2
+    assert input_products(jaxpr, c, kept_cases.POSITIONS) == [runs] * 3
+    if attn_form == "kernels":
+        assert kept_cases.heads_first_transposes(
+            jaxpr, kept_cases.POSITIONS + (c.heads, c.qk_dim)) == 2 * runs
+
+
+def test_kept_attention_block_gives_the_bare_blocks_loss_and_gradients(
+        attn_form):
+    kept_cases.assert_kept_block_is_the_bare_blocks(
+        kl.LatentAttention, kl.LMConfig.from_dict(tiny()),
+        kl.KEEP_ATTN_RESIDUALS, GRAD_RTOL)
+
+
+def test_kernel_path_keeps_the_attention_blocks_operands_by_name(
+        whole, monkeypatch, interpreted_kernels):
+    """The whole model's gradient on the kernel path names every array an
+    attention block keeps once a layer — the flash call's heads-first q, k
+    and v among them — and holds each forward product of an attention
+    block and each transpose of q and the keys to heads-first once a
+    layer: none is replayed."""
+    from distributedpytorch_tpu.models import danet
+
+    cfg, model, params, tokens = whole
+    monkeypatch.setattr(danet, "auto_wants_flash", lambda dtype: True)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: program_loss(model, p, tokens)[0]))(params).jaxpr
+    c, layers = model.cfg, cfg["num_hidden_layers"]
+    names = [e.params["name"] for e in kept_cases.eqns_named(jaxpr, "name")]
+    for name in kl.ATTN_KEPT:
+        assert names.count(name) == layers, name
+    operands = {e.params["name"]: e.invars[0].aval.shape
+                for e in kept_cases.eqns_named(jaxpr, "name")
+                if e.params["name"] in kl.MLA_CAUSAL.kept_operands}
+    b, s = tokens.shape
+    assert operands == {
+        "mla_attn_q": (b * c.heads, s, c.qk_dim),
+        "mla_attn_k": (b * c.heads, s, c.qk_dim),
+        "mla_attn_v": (b * c.heads, s, c.v_dim)}
+    assert input_products(jaxpr, c, tokens.shape) == [layers] * 3
+    assert kept_cases.heads_first_transposes(
+        jaxpr, tokens.shape + (c.heads, c.qk_dim)) == 2 * layers
+
+
+def test_activation_bytes_count_what_an_attention_block_keeps(
+        attn_form, monkeypatch):
+    """The planner is charged every layer's named arrays: the latent, q, k
+    and v, and on the kernel path the call's output and log-sum-exp."""
+    cfg, batch, length = tiny(), 2, 24
+    model = kl.build_kanana_lm(cfg)
+    names = kl.ATTN_KEPT if attn_form == "kernels" \
+        else kl.ATTN_KEPT[:-len(kl.MLA_CAUSAL.kept)]
+    kept = kept_cases.kept_bytes(kl.LatentAttention, model.cfg, names,
+                                 batch, length, jnp.float32)
+
+    def acts():
+        return [kl.build_kanana_lm(cfg, remat=r).activation_bytes(batch,
+                                                                  length)
+                for r in (True, False)]
+
+    charged = acts()
+    monkeypatch.setattr(kl, "attn_kept_bytes", lambda c, t, item, flash: 0)
+    kept_once, plain = (a - b for a, b in zip(charged, acts()))
+    assert (kept_once, plain) == (cfg["num_hidden_layers"] * kept, 0)
 
 
 # ------------------------------------------------------------- the trainer
@@ -651,6 +737,9 @@ def test_activation_bytes_follow_the_attention_form_that_runs(monkeypatch):
     einsum = model.activation_bytes(1, 8192)
     monkeypatch.setattr(danet, "_on_tpu", lambda: True)
     flash = model.activation_bytes(1, 8192)
-    # the einsum form holds 32 heads' float32 (8192, 8192) arrays
+    # the einsum form holds 32 heads' float32 (8192, 8192) arrays; the
+    # kernel path's attention blocks keep 346.0 MB a layer by name
+    kept = 7 * kl.attn_kept_bytes(model.cfg, 8192, 2, True)
+    assert 2.4e9 < kept < 2.45e9
     assert einsum > 3 * 32 * 8192 * 8192 * 4 > flash
-    assert 1.05e9 < flash < 6e9          # the logits and their gradient too
+    assert 1.05e9 < flash - kept < 6e9   # the logits and their gradient too

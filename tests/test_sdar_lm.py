@@ -779,25 +779,6 @@ def test_activation_bytes_count_what_an_expert_block_keeps(monkeypatch):
 ATTN_TINY = tiny(num_attention_heads=8, block_length=2)
 
 
-@pytest.fixture(params=["einsum", "kernels"])
-def attn_form(request, monkeypatch):
-    """The attention form a block runs: the einsum form, or the flash
-    kernels in the pallas interpreter."""
-    from distributedpytorch_tpu.models import danet
-
-    if request.param == "kernels":
-        request.getfixturevalue("interpreted_kernels")
-        monkeypatch.setattr(danet, "auto_wants_flash", lambda dtype: True)
-    return request.param
-
-
-def heads_first_transposes(jaxpr, shape):
-    """Transposes of a (B, S, H, D) array ``shape`` to heads-first."""
-    return sum(e.params["permutation"] == (0, 2, 1, 3)
-               and e.invars[0].aval.shape == tuple(shape)
-               for e in kept_cases.eqns_named(jaxpr, "transpose"))
-
-
 @pytest.mark.parametrize("policy,runs", [("kept", 1), ("bare", 2)])
 def test_replay_of_an_attention_block_holds_no_product_of_its_input(
         attn_form, policy, runs):
@@ -814,7 +795,7 @@ def test_replay_of_an_attention_block_holds_no_product_of_its_input(
     assert [kept_cases.input_products(jaxpr, u.shape, w)
             for w in (q, kv)] == [runs, 2 * runs]
     if attn_form == "kernels":
-        assert heads_first_transposes(
+        assert kept_cases.heads_first_transposes(
             jaxpr, u.shape[:2] + (cfg.kv_heads, cfg.head_dim)) == 2 * runs
 
 
@@ -847,7 +828,7 @@ def test_kernel_path_keeps_the_attention_blocks_operands_by_name(
                                      c.hidden_size)
     assert kept_cases.input_products(jaxpr, x, c.q_heads * c.head_dim) \
         == layers
-    assert heads_first_transposes(jaxpr, x[:2] + (c.kv_heads, c.head_dim)) \
+    assert kept_cases.heads_first_transposes(jaxpr, x[:2] + (c.kv_heads, c.head_dim)) \
         == 2 * layers
 
 
